@@ -15,6 +15,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import jax.numpy as jnp
 
+from repro.compat import make_mesh
 from repro.configs import get_reduced
 from repro.core import SPConfig
 from repro.models import get_model
@@ -26,7 +27,7 @@ def main():
     bundle = get_model(cfg)
     params, _ = bundle.init(cfg, jax.random.PRNGKey(0), 1)
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     # decode shards the KV cache over (pod, model); 4 batch slots over data
     sp = SPConfig(strategy="swift", sp_axes=("pod", "model"),
                   batch_axes=("data",))

@@ -18,11 +18,12 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import jax.numpy as jnp
 
+from repro.compat import make_mesh
 from repro.core import MaskSpec, SPConfig, plan, reference_attention, sp_attention
 
 
 def main():
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     key = jax.random.PRNGKey(0)
     kq, kk, kv = jax.random.split(key, 3)
     q = jax.random.normal(kq, (2, 64, 8, 32))   # [B, L, Hq, D]

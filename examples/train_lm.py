@@ -20,6 +20,7 @@ import jax
 from repro.configs import get_config
 from repro.configs.shapes import InputShape
 from repro.core import SPConfig
+from repro.launch.mesh import make_host_mesh
 from repro.train import AdamWConfig, Trainer
 
 
@@ -41,7 +42,7 @@ def main():
     print(f"model: {n_params / 1e6:.1f}M params, "
           f"{cfg.n_layers}L d={cfg.d_model}")
 
-    mesh = jax.make_mesh((1, len(jax.devices())), ("data", "model"))
+    mesh = make_host_mesh(model=len(jax.devices()))
     sp = SPConfig(strategy="swift_torus" if len(jax.devices()) > 1 else "full",
                   sp_axes=("model",), batch_axes=("data",))
     shape = InputShape("train_demo", args.seq, args.batch, "training")

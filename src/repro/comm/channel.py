@@ -36,7 +36,6 @@ from typing import Any, Sequence
 import jax
 from jax import lax
 
-from ..compat import optimization_barrier
 from . import profiler as _profiler
 from . import trace as _trace
 
@@ -63,8 +62,8 @@ class Channel:
     the name only matters for trace/debug output.  ``backend`` selects the
     lowering: ``"xla"`` (ppermute + optimization_barrier, overlap left to
     XLA's scheduler) or ``"pallas"`` (in-kernel DMA + explicit semaphores,
-    DESIGN.md §8.1); ``interpret`` runs the Pallas branch in interpreter
-    mode (the CPU CI path).
+    DESIGN.md §8.1); ``interpret`` is the CPU landing kernel's
+    interpreter mode (None follows the platform).
     """
 
     axes: tuple[str, ...]
@@ -73,7 +72,7 @@ class Channel:
     stream: str = ""  # owning Stream name (trace bookkeeping)
     stage: int = 0  # stage index within the stream program
     backend: str = "xla"  # "xla" | "pallas"
-    interpret: bool = True  # Pallas branch: interpreter mode (CPU CI)
+    interpret: bool | None = None  # CPU landing kernel; None = platform
 
     def __post_init__(self):
         assert self.backend in ("xla", "pallas"), self.backend
@@ -152,10 +151,9 @@ class Channel:
         records the schedule (put flagged ``overlap=True`` — the
         semaphore validator then requires compute between issue and
         wait) and performs the wire move: the kernel's DMA stages the
-        chunk into the forward buffer on the *local* device, so the
-        inter-device hop is a ppermute on every branch (DESIGN.md §8.1
-        interpret caveats; true in-kernel remote-copy forwarding is the
-        ROADMAP hardware item).
+        chunk into the forward buffer on the *local* device, and the
+        inter-device hop is ``pallas_backend.deliver`` — the remote copy
+        on a TPU, ppermute plus landing kernel elsewhere (DESIGN.md §8.1).
         """
         assert self.backend == "pallas", "put_fused is a Pallas-path verb"
         from . import pallas_backend as _pb
@@ -165,13 +163,8 @@ class Channel:
             _profiler.mark(_profiler.active(), meta, "issue", tensors)
         sem = _pb.fused_transfer_events(
             self, tuple(tensors[0].shape), len(tensors), overlaps=overlaps)
-        # The fused kernel's DMA is a LOCAL make_async_copy into the
-        # forward buffer (the RDMA staging step) on every branch, so the
-        # wire move is always this ppermute — including on real TPUs.
-        # Replacing it with true in-kernel make_async_remote_copy
-        # forwarding is the ROADMAP hardware item.
-        out = tuple(lax.ppermute(t, self.axes, perm=list(self.perm))
-                    for t in tensors)
+        out = _pb.deliver(tensors, tuple(self.axes), tuple(self.perm),
+                          interpret=self.interpret)
         _trace.emit_sem(_trace.SemEvent(
             kind="signal", sem=sem, stream=self.stream, channel=self.name,
             stage=self.stage))
@@ -225,7 +218,7 @@ def fence(tensors: Sequence[jax.Array],
     that do not pass through the fence (e.g. the next put) stay
     independent and keep overlapping.  Returns (tensors, deps) pinned.
     """
-    out = optimization_barrier(tuple(tensors) + tuple(deps))
+    out = lax.optimization_barrier(tuple(tensors) + tuple(deps))
     n = len(tuple(tensors))
     return out[:n], out[n:]
 
@@ -234,4 +227,4 @@ def pin(xs: Sequence[jax.Array]) -> tuple:
     """Serialise a value chain (e.g. an accumulator) across schedule steps
     so only O(1) intermediates are live — the quiet counterpart of fence.
     """
-    return optimization_barrier(tuple(xs))
+    return lax.optimization_barrier(tuple(xs))

@@ -14,18 +14,21 @@ NVSHMEM kernels do, with explicit semaphores:
                semaphore): the receiver-side spin-wait, executed as late
                as the schedule allows.
 
-Two lowering branches, selected by ``interpret`` / the runtime platform:
+Two lowering branches, selected by the platform JAX runs on:
 
-  * **TPU** (``interpret=False`` on a TPU backend): a kernel performs the
-    remote copy proper.  The destination rank comes from the channel's
-    perm table indexed by ``lax.axis_index`` — a *distance*, exactly like
-    the XLA route.  Only single-axis channels lower this way (the RDMA
-    ``device_id`` is a coordinate along one mesh axis); multi-axis routes
-    fall back to the emulation branch.
-  * **interpret / CPU CI** (the tested path): inter-device wire movement
-    is not expressible inside an interpret-mode kernel, so the wire move
-    stays a ``lax.ppermute`` (same HLO pairs, so `trace.validate` keeps
-    working unchanged) and a *landing kernel* executes the put/signal/wait
+  * **TPU**: a kernel performs the remote copy proper.  The destination
+    rank comes from the channel's perm table indexed by ``lax.axis_index``
+    over the route's axes — a *distance*, exactly like the XLA route — and
+    is handed to ``make_async_remote_copy`` as a mesh coordinate over
+    those axes (one axis or several), so every route lowers this way.
+    Before any byte moves, each rank tells the rank that writes into it
+    that its receive buffers are live (barrier semaphore), and writes only
+    after its own destination has said the same.  There is no emulation
+    on a TPU: a route this branch cannot express raises.
+  * **CPU** (the tested path): inter-device wire movement is not
+    expressible inside an interpret-mode kernel, so the wire move stays a
+    ``lax.ppermute`` (same HLO pairs, so `trace.validate` keeps working
+    unchanged) and a *landing kernel* executes the put/signal/wait
     protocol on the received buffer: an in-kernel async copy
     (``pltpu.make_async_copy`` + DMA semaphore) delivers the payload into
     the receive buffer.  Everything downstream of the channel — the fused
@@ -37,7 +40,9 @@ blocking wait) next to the HLO-level overlap checks.
 """
 from __future__ import annotations
 
+import functools
 import itertools
+import zlib
 from typing import Sequence
 
 import jax
@@ -46,6 +51,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..compat import pallas_interpret
 from . import profiler as _profiler
 from . import trace as _trace
 
@@ -55,6 +61,8 @@ __all__ = ["BACKENDS", "deliver", "fused_transfer_events", "new_sem",
 BACKENDS = ("xla", "pallas")
 
 _sem_counter = itertools.count()
+# barrier-semaphore ids the TPU remote put hashes its routes into
+_COLLECTIVE_IDS = 64
 
 
 def new_sem(channel_name: str, stage: int) -> str:
@@ -80,8 +88,9 @@ def _landing_kernel(*refs):
         dma.wait()
 
 
-def landing_copy(tensors: Sequence[jax.Array]) -> tuple[jax.Array, ...]:
-    """Run the landing kernel over ``tensors`` (interpret mode).
+def landing_copy(tensors: Sequence[jax.Array], *,
+                 interpret: bool | None = None) -> tuple[jax.Array, ...]:
+    """Run the landing kernel over ``tensors``.
 
     One ``pallas_call`` delivers all tensors of a put: the buffers stay in
     ANY/HBM space (no VMEM staging of arbitrarily-shaped payloads) and one
@@ -91,37 +100,53 @@ def landing_copy(tensors: Sequence[jax.Array]) -> tuple[jax.Array, ...]:
     n = len(tensors)
     out = pl.pallas_call(
         _landing_kernel,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * n,
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * n,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n,
         out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in tensors],
         scratch_shapes=[pltpu.SemaphoreType.DMA] * n,
-        interpret=True,
+        interpret=pallas_interpret(interpret),
     )(*tensors)
     return tuple(out)
 
 
-def _perm_table(perm: Sequence[tuple[int, int]], size: int) -> jax.Array:
-    tbl = [0] * size
+def _route_tables(perm: Sequence[tuple[int, int]],
+                  size: int) -> tuple[list[int], list[int]]:
+    """(destination, source) of every rank along a route that must be a
+    full permutation of ``range(size)``: a rank that received nothing
+    would keep an unwritten buffer, where ``lax.ppermute`` gives zeros."""
+    dst, src = [-1] * size, [-1] * size
     for s, d in perm:
-        tbl[s] = d
-    return jnp.asarray(tbl, jnp.int32)
+        dst[s], src[d] = d, s
+    if -1 in dst or -1 in src:
+        raise ValueError(
+            f"the TPU remote put needs a full permutation of {size} ranks; "
+            f"got {sorted(perm)}")
+    return dst, src
 
 
-def _remote_put_kernel(dst_ref, *refs):
-    """TPU branch: remote-copy every tensor to ``dst`` (scalar prefetch).
+def _remote_put_kernel(ids_ref, *refs, axes: tuple[str, ...]):
+    """TPU branch: remote-copy every tensor to rank ``ids[0]`` of the
+    route, receiving from rank ``ids[1]`` (scalar prefetch).
 
     refs = (in_0.., out_0.., send_sem_0.., recv_sem_0..).  The out refs
     are this device's *receive* buffers — written by the neighbour's
-    symmetric copy, exactly NVSHMEM's symmetric-heap contract.
+    symmetric copy, exactly NVSHMEM's symmetric-heap contract.  The
+    barrier handshake keeps a rank from writing into a neighbour that has
+    not yet entered the kernel (whose receive buffer may still hold a
+    live value of an earlier op).
     """
     n = len(refs) // 4
     ins, outs = refs[:n], refs[n:2 * n]
     send, recv = refs[2 * n:3 * n], refs[3 * n:]
+    barrier = pltpu.get_barrier_semaphore()
+    pltpu.semaphore_signal(barrier, 1, device_id={axes: ids_ref[1]},
+                           device_id_type=pltpu.DeviceIdType.MESH)
+    pltpu.semaphore_wait(barrier, 1)
     dmas = [
         pltpu.make_async_remote_copy(
             src_ref=i, dst_ref=o, send_sem=s, recv_sem=r,
-            device_id=(dst_ref[0],),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id={axes: ids_ref[0]},
+            device_id_type=pltpu.DeviceIdType.MESH,
         )
         for i, o, s, r in zip(ins, outs, send, recv)
     ]
@@ -131,32 +156,41 @@ def _remote_put_kernel(dst_ref, *refs):
         dma.wait()
 
 
-def _tpu_remote_put(tensors: tuple[jax.Array, ...], axis: str,
-                    perm: Sequence[tuple[int, int]],
-                    size: int) -> tuple[jax.Array, ...]:
-    """In-kernel one-sided put along a single mesh axis (TPU only).
-
-    Untestable on the CPU CI (no RDMA in interpret mode); exercised on
-    hardware via ``backend="pallas", interpret=False``.
-    """
+def _tpu_remote_put(tensors: tuple[jax.Array, ...], axes: tuple[str, ...],
+                    perm: Sequence[tuple[int, int]]) -> tuple[jax.Array, ...]:
+    """In-kernel one-sided put along the route (compiled for a TPU; call
+    inside ``shard_map`` over a mesh that carries ``axes``)."""
     n = len(tensors)
-    dst = _perm_table(perm, size)[lax.axis_index(axis)]
+    axes = tuple(axes)
+    dst, src = _route_tables(perm, lax.axis_size(axes))
+    me = lax.axis_index(axes)
+    ids = jnp.stack([jnp.asarray(dst, jnp.int32)[me],
+                     jnp.asarray(src, jnp.int32)[me]])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * n,
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * n,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n,
         scratch_shapes=([pltpu.SemaphoreType.DMA] * (2 * n)),
     )
-    from ..compat import tpu_compiler_params
-
     out = pl.pallas_call(
-        _remote_put_kernel,
+        functools.partial(_remote_put_kernel, axes=axes),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in tensors],
-        compiler_params=tpu_compiler_params(
-            pltpu, has_side_effects=True, collective_id=0),
-    )(dst[None], *tensors)
+        compiler_params=pltpu.CompilerParams(
+            has_side_effects=True,
+            # kernels with different routes must not share a barrier
+            # semaphore (a signal meant for one would release the other)
+            collective_id=_collective_id(axes, perm)),
+    )(ids, *tensors)
     return tuple(out)
+
+
+def _collective_id(axes: tuple[str, ...],
+                   perm: Sequence[tuple[int, int]]) -> int:
+    """Barrier-semaphore id of a route: equal routes share one, distinct
+    routes get distinct ids (up to a hash collision)."""
+    key = repr((tuple(axes), tuple(sorted(perm)))).encode()
+    return zlib.crc32(key) % _COLLECTIVE_IDS
 
 
 def deliver(
@@ -164,22 +198,21 @@ def deliver(
     axes: tuple[str, ...],
     perm: Sequence[tuple[int, int]],
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     profile_src=None,
 ) -> tuple[jax.Array, ...]:
     """Move ``tensors`` one hop along the channel route, Pallas-lowered.
 
     The caller (Channel.put) owns the trace events; this function owns the
-    lowering branch choice.  ``profile_src`` (the owning Channel, when a
-    runtime profiler is active) brackets the landing kernel's DMA
-    semaphore wait as its own span — the protocol cost on top of the
-    wire move (DESIGN.md §12).
+    lowering branch choice: the remote copy on a TPU, the ppermute plus
+    landing kernel elsewhere (``interpret`` applies to that kernel).
+    ``profile_src`` (the owning Channel, when a runtime profiler is
+    active) brackets the landing kernel's DMA semaphore wait as its own
+    span — the protocol cost on top of the wire move (DESIGN.md §12).
     """
     tensors = tuple(tensors)
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu and not interpret and len(axes) == 1:
-        size = max(max(s, d) for s, d in perm) + 1
-        return _tpu_remote_put(tensors, axes[0], perm, size)
+    if jax.default_backend() == "tpu":
+        return _tpu_remote_put(tensors, tuple(axes), perm)
     # emulation branch: ppermute carries the bytes (keeping the HLO route
     # validatable), the landing kernel executes the semaphore protocol
     moved = tuple(lax.ppermute(t, axes, perm=list(perm)) for t in tensors)
@@ -192,7 +225,7 @@ def deliver(
             axes=tuple(axes), nbytes=_profiler.nbytes_of(tensors),
             n_tensors=len(tensors), backend="pallas", intent="sem")
         _profiler.mark(prof, meta, "issue", moved)
-    out = landing_copy(moved)
+    out = landing_copy(moved, interpret=interpret)
     if meta is not None:
         _profiler.mark(prof, meta, "signal", out)
     return out

@@ -39,7 +39,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from . import compress as _compress
 from .channel import Channel, InFlight, shift_perm
 
@@ -56,14 +55,14 @@ class Stream:
     ``next_stage`` advances the program counter.  Streams are trace-time
     bookkeeping only — they add no ops of their own.  ``backend`` selects
     the channel lowering for every stage of the program ("xla" | "pallas",
-    see channel.py); ``interpret`` runs Pallas channels in interpreter
-    mode (the CPU CI path).
+    see channel.py); ``interpret`` is the Pallas channels' CPU landing
+    kernel mode (None follows the platform).
     """
 
     name: str
     stage: int = 0
     backend: str = "xla"
-    interpret: bool = True
+    interpret: bool | None = None
 
     def channel(self, axes, perm, label: str = "") -> Channel:
         return Channel(axes=tuple(axes), perm=tuple(perm),
@@ -86,7 +85,7 @@ class Stream:
 def ring_shift(layout: Any, *tensors: jax.Array, shift: int = 1,
                stream: Stream | None = None,
                overlaps: str = "", backend: str = "xla",
-               interpret: bool = True) -> InFlight:
+               interpret: bool | None = None) -> InFlight:
     """One rotation inside each Ring group (same u): the KV hop of Ring
     Attention.  Returns the in-flight handle — the caller owns the wait."""
     stream = stream or Stream("ring", backend=backend, interpret=interpret)
@@ -97,7 +96,7 @@ def ring_shift(layout: Any, *tensors: jax.Array, shift: int = 1,
 def torus_hop(layout: Any, k: int, *tensors: jax.Array,
               stream: Stream | None = None,
               overlaps: str = "", backend: str = "xla",
-              interpret: bool = True) -> InFlight:
+              interpret: bool | None = None) -> InFlight:
     """Distance-k hop inside each Ulysses group (same r): stage k of the
     §4.3 decomposed all-to-all."""
     stream = stream or Stream("torus", backend=backend, interpret=interpret)
@@ -108,7 +107,7 @@ def torus_hop(layout: Any, k: int, *tensors: jax.Array,
 def intra_hop(layout: Any, j: int, *tensors: jax.Array,
               stream: Stream | None = None,
               overlaps: str = "", backend: str = "xla",
-              interpret: bool = True) -> InFlight:
+              interpret: bool | None = None) -> InFlight:
     """Distance-j hop inside the machine-local Ulysses sub-group (same
     u_hi, same r): stage j of the hierarchical a2a's fast leg (§8.2).
     Never crosses the slow boundary."""
@@ -120,7 +119,7 @@ def intra_hop(layout: Any, j: int, *tensors: jax.Array,
 def inter_hop(layout: Any, k: int, *tensors: jax.Array,
               stream: Stream | None = None,
               overlaps: str = "", backend: str = "xla",
-              interpret: bool = True) -> InFlight:
+              interpret: bool | None = None) -> InFlight:
     """Distance-k hop across machine sub-groups (same u_lo, same r):
     stage k of the hierarchical a2a's slow leg — the only leg of the
     two-level program that touches the inter-machine wire."""
@@ -140,7 +139,7 @@ def staged_all_to_all(
     split_axis: int,
     stream: Stream | None = None,
     backend: str = "xla",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """All-to-all restricted to Ulysses groups, as P_u - 1 channel stages.
 
@@ -175,7 +174,7 @@ def staged_ungroup(
     concat_axis: int,
     stream: Stream | None = None,
     backend: str = "xla",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Inverse program: put ``stacked[j]`` back to ulysses-peer j and
     concatenate the received chunks along ``concat_axis`` (the fourth
@@ -281,7 +280,7 @@ def hier_all_to_all(
     split_axis: int,
     stream: Stream | None = None,
     backend: str = "xla",
-    interpret: bool = True,
+    interpret: bool | None = None,
     wire_dtype: str | None = None,
     err: tuple | None = None,
 ) -> jax.Array | tuple[jax.Array, tuple]:
@@ -307,7 +306,7 @@ def hier_ungroup(
     concat_axis: int,
     stream: Stream | None = None,
     backend: str = "xla",
-    interpret: bool = True,
+    interpret: bool | None = None,
     wire_dtype: str | None = None,
     err: tuple | None = None,
 ) -> jax.Array | tuple[jax.Array, tuple]:
@@ -340,7 +339,7 @@ def pipe_handoff(
     batch_axes: tuple[str, ...] | None = None,
     stream: Stream | None = None,
     backend: str = "xla",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Stage-boundary hand-off of the displaced patch pipeline: rotate the
     activation one stage forward along the pipe ``axis``.
@@ -368,5 +367,5 @@ def pipe_handoff(
     def body(xs):
         return ch.put(xs, overlaps="stage compute").wait()
 
-    return shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                     check_vma=False)(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                         check_vma=False)(x)

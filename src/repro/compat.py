@@ -1,61 +1,30 @@
-"""Version compatibility shims for the jax APIs this repo uses.
+"""Choices that follow from the installed JAX and the platform it runs on.
 
-The container pins jax 0.4.37, where ``shard_map`` still lives in
-``jax.experimental.shard_map`` (with ``check_rep`` instead of
-``check_vma``) and the Pallas-TPU compiler params class is named
-``TPUCompilerParams``.  Newer jax promotes both.  Every call site imports
-from here so the codebase runs on either side of the rename.
+``make_mesh`` is the one constructor every mesh in this repo goes through:
+``jax.make_mesh`` without ``axis_types`` gives Explicit axes, whose
+sharding-in-types rules reject the GSPMD-propagated shardings this code
+relies on (``ShardingTypeError``), so all meshes here are Auto.
+
+``pallas_interpret`` resolves every ``interpret=None`` in the repo: Pallas
+kernels run interpreted off a TPU and compiled on one.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """jax.shard_map with the 0.4.x fallback (check_vma -> check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def make_mesh(shape, axis_names):
-    """jax.make_mesh with explicit Auto axis types where supported.
-
-    ``jax.sharding.AxisType`` (and make_mesh's ``axis_types``) only exist
-    on newer jax; 0.4.x meshes are implicitly Auto.
-    """
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axis_names)
+def make_mesh(shape, axis_names, *, devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with Auto axis types on every axis."""
     return jax.make_mesh(shape, axis_names,
-                         axis_types=(AxisType.Auto,) * len(axis_names))
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
-@jax.custom_jvp
-def optimization_barrier(xs):
-    """``lax.optimization_barrier`` that is differentiable on jax 0.4.x.
-
-    0.4.37 has no JVP rule for the barrier primitive; training through the
-    Torus/Ring schedules needs one.  The custom rule applies the barrier to
-    the primals (the scheduling pin is a forward-pass concern) and passes
-    tangents through untouched — identity, so reverse-mode transposition
-    works too.
-    """
-    return jax.lax.optimization_barrier(xs)
-
-
-@optimization_barrier.defjvp
-def _optimization_barrier_jvp(primals, tangents):
-    (xs,), (dxs,) = primals, tangents
-    return jax.lax.optimization_barrier(xs), dxs
-
-
-def tpu_compiler_params(pltpu, **kwargs):
-    """pltpu.CompilerParams on new jax, pltpu.TPUCompilerParams on 0.4.x."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+def pallas_interpret(interpret: bool | None = None) -> bool:
+    """Pallas interpret mode: ``interpret`` when given, else on exactly
+    when JAX's default backend is not a TPU (a CPU cannot compile Mosaic
+    kernels; a TPU must never fall back to the interpreter)."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret

@@ -174,7 +174,7 @@ def grouped_all_to_all(
     split_axis: int,
     stack_axis: int = 0,
     backend: str = "xla",
-    interpret: bool = True,
+    interpret: bool | None = None,
     wire_dtype: str | None = None,
 ) -> jax.Array:
     """All-to-all restricted to Ulysses groups of ``layout``.
@@ -202,7 +202,7 @@ def grouped_all_to_all(
 
 def monolithic_all_to_all(
     x: jax.Array, layout: GroupLayout, *, split_axis: int,
-    backend: str = "xla", interpret: bool = True,
+    backend: str = "xla", interpret: bool | None = None,
     wire_dtype: str | None = None,
 ) -> jax.Array:
     """Baseline atomic all-to-all (what Ulysses does before Torus).
@@ -232,7 +232,7 @@ def monolithic_all_to_all(
 
 def ungroup_all_to_all(
     stacked: jax.Array, layout: GroupLayout, *, concat_axis: int,
-    backend: str = "xla", interpret: bool = True,
+    backend: str = "xla", interpret: bool | None = None,
     wire_dtype: str | None = None,
 ) -> jax.Array:
     """Inverse transform: send ``stacked[j]`` back to ulysses-peer j and

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 from .collectives import flat_rank
 from .softmax import MaskSpec, attend_partial
 from .strategy import SPConfig
@@ -93,7 +91,7 @@ def decode_attention(
         scale=scale,
         window=window,
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(qspec, cspec, cspec, qspec, qspec, P()),

@@ -51,7 +51,7 @@ def ring_attention(
     unroll: bool = False,
     kv_block: int | None = None,
     backend: str = "xla",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Partial:
     """Run P_r ring steps; returns the merged partial (not finalized).
 
@@ -64,7 +64,7 @@ def ring_attention(
     inside the kernel, the paper's Algorithm-2 overlap.  The pallas path
     is always step-unrolled (one kernel per step) and ignores
     ``kv_block`` (the kernel has its own VMEM blocking); ``interpret``
-    selects the interpreter-mode lowering (the CPU CI path)."""
+    selects the interpreter-mode lowering (None follows the platform)."""
     if backend == "pallas":
         return _ring_attention_pallas(
             q, k, v, layout, q_pos=q_pos, k_pos_fn=k_pos_fn, scale=scale,

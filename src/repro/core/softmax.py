@@ -33,8 +33,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import optimization_barrier
-
 NEG_INF = float("-inf")
 
 
@@ -172,7 +170,7 @@ def attend_partial_blockwise(
                                         scale=scale, mask=m))
         # pin the schedule: without this XLA is free to materialize every
         # block's score matrix before any merge, defeating the blocking
-        acc = Partial(*optimization_barrier(tuple(acc)))
+        acc = Partial(*jax.lax.optimization_barrier(tuple(acc)))
     return acc
 
 

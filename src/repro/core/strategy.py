@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 from . import planner
 from .collectives import GroupLayout
 from .ring import ring_attention
@@ -67,10 +65,10 @@ class SPConfig:
     attn_kv_block: int | None = None
     # Comm lowering (DESIGN.md §8.1): "xla" = ppermute + barrier, overlap
     # left to XLA's scheduler; "pallas" = in-kernel DMA + semaphores (the
-    # fused ring_flash path).  kernel_interpret runs the Pallas branch in
-    # interpreter mode — required on CPU (the CI path), off on real TPUs.
+    # fused ring_flash path).  kernel_interpret: None follows the platform
+    # (compat.pallas_interpret — interpreted on CPU, compiled on a TPU).
     comm_backend: str = "xla"
-    kernel_interpret: bool = True
+    kernel_interpret: bool | None = None
     # Hierarchical a2a (DESIGN.md §8.2): decompose every Ulysses
     # all-to-all into an intra-machine exchange plus staged inter-machine
     # hops whenever the Ulysses groups span machines (engages only when
@@ -143,7 +141,7 @@ def resolve_layout(
 
 
 def _usp_like(q, k, v, layout: GroupLayout, *, scale, causal, window, unroll,
-              kv_block=None, backend="xla", interpret=True, wire_dtype=None):
+              kv_block=None, backend="xla", interpret=None, wire_dtype=None):
     """Shared body for usp/swift/ulysses/ring: monolithic Ulysses gather →
     Ring Attention → scatter.  The layout decides which boundary each
     technique crosses (that single bit is the paper's §4.2 contribution)."""
@@ -208,7 +206,7 @@ def sp_attention(
             wire_dtype=cfg.a2a_wire_dtype,
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q, k, v: body(q, k, v),
         mesh=mesh,
         in_specs=(spec, spec, spec),

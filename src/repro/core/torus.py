@@ -89,7 +89,7 @@ def torus_attention(
     fused_pull_q: bool = False,
     kv_block: int | None = None,
     backend: str = "xla",
-    interpret: bool = True,
+    interpret: bool | None = None,
     wire_dtype: str | None = None,
 ) -> jax.Array:
     """Full SwiftFusion attention with the Torus schedule; returns O in the
@@ -102,7 +102,7 @@ def torus_attention(
     ``backend="pallas"`` lowers every transfer through the Pallas channel
     backend (semaphore-tracked puts, DESIGN.md §8.1) and runs each
     per-stage RINGATTN through the fused ring_flash kernel;
-    ``interpret`` selects interpreter mode (the CPU CI path).
+    ``interpret`` selects interpreter mode (None follows the platform).
 
     ``fused_pull_q`` is a beyond-paper optimization (EXPERIMENTS.md §Perf):
     Algorithm 1 invokes RINGATTN once per Pull-Q stage, re-circulating the

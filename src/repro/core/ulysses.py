@@ -43,7 +43,7 @@ def group_positions(layout: GroupLayout, shard_len: int, ring_r) -> jax.Array:
 
 def gather_qkv(
     q: jax.Array, k: jax.Array, v: jax.Array, layout: GroupLayout,
-    *, backend: str = "xla", interpret: bool = True,
+    *, backend: str = "xla", interpret: bool | None = None,
     wire_dtype: str | None = None,
 ) -> Gathered:
     """The first three all-to-alls of Ulysses Attention.  ``wire_dtype``
@@ -66,7 +66,7 @@ def gather_qkv(
 
 
 def scatter_o(o: jax.Array, layout: GroupLayout, *, backend: str = "xla",
-              interpret: bool = True,
+              interpret: bool | None = None,
               wire_dtype: str | None = None) -> jax.Array:
     """The fourth all-to-all: restore O from [B, P_u*Ls, H/P_u, D] to the
     original [B, Ls, H, D] sequence sharding."""

@@ -18,7 +18,10 @@ scratch, and divides by ``l`` only when ``finalize`` is set (FA2, eq. 3).
 Grid: (batch·heads, Lq/block_q, Lk/block_k); the KV dimension is the
 innermost "arbitrary" (sequential) axis, so the running (m, l, acc) state
 lives in VMEM scratch across KV iterations.  GQA is handled by the k/v
-index_map (kv head = q head // group) — no KV repetition in HBM.
+index_map (kv head = q head // group) — no KV repetition in HBM.  Inside
+the call the (l, m) state is ``[BH, Lq, 1]``: the last two dims of a TPU
+block must tile by (8, 128) or span the array, which ``(block_q, 1)``
+does and a squeezed-head ``(block_q,)`` row does not.
 """
 from __future__ import annotations
 
@@ -29,7 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
+from ..compat import pallas_interpret
 
 NEG_INF = float("-inf")
 DEFAULT_BLOCK_Q = 128
@@ -49,8 +52,8 @@ def _kernel(
     def _init():
         if has_state:
             acc_s[...] = oin_ref[...].astype(jnp.float32)
-            l_s[...] = lin_ref[...].astype(jnp.float32)[:, None]
-            m_s[...] = min_ref[...].astype(jnp.float32)[:, None]
+            l_s[...] = lin_ref[...].astype(jnp.float32)
+            m_s[...] = min_ref[...].astype(jnp.float32)
         else:
             acc_s[...] = jnp.zeros_like(acc_s)
             l_s[...] = jnp.zeros_like(l_s)
@@ -97,8 +100,8 @@ def _kernel(
             o_ref[...] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
         else:
             o_ref[...] = acc.astype(o_ref.dtype)
-        l_ref[...] = l[:, 0].astype(l_ref.dtype)
-        m_ref[...] = m_s[...][:, 0].astype(m_ref.dtype)
+        l_ref[...] = l.astype(l_ref.dtype)
+        m_ref[...] = m_s[...].astype(m_ref.dtype)
 
 
 def flash_mqkv(
@@ -116,7 +119,7 @@ def flash_mqkv(
     finalize: bool = True,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ):
     """Core pallas_call.  Lq % block_q == 0 and Lk % block_k == 0 required
     (ops.flash_attention pads).  Returns (o, l, m); o normalized iff
@@ -135,14 +138,16 @@ def flash_mqkv(
     if state is None:
         # dummies (never read — has_state=False skips them); keep them tiny
         o_in = jnp.zeros((bh, block_q, d), jnp.float32)
-        l_in = jnp.zeros((bh, block_q), jnp.float32)
-        m_in = jnp.zeros((bh, block_q), jnp.float32)
+        l_in = jnp.zeros((bh, block_q, 1), jnp.float32)
+        m_in = jnp.zeros((bh, block_q, 1), jnp.float32)
         oin_spec = pl.BlockSpec((None, block_q, d), lambda h, qi, ki: (h, 0, 0))
-        lin_spec = pl.BlockSpec((None, block_q), lambda h, qi, ki: (h, 0))
+        lin_spec = pl.BlockSpec((None, block_q, 1), lambda h, qi, ki: (h, 0, 0))
     else:
         o_in, l_in, m_in = state
+        l_in, m_in = l_in[..., None], m_in[..., None]
         oin_spec = pl.BlockSpec((None, block_q, d), lambda h, qi, ki: (h, qi, 0))
-        lin_spec = pl.BlockSpec((None, block_q), lambda h, qi, ki: (h, qi))
+        lin_spec = pl.BlockSpec((None, block_q, 1),
+                                lambda h, qi, ki: (h, qi, 0))
 
     kernel = functools.partial(
         _kernel, scale=scale, causal=causal, window=window,
@@ -150,8 +155,8 @@ def flash_mqkv(
     )
     out_shape = (
         jax.ShapeDtypeStruct((bh, lq, d), q.dtype if finalize else jnp.float32),
-        jax.ShapeDtypeStruct((bh, lq), jnp.float32),
-        jax.ShapeDtypeStruct((bh, lq), jnp.float32),
+        jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32),
+        jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32),
     )
     grid = (bh, n_q, n_k)
     o, l, m = pl.pallas_call(
@@ -171,8 +176,8 @@ def flash_mqkv(
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, d), lambda h, qi, ki: (h, qi, 0)),
-            pl.BlockSpec((None, block_q), lambda h, qi, ki: (h, qi)),
-            pl.BlockSpec((None, block_q), lambda h, qi, ki: (h, qi)),
+            pl.BlockSpec((None, block_q, 1), lambda h, qi, ki: (h, qi, 0)),
+            pl.BlockSpec((None, block_q, 1), lambda h, qi, ki: (h, qi, 0)),
         ],
         out_shape=out_shape,
         scratch_shapes=[
@@ -180,9 +185,9 @@ def flash_mqkv(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(q, k, v, qp2, kp2, o_in, l_in, m_in)
-    return o, l, m
+    return o, l[..., 0], m[..., 0]

@@ -140,7 +140,7 @@ def flash_attention(
     scale: float | None = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = False,
+    interpret: bool | None = None,
     backend: str = "pallas",
     fused: bool = False,
 ) -> jax.Array:
@@ -174,7 +174,7 @@ def flash_attention_segments(
     scale: float | None = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = False,
+    interpret: bool | None = None,
     backend: str = "pallas",
     fused: bool = False,
 ) -> jax.Array:

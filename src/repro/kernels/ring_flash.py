@@ -10,10 +10,8 @@ that consumes the current KV chunk also issues its forwarding copy —
   * at the **first grid step**, before any compute, the DMA of the whole
     (K, V) chunk into the forward buffers is started
     (``pltpu.make_async_copy`` — a *local* copy into the RDMA staging
-    buffer; the inter-device hop itself is ``Channel.put_fused``'s
-    ppermute on every branch, with true in-kernel
-    ``make_async_remote_copy`` forwarding left as the ROADMAP hardware
-    item);
+    buffer; the inter-device hop itself is ``Channel.put_fused``, which
+    hands the staged buffers to ``pallas_backend.deliver``);
   * every (q-block, kv-block) grid step runs the unchanged flash_mqkv
     online-softmax body while the copy rides the DMA engines;
   * only at the **last grid step**, after the final output write, does the
@@ -24,8 +22,8 @@ The attention math is byte-for-byte flash_mqkv's (its kernel body is
 invoked on the same refs), so (o, l, m) parity with ``flash_mqkv`` is
 structural; the property tests in tests/test_ring_flash.py lock it in.
 The forwarded buffers are returned to the caller; ``core/ring.py`` hands
-them to ``Channel.put_fused`` for the wire move (emulated with ppermute
-on CPU CI — see DESIGN.md §8.1 interpret caveats).
+them to ``Channel.put_fused`` for the wire move (a remote copy on a TPU,
+emulated with ppermute on CPU — see DESIGN.md §8.1).
 """
 from __future__ import annotations
 
@@ -36,7 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
+from ..compat import pallas_interpret
 from .flash_mqkv import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, _kernel as _flash_body
 
 
@@ -91,7 +89,7 @@ def ring_flash_step(
     finalize: bool = True,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ):
     """One fused ring step.  Same contract as ``flash_mqkv`` plus the
     forwarded chunk: returns ``(o, l, m), (k_fwd, v_fwd)`` where the
@@ -110,14 +108,16 @@ def ring_flash_step(
     kp2 = k_pos.reshape(1, lk)
     if state is None:
         o_in = jnp.zeros((bh, block_q, d), jnp.float32)
-        l_in = jnp.zeros((bh, block_q), jnp.float32)
-        m_in = jnp.zeros((bh, block_q), jnp.float32)
+        l_in = jnp.zeros((bh, block_q, 1), jnp.float32)
+        m_in = jnp.zeros((bh, block_q, 1), jnp.float32)
         oin_spec = pl.BlockSpec((None, block_q, d), lambda h, qi, ki: (h, 0, 0))
-        lin_spec = pl.BlockSpec((None, block_q), lambda h, qi, ki: (h, 0))
+        lin_spec = pl.BlockSpec((None, block_q, 1), lambda h, qi, ki: (h, 0, 0))
     else:
         o_in, l_in, m_in = state
+        l_in, m_in = l_in[..., None], m_in[..., None]
         oin_spec = pl.BlockSpec((None, block_q, d), lambda h, qi, ki: (h, qi, 0))
-        lin_spec = pl.BlockSpec((None, block_q), lambda h, qi, ki: (h, qi))
+        lin_spec = pl.BlockSpec((None, block_q, 1),
+                                lambda h, qi, ki: (h, qi, 0))
 
     kernel = functools.partial(
         _ring_kernel, scale=scale, causal=causal, window=window,
@@ -125,8 +125,8 @@ def ring_flash_step(
     )
     out_shape = (
         jax.ShapeDtypeStruct((bh, lq, d), q.dtype if finalize else jnp.float32),
-        jax.ShapeDtypeStruct((bh, lq), jnp.float32),
-        jax.ShapeDtypeStruct((bh, lq), jnp.float32),
+        jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32),
+        jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32),
         jax.ShapeDtypeStruct(k.shape, k.dtype),
         jax.ShapeDtypeStruct(v.shape, v.dtype),
     )
@@ -144,15 +144,15 @@ def ring_flash_step(
             oin_spec,
             lin_spec,
             lin_spec,
-            pl.BlockSpec(memory_space=pltpu.ANY),  # DMA source: full K
-            pl.BlockSpec(memory_space=pltpu.ANY),  # DMA source: full V
+            pl.BlockSpec(memory_space=pl.ANY),  # DMA source: full K
+            pl.BlockSpec(memory_space=pl.ANY),  # DMA source: full V
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, d), lambda h, qi, ki: (h, qi, 0)),
-            pl.BlockSpec((None, block_q), lambda h, qi, ki: (h, qi)),
-            pl.BlockSpec((None, block_q), lambda h, qi, ki: (h, qi)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # forward buffer: K
-            pl.BlockSpec(memory_space=pltpu.ANY),  # forward buffer: V
+            pl.BlockSpec((None, block_q, 1), lambda h, qi, ki: (h, qi, 0)),
+            pl.BlockSpec((None, block_q, 1), lambda h, qi, ki: (h, qi, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # forward buffer: K
+            pl.BlockSpec(memory_space=pl.ANY),  # forward buffer: V
         ],
         out_shape=out_shape,
         scratch_shapes=[
@@ -161,11 +161,11 @@ def ring_flash_step(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        compiler_params=tpu_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             # DMA issue/drain at fixed grid steps imposes an execution
             # order; no parallel dimension semantics for the fused kernel
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(q, k, v, qp2, kp2, o_in, l_in, m_in, k, v)
-    return (o, l, m), (k_fwd, v_fwd)
+    return (o, l[..., 0], m[..., 0]), (k_fwd, v_fwd)

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
+from ..compat import pallas_interpret
 
 EPS = 1e-6
 DEFAULT_CHUNK = 64
@@ -86,7 +86,7 @@ def rwkv6_wkv(
     u: jax.Array,  # [BH, N] per-head bonus
     *,
     chunk: int = DEFAULT_CHUNK,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Returns o [BH, L, N] (f32)."""
     bh, l, n = r.shape
@@ -105,8 +105,8 @@ def rwkv6_wkv(
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((bh, l, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(r, k, v, w, u2)

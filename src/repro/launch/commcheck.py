@@ -62,13 +62,14 @@ def main() -> int:
     from ..models.dit import COND_TOKENS, dit_forward_displaced
     from ..serving import SamplerConfig
     from ..serving.sampler import hybrid_state_shape
+    from ..compat import make_mesh
     from .mesh import make_hybrid_mesh
 
     assert len(jax.devices()) == 8, "commcheck needs 8 (fake) devices"
     reports = []
 
     # --- 1. swift_torus attention: torus hops + ring rotations ----------
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     sp = SPConfig(strategy="swift_torus", sp_axes=("pod", "model"),
                   batch_axes=("data",))
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)

@@ -56,6 +56,7 @@ from ..serving import (
     SamplerConfig,
     Tracker,
 )
+from .cache import enable_compile_cache
 from .mesh import make_host_mesh, make_production_mesh
 
 
@@ -96,6 +97,7 @@ def main():
     if args.profile is not None and args.metrics is not None:
         ap.error("--profile already streams metrics records; "
                  "give one output path, not both")
+    enable_compile_cache()
 
     if args.mesh == "host":
         mesh = make_host_mesh(model=args.model, data=args.data)

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 from ..configs.base import ModelConfig
 from . import ssm
 from .blocks import (
@@ -161,7 +159,7 @@ def _token_shift(x: jax.Array, ctx: ParallelContext, prev: jax.Array | None):
         return jnp.concatenate([recv, xl[:, :-1]], axis=1)
 
     ba = ctx.sp.batch_axes
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=P(ba, sp_axes, None), out_specs=P(ba, sp_axes, None),
         check_vma=False,
@@ -217,7 +215,7 @@ def _distributed_scan_rwkv(r, k, v, w, u, ctx: ParallelContext):
         return ssm.rwkv6_apply_influence(res.out, res.infl, s_in)
 
     spec = P(ba, sp_axes, None, None)
-    fn = shard_map(body, mesh=ctx.mesh, in_specs=(spec,) * 4,
+    fn = jax.shard_map(body, mesh=ctx.mesh, in_specs=(spec,) * 4,
                        out_specs=spec, check_vma=False)
     return fn(r, k, v, w)
 
@@ -310,7 +308,7 @@ def _hymba_ssd(x, p, cfg, ctx, cache):
 
         s4 = P(ba, sp_axes, None, None)
         s3 = P(ba, sp_axes, None)
-        fn = shard_map(body, mesh=ctx.mesh, in_specs=(s4, s3, s4, s4),
+        fn = jax.shard_map(body, mesh=ctx.mesh, in_specs=(s4, s3, s4, s4),
                            out_specs=s4, check_vma=False)
         o = fn(xs, dt, bm, cm).astype(x.dtype)
         nc = None

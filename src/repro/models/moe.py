@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 from .blocks import ParallelContext, ParamBuilder, Params
 
 
@@ -235,7 +233,7 @@ def moe_block(
             aux = lax.pmean(aux, all_axes)
             return y.reshape(x.shape), aux
 
-        fn = shard_map(body, mesh=mesh, in_specs=in_specs,
+        fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                            out_specs=(xspec, P()), check_vma=False)
         return fn(x, p["router"]["w"], p["wi_gate"], p["wi_up"], p["wo"])
 
@@ -252,7 +250,7 @@ def moe_block(
         aux = lax.pmean(lax.pmean(aux, ep_axes), tuple(a for a in all_axes if a not in ep_axes))
         return y.reshape(x.shape), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(xspec, P(None, None), espec(None, None), espec(None, None),

@@ -6,23 +6,16 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-try:  # the container image has no hypothesis; fall back to the mini shim
-    import hypothesis  # noqa: F401
-except ImportError:  # pragma: no cover - depends on environment
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _mini_hypothesis
-
-    sys.modules["hypothesis"] = _mini_hypothesis
-    sys.modules["hypothesis.strategies"] = _mini_hypothesis.strategies
-
 import jax
 import pytest
+
+from repro.compat import make_mesh
 
 
 @pytest.fixture(scope="session")
 def mesh1():
     """1-device (data=1, model=1) mesh for smoke tests."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.fixture(scope="session")

@@ -15,12 +15,14 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 import jax
 import pytest
 
+from repro.compat import make_mesh
+
 
 @pytest.fixture(scope="session")
 def mesh8():
     """(pod=2, data=2, model=2) production-mesh miniature."""
     assert len(jax.devices()) == 8, "inner suite needs 8 fake devices"
-    return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    return make_mesh((2, 2, 2), ("pod", "data", "model"))
 
 
 @pytest.fixture(scope="session")

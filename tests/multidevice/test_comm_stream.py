@@ -12,7 +12,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro import comm
-from repro.compat import shard_map
 from repro.configs import get_reduced
 from repro.core import SPConfig, sp_attention
 from repro.core.collectives import (
@@ -36,8 +35,8 @@ def _layout(p_u, p_r):
 
 
 def _smap(fn, mesh, spec):
-    return shard_map(fn, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                     check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                         check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -71,10 +70,10 @@ def test_staged_all_to_all_matches_monolithic(mesh8, rng):
         return monolithic_all_to_all(xs, layout, split_axis=2)
 
     out_spec = P(None, None, SP_AXES, None, None)
-    f1 = shard_map(staged, mesh=mesh8, in_specs=(spec,), out_specs=out_spec,
-                   check_vma=False)
-    f2 = shard_map(monolithic, mesh=mesh8, in_specs=(spec,),
-                   out_specs=out_spec, check_vma=False)
+    f1 = jax.shard_map(staged, mesh=mesh8, in_specs=(spec,), out_specs=out_spec,
+                       check_vma=False)
+    f2 = jax.shard_map(monolithic, mesh=mesh8, in_specs=(spec,),
+                       out_specs=out_spec, check_vma=False)
     np.testing.assert_array_equal(np.asarray(f1(x)), np.asarray(f2(x)))
 
 
@@ -242,8 +241,8 @@ def test_staged_a2a_validates_under_pallas(mesh8, rng):
         return comm.staged_all_to_all(xs, layout, split_axis=2,
                                       backend="pallas", interpret=True)
 
-    f = shard_map(staged, mesh=mesh8, in_specs=(spec,), out_specs=out_spec,
-                  check_vma=False)
+    f = jax.shard_map(staged, mesh=mesh8, in_specs=(spec,), out_specs=out_spec,
+                      check_vma=False)
     with comm.record("a2a_pallas") as tr:
         lowered = jax.jit(f).lower(x)
     # P_u - 1 = 3 wire stages (the diagonal chunk never leaves the device)
@@ -254,9 +253,9 @@ def test_staged_a2a_validates_under_pallas(mesh8, rng):
     assert report.ok, report.summary()
     sem = comm.validate_semaphores(tr)
     assert sem.ok, sem.summary()
-    ref = shard_map(lambda xs: monolithic_all_to_all(xs, layout, split_axis=2),
-                    mesh=mesh8, in_specs=(spec,), out_specs=out_spec,
-                    check_vma=False)
+    ref = jax.shard_map(lambda xs: monolithic_all_to_all(xs, layout, split_axis=2),
+                        mesh=mesh8, in_specs=(spec,), out_specs=out_spec,
+                        check_vma=False)
     np.testing.assert_allclose(np.asarray(jax.jit(f)(x)),
                                np.asarray(ref(x)), rtol=1e-6, atol=1e-6)
 
@@ -315,10 +314,10 @@ def test_hier_a2a_bit_compatible_with_monolithic(backend, mesh8, rng):
     def flat_fn(xs):
         return monolithic_all_to_all(xs, flat, split_axis=2)
 
-    f_h = shard_map(hier_fn, mesh=mesh8, in_specs=(spec,),
-                    out_specs=out_spec, check_vma=False)
-    f_f = shard_map(flat_fn, mesh=mesh8, in_specs=(spec,),
-                    out_specs=out_spec, check_vma=False)
+    f_h = jax.shard_map(hier_fn, mesh=mesh8, in_specs=(spec,),
+                        out_specs=out_spec, check_vma=False)
+    f_f = jax.shard_map(flat_fn, mesh=mesh8, in_specs=(spec,),
+                        out_specs=out_spec, check_vma=False)
     np.testing.assert_allclose(np.asarray(jax.jit(f_h)(x)),
                                np.asarray(jax.jit(f_f)(x)),
                                rtol=0, atol=1e-5)
@@ -360,10 +359,10 @@ def test_hier_a2a_fp8_wire_close_to_exact(mesh8, rng):
     def exact(xs):
         return monolithic_all_to_all(xs, layout, split_axis=2)
 
-    f8 = shard_map(fp8, mesh=mesh8, in_specs=(spec,), out_specs=out_spec,
-                   check_vma=False)
-    fx = shard_map(exact, mesh=mesh8, in_specs=(spec,), out_specs=out_spec,
-                   check_vma=False)
+    f8 = jax.shard_map(fp8, mesh=mesh8, in_specs=(spec,), out_specs=out_spec,
+                       check_vma=False)
+    fx = jax.shard_map(exact, mesh=mesh8, in_specs=(spec,), out_specs=out_spec,
+                       check_vma=False)
     got, ref = np.asarray(jax.jit(f8)(x)), np.asarray(jax.jit(fx)(x))
     assert not np.array_equal(got, ref), "fp8 wire did not engage"
     np.testing.assert_allclose(got, ref, rtol=0.08, atol=0.08)
@@ -387,8 +386,8 @@ def test_hier_a2a_trace_declares_and_validates_inter_overlap(mesh8, rng):
         return (monolithic_all_to_all(xs, layout, split_axis=2),
                 monolithic_all_to_all(ys, layout, split_axis=2))
 
-    f = shard_map(fn, mesh=mesh8, in_specs=(spec, spec),
-                  out_specs=(out_spec, out_spec), check_vma=False)
+    f = jax.shard_map(fn, mesh=mesh8, in_specs=(spec, spec),
+                      out_specs=(out_spec, out_spec), check_vma=False)
     with comm.record("hier") as tr:
         lowered = jax.jit(f).lower(x, y)
     chans = [e.channel for e in tr.events]
@@ -425,8 +424,8 @@ def test_hier_a2a_profiler_measures_inter_hops(backend, mesh8, rng):
         return monolithic_all_to_all(xs, layout, split_axis=2,
                                      backend=backend, interpret=True)
 
-    f = shard_map(fn, mesh=mesh8, in_specs=(spec,), out_specs=out_spec,
-                  check_vma=False)
+    f = jax.shard_map(fn, mesh=mesh8, in_specs=(spec,), out_specs=out_spec,
+                      check_vma=False)
     prof = comm.CommProfiler()
     with comm.profile(prof):
         out = jax.jit(f)(x)
@@ -504,6 +503,6 @@ def test_staged_chunk_order_matches_group_positions(p_u, outer, mesh8):
         got = stacked[:, 0, :, 0, 0]  # [P_u, Ls] of encoded positions
         return jnp.max(jnp.abs(got - want)).reshape(1)
 
-    f = shard_map(check, mesh=mesh8, in_specs=(spec,),
-                  out_specs=P(SP_AXES), check_vma=False)
+    f = jax.shard_map(check, mesh=mesh8, in_specs=(spec,),
+                      out_specs=P(SP_AXES), check_vma=False)
     assert np.asarray(jax.jit(f)(x)).max() == 0.0

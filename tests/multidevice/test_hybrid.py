@@ -10,7 +10,7 @@ import pytest
 
 from repro.configs import get_reduced
 from repro.core import PipelineConfig, SPConfig
-from repro.launch.mesh import make_hybrid_mesh
+from repro.launch.mesh import make_host_mesh, make_hybrid_mesh
 from repro.models import ParallelContext, get_model
 from repro.models.dit import COND_TOKENS
 from repro.serving import DiTRequest, DiTServer, SamplerConfig, sample
@@ -49,7 +49,7 @@ def test_hybrid_matches_single_device_reference(setup):
     """cfg-parallel + swift_torus on the hybrid mesh == plain sequential
     CFG on one device (warm pipeline => no staleness)."""
     cfg, params, _, cond = setup
-    ref = _sample(cfg, params, cond, jax.make_mesh((1, 1), ("data", "model")),
+    ref = _sample(cfg, params, cond, make_host_mesh(),
                   SPConfig(strategy="full", sp_axes=("model",),
                            batch_axes=("data",)),
                   SamplerConfig(num_steps=3, guidance_scale=4.0))
@@ -66,7 +66,7 @@ def test_hybrid_matches_single_device_reference(setup):
 
 def test_hybrid_displaced_close_to_reference(setup):
     cfg, params, _, cond = setup
-    ref = _sample(cfg, params, cond, jax.make_mesh((1, 1), ("data", "model")),
+    ref = _sample(cfg, params, cond, make_host_mesh(),
                   SPConfig(strategy="full", sp_axes=("model",),
                            batch_axes=("data",)),
                   SamplerConfig(num_steps=4, guidance_scale=4.0))
@@ -91,7 +91,7 @@ def test_unguided_sampling_on_hybrid_mesh(setup):
                   batch_axes=("data",), cfg_axis="cfg", pp_axis="pipe")
     out = _sample(cfg, params, cond, mesh, sp, SamplerConfig(num_steps=2))
     assert bool(jnp.all(jnp.isfinite(out)))
-    ref = _sample(cfg, params, cond, jax.make_mesh((1, 1), ("data", "model")),
+    ref = _sample(cfg, params, cond, make_host_mesh(),
                   SPConfig(strategy="full", sp_axes=("model",),
                            batch_axes=("data",)),
                   SamplerConfig(num_steps=2))
@@ -109,7 +109,7 @@ def test_cfg_degree_4_on_4way_cfg_axis(setup):
         [cond, 2.0 * cond, -1.0 * cond, jnp.zeros_like(cond)], axis=0)
     conds = conds.reshape(4, 1, COND_TOKENS, cfg.d_model)
     ref = _sample(cfg, params, conds,
-                  jax.make_mesh((1, 1), ("data", "model")),
+                  make_host_mesh(),
                   SPConfig(strategy="full", sp_axes=("model",),
                            batch_axes=("data",)),
                   SamplerConfig(num_steps=2, cfg_weights=weights))

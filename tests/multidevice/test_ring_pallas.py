@@ -16,7 +16,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro import comm
-from repro.compat import shard_map
+from repro.compat import make_mesh
 from repro.core import MaskSpec, SPConfig, reference_attention, sp_attention
 from repro.core.collectives import GroupLayout
 from repro.core.ring import ring_attention
@@ -33,7 +33,7 @@ def _mk(seed, b, l, hq, hkv, d, dtype=jnp.float32):
 
 
 def _ring_mesh():
-    return jax.make_mesh((4, 2), ("sp", "data"))
+    return make_mesh((4, 2), ("sp", "data"))
 
 
 def _run_ring(mesh, layout, q, k, v, *, backend, causal=False, window=None,
@@ -62,13 +62,13 @@ def _run_ring(mesh, layout, q, k, v, *, backend, causal=False, window=None,
     spec = P(("data",), ("sp",), None, None)
     espec = P(("data",), None, None, None)
     if extra_chunk is not None:
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(spec, spec, spec, espec, espec), out_specs=spec,
             check_vma=False)
         return jax.jit(fn)(q, k, v, extra_chunk[0], extra_chunk[1])
-    fn = shard_map(body, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
-                   check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
+                       check_vma=False)
     return jax.jit(fn)(q, k, v)
 
 

@@ -1,0 +1,159 @@
+"""Compile rehearsals for a TPU v5e that is described, not attached.
+
+The TPU compiler is installed with jaxlib, so ``jax.jit(...).lower(...)
+.compile()`` against the devices of a described ``v5e:2x2`` topology
+raises what the chip's compiler would raise: block shapes that do not
+tile, kernels that cannot be partitioned, programs that do not fit HBM.
+Nothing runs, so these tests say nothing about results or times.
+
+The topology is described inside a module fixture (never at import: only
+one process may load the TPU library, and every xdist worker imports this
+file), and the persistent compilation cache is off around the compiles (a
+compile for a described chip is written to it but cannot be read back).
+"""
+import dataclasses
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from repro.comm.pallas_backend import _tpu_remote_put
+from repro.compat import make_mesh
+from repro.configs import get_config
+from repro.core import SPConfig
+from repro.kernels.flash_mqkv import flash_mqkv
+from repro.kernels.ring_flash import ring_flash_step
+from repro.models import ParallelContext, get_model
+from repro.models.dit import COND_TOKENS, LATENT_CHANNELS
+from repro.serving import SamplerConfig
+from repro.serving.sampler import sample_step
+
+HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
+TOKENS = 4096 + COND_TOKENS  # a 1024x1024 image's latents plus text
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs to /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("arch", ["flux-12b", "cogvideox-5b"])
+@pytest.mark.parametrize("fused", [False, True], ids=["flash_mqkv",
+                                                      "ring_flash"])
+def test_attention_kernel_compiles(one_chip, arch, fused):
+    """Both kernels at the DiTs' head geometry (24 heads x 128 / x 64),
+    bf16: flash_mqkv fresh, ring_flash with carried (O', l, m) state."""
+    cfg = get_config(arch)
+    bh, d = cfg.n_heads, cfg.resolved_head_dim
+    x = _sds((bh, TOKENS, d), jnp.bfloat16, one_chip)
+    pos = _sds((TOKENS,), jnp.int32, one_chip)
+    state = (_sds((bh, TOKENS, d), jnp.float32, one_chip),
+             _sds((bh, TOKENS), jnp.float32, one_chip),
+             _sds((bh, TOKENS), jnp.float32, one_chip))
+    if fused:
+        fn = functools.partial(ring_flash_step, finalize=False,
+                               interpret=False)
+        args = (x, x, x, pos, pos, state)
+        step = lambda q, k, v, qp, kp, st: fn(q, k, v, qp, kp, state=st)
+    else:
+        step = functools.partial(flash_mqkv, interpret=False)
+        args = (x, x, x, pos, pos)
+    compiled = jax.jit(step).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("mesh_shape,route", [((1, 4), ("model",)),
+                                              ((2, 2), ("data", "model"))])
+def test_remote_put_compiles(topo, mesh_shape, route):
+    """The TPU branch of the Pallas channel: an in-kernel remote copy
+    along a 4-rank ring inside shard_map — over one axis of a mesh whose
+    other axis is carried along, and over both axes jointly (the device
+    id is a coordinate over the route's axes)."""
+    from repro.comm.channel import shift_perm
+
+    mesh = make_mesh(mesh_shape, ("data", "model"), devices=topo.devices)
+    spec = P(None, route)
+    x = _sds((8, 4 * 1024), jnp.bfloat16, NamedSharding(mesh, spec))
+
+    def body(a, b):
+        return _tpu_remote_put((a, b), route, shift_perm(4))
+
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                       out_specs=(spec, spec), check_vma=False)
+    compiled = jax.jit(fn).lower(x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _flux_step(mesh, sp, tokens, sharding):
+    """The served sampler step of flux-12b at published widths, depth cut
+    to 2 blocks, bf16; shapes only."""
+    cfg = dataclasses.replace(get_config("flux-12b"), n_layers=2)
+    bundle = get_model(cfg)
+    params = jax.eval_shape(
+        lambda: bundle.init(cfg, jax.random.PRNGKey(0), 1)[0])
+    params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, sharding), params)
+    ctx = ParallelContext(mesh, sp, "prefill")
+    sc = SamplerConfig(num_steps=4)
+    x = _sds((1, tokens, LATENT_CHANNELS), jnp.bfloat16, sharding)
+    cond = _sds((1, COND_TOKENS, cfg.d_model), jnp.bfloat16, sharding)
+    t = _sds((), jnp.float32, sharding)
+
+    def step(params, x, cond, t):
+        return sample_step(params, cfg, ctx, x, cond, t, 0.25, sc)
+
+    return jax.jit(step).lower(params, x, cond, t).compile()
+
+
+def _assert_fits(compiled):
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < HBM_BYTES
+
+
+def test_flux_step_one_chip(topo, one_chip):
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    sp = SPConfig(strategy="full", sp_axes=("model",), batch_axes=("data",))
+    compiled = _flux_step(mesh, sp, 4096, one_chip)
+    _assert_fits(compiled)
+
+
+@pytest.mark.parametrize("strategy,backend", [("swift_torus", "xla"),
+                                              ("ring", "pallas")])
+def test_flux_step_sp4(topo, monkeypatch, strategy, backend):
+    """SP=4 at 16384 tokens (a 2048x2048 image).  The Pallas ring takes
+    its TPU branches (compiled ring_flash, in-kernel remote put) only
+    where JAX reports a TPU backend, so the test reports one."""
+    if backend == "pallas":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = make_mesh((1, 4), ("data", "model"), devices=topo.devices)
+    sp = SPConfig(strategy=strategy, sp_axes=("model",),
+                  batch_axes=("data",), comm_backend=backend)
+    compiled = _flux_step(mesh, sp, 16384, NamedSharding(mesh, P()))
+    _assert_fits(compiled)
+    if backend == "pallas":
+        assert "tpu_custom_call" in compiled.as_text()

@@ -22,7 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import comm
 from repro.comm import pallas_backend
-from repro.compat import shard_map
+from repro.compat import make_mesh
 from repro.core.collectives import GroupLayout
 
 N_DEV = jax.device_count()
@@ -33,7 +33,7 @@ UNEVEN_SHAPES = [(3, 5), (7, 3, 2), (1, 13)]  # per-shard, no tile alignment
 
 
 def _mesh_sp():
-    return jax.make_mesh((N_DEV,), ("sp",))
+    return make_mesh((N_DEV,), ("sp",))
 
 
 def _sharded(key, shape, dtype):
@@ -44,7 +44,7 @@ def _sharded(key, shape, dtype):
 
 def _run_program(mesh, fn, *xs):
     spec = P("sp")
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(spec,) * len(xs), out_specs=spec,
         check_vma=False))(*xs)
 
@@ -166,10 +166,10 @@ def test_staged_ungroup_parity(dtype):
 
 
 # ---------------------------------------------------------------------------
-# semaphore pairing of randomly generated Stream programs (mini-hypothesis)
+# semaphore pairing of randomly generated Stream programs (hypothesis)
 # ---------------------------------------------------------------------------
 
-from hypothesis import given, settings  # noqa: E402  (shim via conftest)
+from hypothesis import given, settings  # noqa: E402
 from hypothesis import strategies as st  # noqa: E402
 
 

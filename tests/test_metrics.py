@@ -16,8 +16,7 @@ plus the counter-migration regression: the legacy attribute surface
 on a mixed-resolution serve — pinned here so future sinks can't drift
 from the attributes tests and launchers consume.
 
-All host-side (no jax, no mesh); property tests use seeded
-mini-hypothesis (see tests/_mini_hypothesis.py)."""
+All host-side (no jax, no mesh); property tests use hypothesis."""
 import dataclasses
 import json
 import pathlib

@@ -22,6 +22,9 @@ HERE = os.path.dirname(__file__)
 def _run_inner(marker_expr: str) -> None:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # this process has already loaded JAX: on a TPU host it holds the chip,
+    # so the child must stay on the CPU
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(HERE, "..", "src")
     proc = subprocess.run(
         [sys.executable, "-m", "pytest",

@@ -3,7 +3,7 @@
 The ring_flash kernel reuses flash_mqkv's body on the same refs, so the
 attention outputs must agree *bitwise* on every configuration — random
 chunk counts, k_pos = -1 padding layouts, causal/window masks, GQA, and
-carried (O', l, m) state (mini-hypothesis sweeps).  The forwarded KV
+carried (O', l, m) state (hypothesis sweeps).  The forwarded KV
 buffers must equal the inputs (the in-kernel DMA is a copy).
 
 The dispatch regression pins kernels/ops.py's static-arg discipline: all

@@ -10,7 +10,7 @@ and the preemption invariants (ISSUE 5) —
 
 All host-side: the property tests drive the same scheduler objects and
 step-granular simulation the engine and the replay harness use, on
-simulated time (seeded mini-hypothesis, no wall clock)."""
+simulated time (hypothesis, no wall clock)."""
 import dataclasses
 import random
 
