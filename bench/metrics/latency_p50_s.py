@@ -1,0 +1,7 @@
+"""Median latency (s) over the requests due in the window."""
+from bench import readers
+
+
+def read(run):
+    lat = readers.latencies(run)
+    return readers.percentile(lat, 50) if lat else None
