@@ -278,37 +278,43 @@ def attention(
     causal = cfg.causal if causal is None else causal
     src = x if xkv is None else xkv
 
-    q = linear(x, p["wq"]).reshape(b_, l_, hq, hd)
-    k = linear(src, p["wk"]).reshape(b_, src.shape[1], hkv, hd)
-    v = linear(src, p["wv"]).reshape(b_, src.shape[1], hkv, hd)
-    if xkv is None:  # no rope on cross-attention
-        q, k = apply_rope(q, k, positions, variant=cfg.rope, theta=cfg.rope_theta,
-                          rope_pct=cfg.rope_pct)
+    # named scopes (qkv, attn, attn_out; mlp in ``mlp``) are metadata only:
+    # they prefix the ops' names in the device trace, so a profile splits
+    # a block's device time by part
+    with jax.named_scope("qkv"):
+        q = linear(x, p["wq"]).reshape(b_, l_, hq, hd)
+        k = linear(src, p["wk"]).reshape(b_, src.shape[1], hkv, hd)
+        v = linear(src, p["wv"]).reshape(b_, src.shape[1], hkv, hd)
+        if xkv is None:  # no rope on cross-attention
+            q, k = apply_rope(q, k, positions, variant=cfg.rope,
+                              theta=cfg.rope_theta, rope_pct=cfg.rope_pct)
 
-    if extra_kv is not None:
-        assert not ctx.decode and xkv is None
-        assert not causal and window is None, (
-            "displaced attention is DiT-only (bidirectional, unwindowed)")
-        o = displaced_attention(q, k, v, extra_kv[0], extra_kv[1])
-        new_cache = None
-    elif ctx.decode and xkv is None:
-        assert kv_cache is not None and cur_index is not None
-        kc, vc = kv_cache
-        o, kc, vc = decode_attention(
-            q, kc, vc, k, v, cur_index,
-            mesh=ctx.mesh, cfg=ctx.sp, window=window,
-        )
-        new_cache = (kc, vc)
-    elif ctx.decode:  # cross-attention during decode: q len 1 vs full memory
-        o = sp_attention(q, k, v, mesh=ctx.mesh, cfg=_xattn_cfg(ctx.sp),
-                         causal=False, window=None)
-        new_cache = kv_cache
-    else:
-        o = sp_attention(q, k, v, mesh=ctx.mesh, cfg=ctx.sp, causal=causal,
-                         window=_static_window(window))
-        new_cache = None
-    o = o.reshape(b_, l_, hq * hd)
-    out = linear(o, p["wo"])
+    with jax.named_scope("attn"):
+        if extra_kv is not None:
+            assert not ctx.decode and xkv is None
+            assert not causal and window is None, (
+                "displaced attention is DiT-only (bidirectional, unwindowed)")
+            o = displaced_attention(q, k, v, extra_kv[0], extra_kv[1])
+            new_cache = None
+        elif ctx.decode and xkv is None:
+            assert kv_cache is not None and cur_index is not None
+            kc, vc = kv_cache
+            o, kc, vc = decode_attention(
+                q, kc, vc, k, v, cur_index,
+                mesh=ctx.mesh, cfg=ctx.sp, window=window,
+            )
+            new_cache = (kc, vc)
+        elif ctx.decode:  # cross-attention during decode: q len 1 vs memory
+            o = sp_attention(q, k, v, mesh=ctx.mesh, cfg=_xattn_cfg(ctx.sp),
+                             causal=False, window=None)
+            new_cache = kv_cache
+        else:
+            o = sp_attention(q, k, v, mesh=ctx.mesh, cfg=ctx.sp,
+                             causal=causal, window=_static_window(window))
+            new_cache = None
+    with jax.named_scope("attn_out"):
+        o = o.reshape(b_, l_, hq * hd)
+        out = linear(o, p["wo"])
     if return_kv:
         return out, new_cache, (k, v)
     return out, new_cache
@@ -342,6 +348,7 @@ def init_mlp(b: ParamBuilder, cfg, prefix: str = "mlp", d_ff: int | None = None,
                 scale=ff ** -0.5 / (2 * cfg.n_layers) ** 0.5)
 
 
+@jax.named_scope("mlp")
 def mlp(x: jax.Array, p: Params, cfg) -> jax.Array:
     if cfg.act == "swiglu":
         h = jax.nn.silu(linear(x, p["wi_gate"])) * linear(x, p["wi_up"])

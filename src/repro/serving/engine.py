@@ -33,7 +33,7 @@ from ..core import SPConfig, plan_hybrid
 from ..core.comm_model import NetworkModel
 from ..models import ParallelContext, get_model, param_shardings
 from ..models.dit import COND_TOKENS, LATENT_CHANNELS
-from .metrics import Tracker
+from .metrics import Tracker, profiler_annotation
 from .sampler import (
     SamplerConfig,
     hybrid_sample_step,
@@ -333,8 +333,23 @@ class DiTServer:
         blocked on and wall-clocked individually, the preemption policy
         runs between steps (a parked batch returns [] and its requests
         re-enter the queue), and completed batches feed the online
-        calibrator.  Without it, the loop is the PR-3 sync-free one."""
-        adm = self.scheduler.next_batch(time.time(), flush=flush)
+        calibrator.  Without it, the loop is the PR-3 sync-free one.
+
+        The call is an ``engine.run_once`` tracker span holding, in order,
+        ``engine.admit``, ``engine.prepare``, one ``engine.dispatch`` a
+        sampler step, ``engine.sync`` (one a step when measured) and
+        ``engine.finish``; the inner ones are tagged with the batch's
+        ``rows`` (its requests, padding excluded) and ``seq``.  Under a
+        profiler session they sit on the device trace's clock (DESIGN.md
+        §12)."""
+        with self.tracker.span("engine.run_once"):
+            return self._run_admission(flush)
+
+    def _run_admission(self, flush: bool) -> list[DiTResult]:
+        """``run_once``'s body: admit, step and finish one batch."""
+        tr = self.tracker
+        with tr.span("engine.admit"):
+            adm = self.scheduler.next_batch(time.time(), flush=flush)
         if adm is None:
             return []
         # admission ordinal: the tag that stitches one batch's step
@@ -346,14 +361,17 @@ class DiTServer:
         b = adm.batch_rows  # n_real + dp padding rows (dropped at the end)
         t = adm.seq_len
         d = self.cfg.d_model
-        sc = self._bucket_sampler(adm.plan)
-        cond = jnp.stack([
-            (batch[i].cond if i < n_real and batch[i].cond is not None
-             else jnp.zeros((COND_TOKENS, d), self.cfg.dtype))
-            for i in range(b)
-        ])
-        x = self._noise(batch, b, t)
-        fn = self._step_fn(b, t, adm.plan)
+        # span tags stay low-cardinality: no request or admission ids
+        span_tags = {"rows": n_real, "seq": t}
+        with tr.span("engine.prepare", tags=span_tags):
+            sc = self._bucket_sampler(adm.plan)
+            cond = jnp.stack([
+                (batch[i].cond if i < n_real and batch[i].cond is not None
+                 else jnp.zeros((COND_TOKENS, d), self.cfg.dtype))
+                for i in range(b)
+            ])
+            x = self._noise(batch, b, t)
+            fn = self._step_fn(b, t, adm.plan)
         dt = 1.0 / sc.num_steps
         # a persistent sink (JSONL / recording) opts into the per-step
         # series even without the control loop: the wall-clock sync is
@@ -365,6 +383,21 @@ class DiTServer:
         step_times: list[float] = []
         drift_vals = []
         resyncs = 0
+        t_enqueued = 0.0
+
+        def dispatch(i: int, f, *args):
+            """Enqueue sampler step ``i`` (``f(*args)``) as an
+            ``engine.dispatch`` span.  Measured, only its profiler
+            annotation is open here: ``tick`` publishes the record once
+            the step's clock has stopped."""
+            nonlocal t_enqueued
+            if not measure:
+                with tr.span("engine.dispatch", step=i, tags=span_tags):
+                    return f(*args)
+            with profiler_annotation("engine.dispatch", span_tags):
+                out = f(*args)
+            t_enqueued = time.perf_counter()
+            return out
 
         def tick(i: int, outputs, t0: float, warm=None) -> bool:
             """Post-step control point: stamp the step's wall clock, run
@@ -373,9 +406,18 @@ class DiTServer:
             after it (the sampler satellite's contract, applied here
             too)."""
             if measure:
-                jax.block_until_ready(outputs)
-                t_step = time.perf_counter() - t0
+                t_sync = time.perf_counter()
+                with profiler_annotation("engine.sync", span_tags):
+                    jax.block_until_ready(outputs)
+                t_ready = time.perf_counter()
+                t_step = t_ready - t0
                 step_times.append(t_step)
+                ep = tr.epoch
+                rec_tags = {**span_tags, "parent": "engine.run_once"}
+                tr.span_event("engine.dispatch", t0 - ep, t_enqueued - t0,
+                              step=i, tags=rec_tags)
+                tr.span_event("engine.sync", t_sync - ep, t_ready - t_sync,
+                              step=i, tags=rec_tags)
                 self.tracker.log("engine.t_step_s", t_step, step=i,
                                  tags=step_tags)
                 if self.profiler is not None:
@@ -421,8 +463,8 @@ class DiTServer:
                         warm = pipe.warm_step(i)
                     f = warm_fn if warm else displaced_fn
                     t0 = time.perf_counter()
-                    x, state, m = f(self.params, x, cond,
-                                    jnp.float32(1.0 - i * dt), state)
+                    x, state, m = dispatch(i, f, self.params, x, cond,
+                                           jnp.float32(1.0 - i * dt), state)
                     per = m["kv_drift_per_request"]
                     drift_vals.append(per)
                     if use_drift:
@@ -436,18 +478,32 @@ class DiTServer:
             else:
                 for i in range(sc.num_steps):
                     t0 = time.perf_counter()
-                    x = fn(self.params, x, cond, jnp.float32(1.0 - i * dt))
+                    x = dispatch(i, fn, self.params, x, cond,
+                                 jnp.float32(1.0 - i * dt))
                     if tick(i, x, t0):
                         parked = True
                         break
-            if not parked:
-                x.block_until_ready()
+            if not parked and not measure:
+                with tr.span("engine.sync", tags=span_tags):
+                    x.block_until_ready()
         if self.profiler is not None:
             # pair and publish this admission's device-side leg events
             # (comm.leg / comm.compute / comm.exposed_wait spans)
             emit_leg_spans(self.profiler, self.tracker)
         if parked:
             return []
+        with tr.span("engine.finish", tags=span_tags):
+            return self._finish(adm, adm_id, sc, x, step_times, drift_vals,
+                                resyncs)
+
+    def _finish(self, adm, adm_id: int, sc: SamplerConfig, x: jax.Array,
+                step_times: list[float], drift_vals: list,
+                resyncs: int) -> list[DiTResult]:
+        """Hand each request its row of the finished batch and publish the
+        completion telemetry."""
+        batch = adm.requests
+        n_real = len(batch)
+        b, t = adm.batch_rows, adm.seq_len
         now = time.time()
         if self.calibrator is not None and step_times:
             self.calibrator.observe(adm.plan, b, t, step_times)

@@ -18,9 +18,10 @@ Sink taxonomy:
     thin reads, and ``summary()`` can print an end-of-run table) but
     retains **no per-record stream** — a long-running server never
     accumulates unbounded history by default.
-  * ``NullTracker``   — a TRUE no-op: no counters, no stats, no records.
-    Legacy counter reads through it are always 0; use it only when the
-    attribute surface is not consumed.
+  * ``NullTracker``   — a TRUE no-op: no counters, no stats, no records
+    (its spans still annotate the profiler).  Legacy counter reads
+    through it are always 0; use it only when the attribute surface is
+    not consumed.
   * ``RecordingTracker`` — ``Tracker`` plus the full in-memory record
     stream (``records``).  The test sink.
   * ``JsonlTracker``  — ``Tracker`` plus one schema-versioned JSON line
@@ -48,21 +49,28 @@ Metric kinds:
     manager that times a host region (nesting recorded via a ``parent``
     tag); ``span_event`` publishes an interval measured elsewhere (the
     comm profiler's drained device-side legs).  ``scripts/trace_report.py``
-    turns a span stream into a Perfetto timeline plus overlap/residual
-    reports.
+    reports.  Every ``span`` (whatever the sink, ``NullTracker`` too) is
+    also a ``jax.profiler.TraceAnnotation`` for its duration, so under a
+    profiler session it lands on the trace's host plane, on the clock of
+    the device's ops, with its caller's tags as the event's stats.
 
-Everything is host-side pure Python — no jax — so the discrete-event
+Aggregates are keyed on low-cardinality tags only: ``ID_TAGS`` (request
+and admission ids) stay on the emitted records but never split a gauge
+or span series, so a long-running default sink holds a bounded set.
+
+Everything else is host-side pure Python, and ``jax.profiler`` is
+imported at the first span, not at import, so the discrete-event
 simulation in ``benchmarks/sched_sweep.py`` publishes through the exact
 sink type the real engine uses.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
+import functools
 import json
 import pathlib
 import time
-from typing import IO, Any, Iterable, Iterator, Mapping
+from typing import IO, Any, Iterable, Mapping
 
 SCHEMA_VERSION = "metrics.v1"
 
@@ -165,11 +173,39 @@ def validate_record(d: Mapping[str, Any]) -> list[str]:
     return errs
 
 
+# per-request / per-admission identifiers: carried on emitted records
+# (persistent sinks, trace replays) but left out of the gauge and span
+# aggregates, where each value would open a series of its own
+ID_TAGS = frozenset({"rid", "rids", "adm"})
+
+
 def _tag_key(tags: Mapping[str, TagValue] | None) -> tuple:
     """Canonical hashable identity of a tag set (order-insensitive)."""
     if not tags:
         return ()
     return tuple(sorted(tags.items()))
+
+
+def _series_key(tags: Mapping[str, TagValue] | None) -> tuple:
+    """Identity of a gauge/span series: the tag set without ``ID_TAGS``."""
+    if not tags:
+        return ()
+    if ID_TAGS.isdisjoint(tags):
+        return tuple(sorted(tags.items()))
+    return tuple(sorted((k, v) for k, v in tags.items() if k not in ID_TAGS))
+
+
+@functools.cache
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
+def profiler_annotation(name: str, tags: Mapping[str, TagValue]):
+    """A profiler annotation of the region ``name`` carrying ``tags``:
+    under a profiler session an event on the trace's host plane."""
+    return _trace_annotation()(name, **tags)
 
 
 @dataclasses.dataclass
@@ -225,8 +261,9 @@ class Tracker:
 
     def log(self, name: str, value: float, *, step: int | None = None,
             tags: Mapping[str, TagValue] | None = None) -> None:
-        """Publish one gauge sample of the series (name, tags)."""
-        key = (name, _tag_key(tags))
+        """Publish one gauge sample of the series (name, tags without
+        ``ID_TAGS``)."""
+        key = (name, _series_key(tags))
         st = self._stats.get(key)
         if st is None:
             st = self._stats[key] = SeriesStats()
@@ -244,7 +281,7 @@ class Tracker:
         ``self.epoch`` (use ``now()``), ``dur`` the duration in seconds.
         Durations aggregate into the same per-series stats as gauges, so
         ``summary()`` shows span timing tables for free."""
-        key = (name, _tag_key(tags))
+        key = (name, _series_key(tags))
         st = self._stats.get(key)
         if st is None:
             st = self._stats[key] = SeriesStats()
@@ -252,29 +289,23 @@ class Tracker:
         self._record(name, float(dur), "span", step, tags,
                      t_start=float(t_start))
 
-    @contextlib.contextmanager
     def span(self, name: str, *, step: int | None = None,
-             tags: Mapping[str, TagValue] | None = None) -> Iterator[None]:
-        """Time a host-side region as a span record.  Nested spans get a
-        ``parent`` tag automatically (unless the caller sets one), which
-        is how ``scripts/trace_report.py`` rebuilds the step→stage tree.
-        The record is emitted even if the body raises, so a crashed
-        step's partial timing still lands in the stream."""
-        t0 = self.now()
-        tags = dict(tags) if tags else {}
-        if self._span_stack and "parent" not in tags:
-            tags["parent"] = self._span_stack[-1]
-        self._span_stack.append(name)
-        try:
-            yield
-        finally:
-            self._span_stack.pop()
-            self.span_event(name, t0, self.now() - t0, step=step,
-                            tags=tags or None)
+             tags: Mapping[str, TagValue] | None = None) -> "_Span":
+        """Time a host-side region as a span record (a context manager).
+        Nested spans get a ``parent`` tag automatically (unless the caller
+        sets one), which is how ``scripts/trace_report.py`` rebuilds the
+        step→stage tree.  The record is emitted even if the body raises,
+        so a crashed step's partial timing still lands in the stream.  The
+        region is also a profiler annotation carrying the caller's
+        ``tags``."""
+        return _Span(self, name, step, dict(tags) if tags else {})
 
     def _record(self, name: str, value: float, kind: str,
                 step: int | None, tags: Mapping[str, TagValue] | None, *,
                 t_start: float | None = None) -> None:
+        if type(self)._emit is Tracker._emit:  # nothing keeps the record
+            self._seq += 1
+            return
         rec = Record(name=name, value=value, kind=kind, step=step,
                      tags=dict(tags) if tags else {}, seq=self._seq,
                      t_start=t_start)
@@ -317,7 +348,7 @@ class Tracker:
     def series(self, name: str,
                tags: Mapping[str, TagValue] | None = None) -> SeriesStats:
         """Aggregate stats of one gauge series (empty stats if unseen)."""
-        return self._stats.get((name, _tag_key(tags)), SeriesStats())
+        return self._stats.get((name, _series_key(tags)), SeriesStats())
 
     def summary(self) -> list[dict[str, Any]]:
         """End-of-run aggregate table: one row per counter and per gauge
@@ -360,9 +391,40 @@ class Tracker:
         self.close()
 
 
+class _Span:
+    """``Tracker.span``'s context manager: a profiler annotation for the
+    region, then one span record."""
+
+    __slots__ = ("tracker", "name", "step", "tags", "annotation", "t0")
+
+    def __init__(self, tracker: Tracker, name: str, step: int | None,
+                 tags: dict[str, TagValue]):
+        self.tracker, self.name, self.step, self.tags = (tracker, name, step,
+                                                        tags)
+        self.annotation = profiler_annotation(name, tags)
+
+    def __enter__(self) -> None:
+        stack = self.tracker._span_stack
+        if stack and "parent" not in self.tags:
+            self.tags["parent"] = stack[-1]
+        stack.append(self.name)
+        self.annotation.__enter__()
+        self.t0 = self.tracker.now()
+
+    def __exit__(self, *exc) -> None:
+        tr = self.tracker
+        dur = tr.now() - self.t0
+        self.annotation.__exit__(*exc)
+        tr._span_stack.pop()
+        tr.span_event(self.name, self.t0, dur, step=self.step,
+                      tags=self.tags or None)
+
+
 class NullTracker(Tracker):
     """A true no-op sink: publishing does nothing at all (no counters,
-    no stats, no seq advance), reads are always empty/zero."""
+    no stats, no seq advance), reads are always empty/zero.  A ``span``
+    is still a profiler annotation, so traces see the same regions
+    whatever the sink."""
 
     def count(self, name: str, value: float = 1.0, *, step=None,
               tags=None) -> float:
@@ -374,9 +436,8 @@ class NullTracker(Tracker):
     def span_event(self, name, t_start, dur, *, step=None, tags=None) -> None:
         pass
 
-    @contextlib.contextmanager
     def span(self, name, *, step=None, tags=None):
-        yield
+        return profiler_annotation(name, tags or {})
 
 
 class RecordingTracker(Tracker):

@@ -1,0 +1,160 @@
+"""The serving engine's spans and the DiT block's named scopes
+(DESIGN.md §12): what a profile of ``DiTServer.run_once`` can name.
+
+* ``run_once`` is one ``engine.run_once`` span holding ``engine.admit``,
+  ``engine.prepare``, one ``engine.dispatch`` a sampler step,
+  ``engine.sync`` and ``engine.finish``, in that order, tagged with the
+  batch's rows and latent length and with no request or admission id;
+* the default tracker's aggregates do not grow with the requests served;
+* a slow sink's emission stays out of the measured step clock;
+* the step's HLO carries the block's scopes (``qkv``, ``attn``,
+  ``attn_out``, ``mlp``) in its op names, which the device trace's
+  ``tf_op`` paths come from.
+"""
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.configs import get_reduced
+from repro.core import SPConfig
+from repro.models import ParallelContext, get_model
+from repro.serving import DiTRequest, DiTServer, SamplerConfig
+from repro.serving.metrics import RecordingTracker, Tracker
+from repro.serving.sampler import sample_step
+from tests.test_sampler import SlowTracker
+
+SP = SPConfig(strategy="full", sp_axes=("model",), batch_axes=("data",))
+SCOPES = ("qkv", "attn", "attn_out", "mlp")
+STEPS = 3
+
+
+@pytest.fixture(scope="module")
+def dit():
+    cfg = dataclasses.replace(get_reduced("flux-12b"), dtype="float32")
+    params, _ = get_model(cfg).init(cfg, jax.random.PRNGKey(0), 1)
+    return cfg, params
+
+
+class SpanSink(Tracker):
+    """Keeps span records, and is not persistent: the engine's step loop
+    stays the sync-free one."""
+
+    def __init__(self):
+        super().__init__()
+        self.spans = []
+
+    def _emit(self, rec):
+        if rec.kind == "span":
+            self.spans.append(rec)
+
+
+@pytest.mark.parametrize("measured", [False, True])
+def test_run_once_spans_nest_in_order(dit, mesh1, measured):
+    cfg, params = dit
+    sink = RecordingTracker() if measured else SpanSink()
+    srv = DiTServer(params, cfg, mesh1, SP,
+                    sampler=SamplerConfig(num_steps=STEPS), max_batch=2,
+                    tracker=sink)
+    for rid in (11, 12):
+        srv.submit(DiTRequest(rid=rid, seq_len=32))
+    assert len(srv.run_once()) == 2
+    spans = [r for r in (sink.records if measured else sink.spans)
+             if r.kind == "span" and r.name.startswith("engine.")]
+    outer = spans[-1]
+    assert outer.name == "engine.run_once" and outer.tags == {}
+    inner = sorted(spans[:-1], key=lambda r: r.t_start)
+    # measured: a sync after each step; else one after the last
+    steps = (["engine.dispatch", "engine.sync"] * STEPS if measured
+             else ["engine.dispatch"] * STEPS + ["engine.sync"])
+    assert [r.name for r in inner] == [
+        "engine.admit", "engine.prepare", *steps, "engine.finish"]
+    assert [r.step for r in inner if r.name == "engine.dispatch"] == list(
+        range(STEPS))
+    end = outer.t_start + outer.value
+    for r in inner:
+        assert r.tags["parent"] == "engine.run_once"
+        assert outer.t_start <= r.t_start
+        assert r.t_start + r.value <= end + 1e-9
+        if r.name != "engine.admit":
+            assert r.tags == {"rows": 2, "seq": 32,
+                              "parent": "engine.run_once"}
+    for a, b in zip(inner, inner[1:]):  # one after another, no overlap
+        assert a.t_start + a.value <= b.t_start + 1e-9
+
+
+def test_slow_tracker_does_not_inflate_engine_step_clock(dit, mesh1):
+    """A persistent sink measures every step; the step's dispatch and sync
+    records are written after its clock stops, so ``engine.t_step_s``
+    (what the calibrator and the preemption policy read) holds no sink
+    time.  Step 0 pays the compile, so the steady steps are checked."""
+    cfg, params = dit
+    sink = SlowTracker()
+    srv = DiTServer(params, cfg, mesh1, SP,
+                    sampler=SamplerConfig(num_steps=STEPS), max_batch=2,
+                    tracker=sink)
+    srv.submit(DiTRequest(rid=1, seq_len=32))
+    (res,) = srv.run_once()
+    names = [r.name for r in sink.records]
+    assert names.count("engine.dispatch") == STEPS
+    assert names.count("engine.sync") == STEPS
+    assert len(res.step_times) == STEPS
+    steady = [r.value for r in sink.records
+              if r.name == "engine.t_step_s" and r.step > 0]
+    assert steady == res.step_times[1:]
+    for t_step in steady:
+        assert t_step < SlowTracker.EMIT_S, (
+            f"t_step_s {t_step:.3f}s includes sink emission time")
+
+
+def served_series(dit, mesh, requests: int) -> dict:
+    """The default tracker's series (name -> tag sets) after serving
+    ``requests`` requests of one length in full batches of two."""
+    cfg, params = dit
+    srv = DiTServer(params, cfg, mesh, SP,
+                    sampler=SamplerConfig(num_steps=2), max_batch=2)
+    for rid in range(requests):
+        srv.submit(DiTRequest(rid=rid, seq_len=32))
+    assert len(srv.serve()) == requests
+    done = srv.tracker.series("engine.request_done",
+                              {"preemptions": 0, "sla_met": True, "seq": 32})
+    assert done.n == requests
+    out: dict = {}
+    for row in srv.tracker.summary():
+        out.setdefault(row["name"], []).append(row["tags"])
+    return out
+
+
+def test_default_tracker_series_do_not_grow_with_requests(dit, mesh1):
+    """Serving 4 or 10 requests (2 or 5 batches) leaves the default
+    tracker with the same series: request and admission ids split none."""
+    few = served_series(dit, mesh1, 4)
+    assert few == served_series(dit, mesh1, 10)
+    assert few["engine.request_done"] == [
+        {"preemptions": 0, "seq": 32, "sla_met": True}]
+    assert few["engine.batch_done"] == [{"rows": 2, "seq": 32}]
+
+
+def test_block_scopes_name_the_step_ops(dit, mesh1):
+    cfg, params = dit
+    ctx = ParallelContext(mesh1, SP, "prefill")
+    sc = SamplerConfig(num_steps=2)
+
+    def f(params, x, cond, t):
+        return sample_step(params, cfg, ctx, x, cond, t, 0.5, sc)
+
+    lowered = jax.jit(f).lower(params, jnp.zeros((1, 32, 64)),
+                               jnp.zeros((1, 256, cfg.d_model)),
+                               jnp.float32(1.0))
+    text = lowered.as_text(debug_info=True)
+    op_names = set(re.findall(r'op_name="([^"]*)"',
+                              lowered.compile().as_text()))
+    for scope in SCOPES:
+        assert f'"{scope}/' in text, scope
+        assert any(f"/{scope}/" in n for n in op_names), scope
+    # the attention core's score and softmax work is under ``attn``
+    attn = {n.split("/attn/", 1)[1] for n in op_names if "/attn/" in n}
+    assert {"reduce_max", "exp"} <= {a.split("/")[-1] for a in attn}
+    assert any(n.startswith("bhlk,bkhd->blhd") for n in attn)  # p @ v

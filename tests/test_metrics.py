@@ -16,7 +16,8 @@ plus the counter-migration regression: the legacy attribute surface
 on a mixed-resolution serve — pinned here so future sinks can't drift
 from the attributes tests and launchers consume.
 
-All host-side (no jax, no mesh); property tests use hypothesis."""
+All host-side (no mesh; jax only to read a profiler trace); property
+tests use hypothesis."""
 import dataclasses
 import json
 import pathlib
@@ -463,6 +464,62 @@ def test_null_tracker_span_noop():
     with t.span("x"):
         t.span_event("y", 0.0, 1.0)
     assert t.series("y").n == 0
+
+
+def test_spans_reach_the_profiler_host_plane(tmp_path):
+    """A span opened under a profiler session is an event of the written
+    xplane's host plane, with the caller's tags as its stats, whatever
+    the sink; the tracker's own ``parent`` tag stays off the trace."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with Tracker().span("engine.run_once"):
+            with Tracker().span("engine.dispatch", step=0,
+                                tags={"rows": 2, "seq": 1024}):
+                pass
+            with NullTracker().span("engine.sync", tags={"rows": 2}):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    events = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for ln in plane.lines:
+                for e in ln.events:
+                    if e.name.startswith("engine."):
+                        events[e.name] = (e.start_ns, e.end_ns,
+                                          dict(e.stats))
+    assert set(events) == {"engine.run_once", "engine.dispatch",
+                           "engine.sync"}
+    assert events["engine.dispatch"][2] == {"rows": 2, "seq": 1024}
+    assert events["engine.sync"][2] == {"rows": 2}
+    assert events["engine.run_once"][2] == {}
+    outer = events["engine.run_once"]
+    for name in ("engine.dispatch", "engine.sync"):
+        assert outer[0] <= events[name][0] <= events[name][1] <= outer[1]
+
+
+def test_id_tags_stay_on_records_not_in_aggregates():
+    """Request and admission ids split no gauge or span series (the
+    default sink stays bounded) but reach a persistent sink intact."""
+    t = RecordingTracker()
+    for i in range(50):
+        t.log("engine.request_done", 0.5,
+              tags={"adm": i, "rid": 1000 + i, "seq": 1024})
+        t.span_event("engine.step", 0.0, 0.1, tags={"adm": i, "seq": 1024})
+        t.log("engine.park", 1.0, tags={"adm": i, "rids": f"{i},{i + 1}"})
+    assert [name for name, _ in t._stats] == [
+        "engine.request_done", "engine.step", "engine.park"]
+    assert t.series("engine.request_done", {"seq": 1024}).n == 50
+    # a lookup by the record's full tags finds the same series
+    assert t.series("engine.request_done",
+                    {"adm": 7, "rid": 1007, "seq": 1024}).n == 50
+    done = [r for r in t.records if r.name == "engine.request_done"]
+    assert [r.tags["rid"] for r in done] == list(range(1000, 1050))
+    assert done[3].tags == {"adm": 3, "rid": 1003, "seq": 1024}
 
 
 def test_jsonl_crash_tail_recoverable(tmp_path):
