@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..compat import pallas_interpret
+from ..kernels.ops import flash_attention
 from . import planner
 from .collectives import GroupLayout
 from .ring import ring_attention
@@ -140,6 +142,50 @@ def resolve_layout(
                        u_groups=u_groups(pl.p_ulysses, swift))
 
 
+def attention_lowering(cfg: SPConfig, mesh: jax.sharding.Mesh, q_len: int,
+                       head_dim: int, window=None) -> str:
+    """What ``sp_attention`` runs for self-attention of this shape.
+
+    Below SP=2: ``"flash"``, the Pallas flash kernel
+    (``kernels.ops.flash_attention``), on a one-device mesh of a TPU (the
+    platform test of ``compat.pallas_interpret``) for a real query length,
+    a head_dim of whole 128-lane tiles and a static window (the kernel's
+    mask is static); ``"reference"``, the materialised oracle, everywhere
+    else: the CPU, decode's one-token queries, a traced window, several
+    devices (GSPMD partitions the oracle, and does not partition a Pallas
+    call).  At SP > 1 the SP strategy's name."""
+    if cfg.strategy != "full" and math.prod(mesh.shape[a]
+                                            for a in cfg.sp_axes) > 1:
+        return cfg.strategy
+    flash = (math.prod(mesh.shape.values()) == 1 and not pallas_interpret()
+             and q_len > 1 and head_dim % 128 == 0
+             and (window is None or isinstance(window, int)))
+    return "flash" if flash else "reference"
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _one_chip_flash(q, k, v, scale, causal, window):
+    """The flash kernel forward, the oracle's gradient: the Pallas call
+    has no transpose rule, so training differentiates the materialised
+    attention as it did before the kernel served SP=1."""
+    return flash_attention(q, k, v, scale=scale, causal=causal, window=window)
+
+
+def _one_chip_flash_fwd(q, k, v, scale, causal, window):
+    return _one_chip_flash(q, k, v, scale, causal, window), (q, k, v)
+
+
+def _one_chip_flash_bwd(scale, causal, window, qkv, g):
+    mask = MaskSpec(causal=causal, window=window)
+    _, vjp = jax.vjp(
+        lambda q, k, v: reference_attention(q, k, v, scale=scale, mask=mask),
+        *qkv)
+    return vjp(g)
+
+
+_one_chip_flash.defvjp(_one_chip_flash_fwd, _one_chip_flash_bwd)
+
+
 def _usp_like(q, k, v, layout: GroupLayout, *, scale, causal, window, unroll,
               kv_block=None, backend="xla", interpret=None, wire_dtype=None):
     """Shared body for usp/swift/ulysses/ring: monolithic Ulysses gather →
@@ -176,7 +222,10 @@ def sp_attention(
     Sequence is sharded over ``cfg.sp_axes`` (flat-rank order), batch over
     ``cfg.batch_axes``; heads/head_dim replicated inside the SP group.
     """
-    if cfg.strategy == "full" or math.prod(mesh.shape[a] for a in cfg.sp_axes) == 1:
+    lowering = attention_lowering(cfg, mesh, q.shape[1], q.shape[3], window)
+    if lowering == "flash":
+        return _one_chip_flash(q, k, v, scale, causal, window)
+    if lowering == "reference":
         mask = MaskSpec(causal=causal, window=window)
         return reference_attention(q, k, v, scale=scale, mask=mask)
 

@@ -32,7 +32,20 @@ from .ring_flash import ring_flash_step
 # the ONE variant key: lowering variants must never share a jit cache
 # entry; asserted below to match _dispatch's signature exactly
 STATIC_ARGNAMES = ("causal", "window", "scale", "block_q", "block_k",
-                   "interpret", "backend", "fused")
+                   "masked", "interpret", "backend", "fused")
+
+# Block sizes from the shapes (``block_sizes``; PERF.md, section 6):
+# a head's K and V stay resident in VMEM (one kv step, K/V read once a
+# head) while, double-buffered, they take at most KV_VMEM_BYTES; a grid
+# step's scores are at most SCORE_ELEMS elements.  Per-step work beyond
+# the two dots grows with block_q alone (the q scale, the row state, the
+# output), so larger steps come closer to the MXU's bound: the compiler's
+# static schedule for v5e puts a 4352-token step at 77% of it with 128
+# q rows, 86% with 272 and 88% with 544.  On a v5e chip the blocks these
+# give at 1280, 2560 and 4352 tokens timed within 3% of the best of the
+# candidate blocks measured at each length.
+KV_VMEM_BYTES = 16 << 20
+SCORE_ELEMS = 1 << 21
 
 # traces per static key (trace-time side effect; the regression counter)
 _trace_counts: dict[tuple, int] = {}
@@ -57,6 +70,40 @@ def _pad_to(x: jax.Array, axis: int, mult: int, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
+def _round_up(n: int, mult: int) -> int:
+    return -(-n // mult) * mult
+
+
+def _largest_divisor(n: int, mult: int, cap: int) -> int | None:
+    """The largest multiple of ``mult`` that divides ``n`` and is at most
+    ``cap``, or None."""
+    for b in range(cap // mult * mult, 0, -mult):
+        if n % b == 0:
+            return b
+    return None
+
+
+def block_sizes(lq: int, lk: int, d: int, itemsize: int) -> tuple[int, int]:
+    """(block_q, block_k) for ``flash_mqkv`` from the shapes alone.
+
+    K and V of a head are one kv block (padded to whole 128-lane tiles)
+    when they fit KV_VMEM_BYTES, else the largest 128-multiple dividing
+    the padded length that does.  block_q is the largest multiple of the
+    sublane tile (8 rows of f32, 16 of bf16) that divides Lq and keeps the
+    scores within SCORE_ELEMS; where no divisor comes within half of that
+    cap, q is padded to whole cap-sized blocks instead."""
+    sub = 8 * max(4 // itemsize, 1)
+    lk_pad = _round_up(lk, 128 if lk > 128 else 8)
+    kv_cap = KV_VMEM_BYTES // (4 * d * itemsize)
+    bk = (lk_pad if lk_pad <= kv_cap
+          else _largest_divisor(lk_pad, 128, max(kv_cap, 128)))
+    cap = max(SCORE_ELEMS // bk // sub * sub, sub)
+    if lq <= cap:
+        return _round_up(lq, sub), bk
+    bq = _largest_divisor(lq, sub, cap)
+    return (bq if bq is not None and 2 * bq >= cap else cap), bk
+
+
 def _flatten_heads(x: jax.Array) -> jax.Array:
     b, l, h, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b * h, l, d)
@@ -68,7 +115,8 @@ def _unflatten_heads(x: jax.Array, b: int, h: int) -> jax.Array:
 
 
 def _step(qf, kf, vf, qpp, kpp, *, group, scale, causal, window, state,
-          finalize, block_q, block_k, interpret, backend, fused):
+          finalize, block_q, block_k, interpret, backend, fused,
+          masked=True):
     """One kernel step on flattened [BH, L, D] operands, by variant."""
     if backend == "xla":
         kr = jnp.repeat(kf, group, axis=0) if group > 1 else kf
@@ -86,20 +134,20 @@ def _step(qf, kf, vf, qpp, kpp, *, group, scale, causal, window, state,
     return flash_mqkv(
         qf, kf, vf, qpp, kpp, group=group, scale=scale, causal=causal,
         window=window, state=state, finalize=finalize,
-        block_q=block_q, block_k=block_k, interpret=interpret)
+        block_q=block_q, block_k=block_k, masked=masked, interpret=interpret)
 
 
 @functools.lru_cache(maxsize=None)
-def _dispatch(causal, window, scale, block_q, block_k, interpret, backend,
-              fused):
+def _dispatch(causal, window, scale, block_q, block_k, masked, interpret,
+              backend, fused):
     """Build (and cache) the jitted impl for one static-variant key.
 
     The lru_cache key IS the full variant tuple (one jitted closure per
     key — the knobs are closure constants, not jit static args), so no
     two variants can collide on a cache entry.
     """
-    key = (causal, window, scale, block_q, block_k, interpret, backend,
-           fused)
+    key = (causal, window, scale, block_q, block_k, masked, interpret,
+           backend, fused)
 
     @jax.jit
     def impl(q, k, v, q_pos, k_pos):
@@ -107,17 +155,31 @@ def _dispatch(causal, window, scale, block_q, block_k, interpret, backend,
         b, lq, hq, d = q.shape
         _, lk, hkv, _ = k.shape
         group = hq // hkv
-        bq = min(block_q, max(8, lq))
-        bk = min(block_k, max(8, lk))
+        bq, bk = block_sizes(lq, lk, d, q.dtype.itemsize)
+        if block_q is not None:
+            bq = min(block_q, max(8, lq))
+        if block_k is not None:
+            bk = min(block_k, max(8, lk))
+        qpp = _pad_to(q_pos.astype(jnp.int32), 0, bq, value=0)
+        kpp = _pad_to(k_pos.astype(jnp.int32), 0, bk, value=-1)
+        mask = masked or kpp.shape[0] != lk  # padded K is masked out
+        if backend == "pallas" and not fused and d % 128 == 0:
+            # heads stay packed in the lanes: no transpose in or out
+            pack = lambda x, blk: _pad_to(x.reshape(b, x.shape[1], -1), 1,
+                                          blk)
+            o, _, _ = flash_mqkv(
+                pack(q, bq), pack(k, bk), pack(v, bk), qpp, kpp, group=group,
+                scale=scale, causal=causal, window=window, block_q=bq,
+                block_k=bk, masked=mask, heads=hq,
+                interpret=interpret)
+            return o[:, :lq].reshape(b, lq, hq, d)
         qf = _pad_to(_flatten_heads(q), 1, bq)
         kf = _pad_to(_flatten_heads(k), 1, bk)
         vf = _pad_to(_flatten_heads(v), 1, bk)
-        qpp = _pad_to(q_pos.astype(jnp.int32), 0, bq, value=0)
-        kpp = _pad_to(k_pos.astype(jnp.int32), 0, bk, value=-1)
         o, _, _ = _step(
             qf, kf, vf, qpp, kpp, group=group, scale=scale, causal=causal,
             window=window, state=None, finalize=True, block_q=bq, block_k=bk,
-            interpret=interpret, backend=backend, fused=fused)
+            interpret=interpret, backend=backend, fused=fused, masked=mask)
         return _unflatten_heads(o[:, :lq], b, hq)
 
     return impl
@@ -138,13 +200,17 @@ def flash_attention(
     causal: bool = False,
     window: int | None = None,
     scale: float | None = None,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool | None = None,
     backend: str = "pallas",
     fused: bool = False,
 ) -> jax.Array:
     """Drop-in flash attention; returns [B, Lq, Hq, D].
+
+    Blocks not given come from the shapes (``block_sizes``).  Without
+    ``k_pos``, a causal or window mask, or K padding, the kernel skips
+    its mask work altogether.
 
     ``backend="pallas"`` runs the Pallas kernel (``fused=True`` selects
     the ring_flash variant that also issues its forwarding DMA);
@@ -155,12 +221,13 @@ def flash_attention(
     exists for parity and dispatch testing, not as a perf knob.
     """
     lq, lk = q.shape[1], k.shape[1]
+    masked = causal or window is not None or k_pos is not None
     if q_pos is None:
         q_pos = jnp.arange(lq, dtype=jnp.int32)
     if k_pos is None:
         k_pos = jnp.arange(lk, dtype=jnp.int32)
-    impl = _dispatch(causal, window, scale, block_q, block_k, interpret,
-                     backend, fused)
+    impl = _dispatch(causal, window, scale, block_q, block_k, masked,
+                     interpret, backend, fused)
     return impl(q, k, v, q_pos, k_pos)
 
 

@@ -30,6 +30,7 @@ from ..comm import CommProfiler, emit_leg_spans
 from ..comm import profile as comm_profile
 from ..configs.base import ModelConfig
 from ..core import SPConfig, plan_hybrid
+from ..core.strategy import attention_lowering
 from ..core.comm_model import NetworkModel
 from ..models import ParallelContext, get_model, param_shardings
 from ..models.dit import COND_TOKENS, LATENT_CHANNELS
@@ -255,8 +256,15 @@ class DiTServer:
         # the patch count is part of the compiled step's identity: after
         # an online recalibration changes a bucket's plan choice, the new
         # variant compiles lazily instead of reusing the stale trace
+        # the attention the bucket's steps run: displaced attention in a
+        # pipelined bucket, else sp_attention's lowering of the joint
+        # (text + latent) self-attention
+        attn = ("displaced" if sc.pipelined else attention_lowering(
+            self.ctx.sp, self.ctx.mesh, COND_TOKENS + seq,
+            self.cfg.resolved_head_dim))
         return self.plan_cache.step_fn(batch, seq, build,
-                                       variant=choice.num_patches)
+                                       variant=choice.num_patches,
+                                       build_tags={"attn": attn})
 
     def _dp_degree(self) -> int:
         ba = self.ctx.sp.batch_axes or ()

@@ -174,13 +174,15 @@ class PlanCache:
 
     # -- compiled-step memoization ---------------------------------------
     def step_fn(self, batch_rows: int, seq: int, build: Callable[[], Any],
-                variant: Any = None):
+                variant: Any = None, build_tags: dict | None = None):
         """Return the compiled step artifact for a shape, building (and
         counting a trace) only on first use.  ``variant`` distinguishes
         compile-relevant plan attributes beyond the shape (the engine
         passes the selected patch count): after a ``recalibrate`` changes
         a bucket's plan choice, the new variant compiles lazily while the
-        old one stays cached."""
+        old one stays cached.  ``build_tags`` are added to the build's
+        ``plan_cache.trace`` span (the engine's ``attn``: the attention
+        lowering the bucket compiled)."""
         key = (batch_rows, seq) if variant is None else (batch_rows, seq,
                                                          variant)
         tags = {"rows": batch_rows, "seq": seq}
@@ -191,7 +193,8 @@ class PlanCache:
             # the build (trace + compile) is a span: bucket switches show
             # up on the host timeline as plan_cache.trace blocks, making
             # compile stalls distinguishable from slow steps (§12)
-            with self.tracker.span("plan_cache.trace", tags=tags):
+            with self.tracker.span("plan_cache.trace",
+                                   tags={**tags, **(build_tags or {})}):
                 self._steps[key] = build()
         return self._steps[key]
 

@@ -22,7 +22,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.comm.pallas_backend import _tpu_remote_put
 from repro.compat import make_mesh
-from repro.configs import get_config
+from repro.configs import get_config, get_reduced
+from repro.configs.shapes import InputShape
 from repro.core import SPConfig
 from repro.kernels.flash_mqkv import flash_mqkv
 from repro.kernels.ring_flash import ring_flash_step
@@ -30,6 +31,9 @@ from repro.models import ParallelContext, get_model
 from repro.models.dit import COND_TOKENS, LATENT_CHANNELS
 from repro.serving import SamplerConfig
 from repro.serving.sampler import sample_step
+from repro.train import AdamWConfig
+from repro.train.optimizer import init_adamw
+from repro.train.trainer import make_train_step
 
 HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
 TOKENS = 4096 + COND_TOKENS  # a 1024x1024 image's latents plus text
@@ -135,11 +139,44 @@ def _assert_fits(compiled):
             + mem.output_size_in_bytes) < HBM_BYTES
 
 
-def test_flux_step_one_chip(topo, one_chip):
+# temp_size_in_bytes of the step below when SP=1 attention materialised
+# its [1, 24, 4352, 4352] scores (the parent of the flash lowering)
+MATERIALISED_TEMP_BYTES = 1_052_579_328
+
+
+def test_flux_step_one_chip(topo, one_chip, monkeypatch):
+    """SP=1 at 4096 tokens: where JAX reports a TPU, attention is the
+    flash kernel, and the step holds no score matrix."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
     sp = SPConfig(strategy="full", sp_axes=("model",), batch_axes=("data",))
     compiled = _flux_step(mesh, sp, 4096, one_chip)
     _assert_fits(compiled)
+    assert "tpu_custom_call" in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < MATERIALISED_TEMP_BYTES, temp
+
+
+def test_train_step_one_chip(topo, one_chip, monkeypatch):
+    """A one-chip train step where JAX reports a TPU (the launcher's
+    default mesh): the forward's SP=1 attention is the flash kernel, and
+    the gradient, the oracle's, compiles beside it.  A reduced causal LM
+    with 128-wide heads."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_reduced("qwen2-1.5b"), head_dim=128,
+                              dtype="bfloat16", sharding_overrides=())
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    sp = SPConfig(strategy="full", sp_axes=("model",), batch_axes=("data",))
+    bundle = get_model(cfg)
+    sds = lambda a: _sds(a.shape, a.dtype, one_chip)
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: bundle.init(cfg, jax.random.PRNGKey(0), 1)[0]))
+    opt = jax.tree.map(sds, jax.eval_shape(init_adamw, params))
+    batch = jax.tree.map(sds, bundle.input_specs(
+        cfg, InputShape("train", 256, 2, "training"), abstract=True))
+    step = make_train_step(cfg, mesh, sp, AdamWConfig())
+    compiled = jax.jit(step).lower(params, opt, batch).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("strategy,backend", [("swift_torus", "xla"),

@@ -7,6 +7,8 @@
   batch's rows and latent length and with no request or admission id;
 * the default tracker's aggregates do not grow with the requests served;
 * a slow sink's emission stays out of the measured step clock;
+* each bucket build's ``plan_cache.trace`` span names its attention
+  lowering (``attn``);
 * the step's HLO carries the block's scopes (``qkv``, ``attn``,
   ``attn_out``, ``mlp``) in its op names, which the device trace's
   ``tf_op`` paths come from.
@@ -19,8 +21,10 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_reduced
-from repro.core import SPConfig
+from repro.core import PipelineConfig, SPConfig
+from repro.core.strategy import attention_lowering
 from repro.models import ParallelContext, get_model
+from repro.models.dit import COND_TOKENS
 from repro.serving import DiTRequest, DiTServer, SamplerConfig
 from repro.serving.metrics import RecordingTracker, Tracker
 from repro.serving.sampler import sample_step
@@ -107,6 +111,30 @@ def test_slow_tracker_does_not_inflate_engine_step_clock(dit, mesh1):
     for t_step in steady:
         assert t_step < SlowTracker.EMIT_S, (
             f"t_step_s {t_step:.3f}s includes sink emission time")
+
+
+@pytest.mark.parametrize("pipeline", [None, PipelineConfig(pp=2,
+                                                          warmup_steps=1)],
+                         ids=["sync", "pipelined"])
+def test_plan_cache_trace_tags_the_attention_lowering(dit, mesh1, pipeline):
+    """Each bucket build's ``plan_cache.trace`` span names the attention
+    its steps run: the predicate ``sp_attention`` uses (the oracle on the
+    CPU), or displaced attention in a pipelined bucket."""
+    cfg, params = dit
+    sink = RecordingTracker()
+    srv = DiTServer(params, cfg, mesh1, SP,
+                    sampler=SamplerConfig(num_steps=2, pipeline=pipeline),
+                    max_batch=2, tracker=sink)
+    for rid, seq in ((1, 32), (2, 64)):
+        srv.submit(DiTRequest(rid=rid, seq_len=seq))
+    assert len(srv.serve()) == 2
+    builds = [r for r in sink.records if r.name == "plan_cache.trace"]
+    assert sorted(r.tags["seq"] for r in builds) == [32, 64]
+    for r in builds:
+        want = "displaced" if pipeline else attention_lowering(
+            SP, mesh1, COND_TOKENS + r.tags["seq"], cfg.resolved_head_dim)
+        assert r.tags["attn"] == want
+        assert want in ("reference", "displaced")
 
 
 def served_series(dit, mesh, requests: int) -> dict:

@@ -1,16 +1,23 @@
 """Pallas flash_mqkv kernel vs pure-jnp oracle (interpret mode on CPU).
 
-Sweeps shapes / dtypes / masks / GQA groups / multi-segment merges per the
-assignment's per-kernel requirement.
+Sweeps shapes / dtypes / masks / GQA groups / multi-segment merges, the
+blocks derived from the shapes, and the SP=1 choice between the kernel
+and the oracle.
 """
+import dataclasses
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import MaskSpec, reference_attention
+from repro.core import MaskSpec, SPConfig, reference_attention
+from repro.core import strategy
+from repro.core.strategy import attention_lowering, sp_attention
 from repro.kernels import flash_attention, flash_attention_segments
 from repro.kernels.flash_mqkv import flash_mqkv
+from repro.kernels.ops import KV_VMEM_BYTES, SCORE_ELEMS, block_sizes
 from repro.kernels.ref import flash_attention_ref
 
 
@@ -136,3 +143,119 @@ def test_kernel_unnormalized_state_output():
     np.testing.assert_allclose(o, o_ref, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(lsum, l_ref, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(m, m_ref, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("shape", [
+    (1, 256, 2, 2, 128),   # one block each way
+    (1, 2560, 2, 2, 128),  # q blocks of 640 rows against one kv block
+    (2, 512, 4, 2, 128),   # GQA, heads packed in the lanes
+    (1, 512, 2, 2, 64),    # head_dim 64: heads flattened
+    (1, 300, 2, 2, 128),   # K padded to 384 columns: the masked path
+])
+def test_kernel_bf16_shape_derived_blocks(shape):
+    """bf16 MXU operands, f32 softmax state, blocks from the shapes: the
+    no-mask path and one padded length against the float32 oracle."""
+    b, l, hq, hkv, d = shape
+    q, k, v = _mk(jax.random.PRNGKey(8), b, l, l, hq, hkv, d, jnp.bfloat16)
+    out = flash_attention(q, k, v, interpret=True)
+    ref = reference_attention(*(x.astype(jnp.float32) for x in (q, k, v)))
+    np.testing.assert_allclose(np.asarray(out, np.float32), ref, rtol=2e-2,
+                               atol=2e-2)
+
+
+@pytest.mark.parametrize("state", [False, True])
+def test_kernel_unmasked_path_matches_masked(state):
+    """``masked=False`` skips the compares and -inf guards, fresh or with
+    a carried state, and changes no number."""
+    b, l, h, d = 1, 64, 2, 32
+    q, k, v = _mk(jax.random.PRNGKey(9), b, l, l, h, h, d, jnp.float32)
+    qf, kf, vf = (x.transpose(0, 2, 1, 3).reshape(b * h, l, d)
+                  for x in (q, k, v))
+    pos = jnp.arange(l, dtype=jnp.int32)
+    carried = (flash_mqkv(qf, kf, vf, pos, pos, finalize=False, block_q=16,
+                          block_k=16, interpret=True) if state else None)
+    outs = [flash_mqkv(qf, kf, vf, pos, pos, state=carried, finalize=False,
+                       block_q=16, block_k=32, masked=masked, interpret=True)
+            for masked in (True, False)]
+    for got, want in zip(*outs):
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("length", [1280, 2560, 4352])
+def test_block_sizes_from_shape(length):
+    """The served lengths tile without padding: one kv block spanning the
+    length, q blocks that divide it, scores within the VMEM budget."""
+    bq, bk = block_sizes(length, length, 128, 2)
+    assert bk == length and length % bq == 0 and bq % 16 == 0
+    assert bq * bk <= SCORE_ELEMS
+
+
+def test_block_sizes_pad_without_a_divisor():
+    """A length with no fitting divisor pads q to whole blocks and K to
+    whole 128-lane tiles; K and V too large for VMEM split into blocks."""
+    bq, bk = block_sizes(4100, 4100, 128, 2)  # 4100 = 4 * 25 * 41
+    assert bk == 4224 and bq == SCORE_ELEMS // bk // 16 * 16
+    long = 65536  # K and V of a head, double-buffered: 64 MiB
+    bq, bk = block_sizes(long, long, 128, 2)
+    assert bk < long and long % bk == 0 and 4 * bk * 128 * 2 <= KV_VMEM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# dispatch at SP=1: the flash kernel on a TPU, the oracle elsewhere
+# ---------------------------------------------------------------------------
+
+def _mesh(data, model):
+    return types.SimpleNamespace(shape={"data": data, "model": model})
+
+
+@pytest.mark.parametrize("backend,q_len,head_dim,window,want", [
+    ("tpu", 1280, 128, None, "flash"),   # 1 x 1280 x 24 x 128 self-attention
+    ("cpu", 1280, 128, None, "reference"),  # the CPU keeps the oracle
+    ("tpu", 1, 128, None, "reference"),  # decode's one-token queries
+    ("tpu", 1280, 64, None, "reference"),  # a head_dim of part of a lane tile
+    ("tpu", 1280, 128, 256, "flash"),    # a static window: the kernel's mask
+    ("tpu", 1280, 128, jnp.int32(256), "reference"),  # a traced one is not
+])
+def test_one_chip_lowering(monkeypatch, backend, q_len, head_dim, window,
+                           want):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    sp = SPConfig(strategy="full", sp_axes=("model",))
+    assert attention_lowering(sp, _mesh(1, 1), q_len, head_dim,
+                              window) == want
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_one_chip_flash_differentiates_as_the_oracle(monkeypatch, mesh1,
+                                                     causal):
+    """Where SP=1 runs the kernel, the forward is the kernel's and the
+    gradient the oracle's (the Pallas call has no transpose rule), so a
+    train step differentiates through it.  The platform test is reported
+    as a TPU's; the kernel itself still runs interpreted on the CPU."""
+    monkeypatch.setattr(strategy, "pallas_interpret", lambda: False)
+    sp = SPConfig(strategy="full", sp_axes=("model",), batch_axes=("data",))
+    q, k, v = _mk(jax.random.PRNGKey(8), 1, 64, 64, 2, 2, 128, jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+    mask = MaskSpec(causal=causal)
+    flash = lambda q, k, v: sp_attention(q, k, v, mesh=mesh1, cfg=sp,
+                                         causal=causal)
+    oracle = lambda q, k, v: reference_attention(q, k, v, mask=mask)
+    assert "pallas_call" in str(jax.make_jaxpr(flash)(q, k, v))
+    np.testing.assert_allclose(flash(q, k, v), oracle(q, k, v),
+                               rtol=2e-5, atol=2e-5)
+    loss = lambda f: lambda q, k, v: jnp.sum(f(q, k, v) * w)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(oracle), argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, rtol=2e-5, atol=2e-5)
+
+
+def test_attention_lowering_names_the_sp_strategy(monkeypatch):
+    """The kernel on a one-device mesh; the oracle at SP=1 on several
+    (GSPMD does not partition a Pallas call); the strategy at SP > 1."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sp = SPConfig(strategy="swift_torus", sp_axes=("model",))
+    assert attention_lowering(sp, _mesh(1, 1), 1280, 128) == "flash"
+    assert attention_lowering(sp, _mesh(2, 1), 1280, 128) == "reference"
+    assert attention_lowering(sp, _mesh(1, 4), 1280, 128) == "swift_torus"
+    full = dataclasses.replace(sp, strategy="full")
+    assert attention_lowering(full, _mesh(1, 4), 1280, 128) == "reference"

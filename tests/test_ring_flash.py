@@ -34,6 +34,16 @@ def _mk_flat(seed, bh, l, d, dtype=jnp.float32):
             jax.random.normal(ks[2], (bh, l, d), dtype))
 
 
+def _flash_state(*args, **kw):
+    """flash_mqkv's (o, l, m) for a ring step's arguments.  A finalized
+    flash_mqkv call returns no (l, m); the fused kernel still does, so
+    they come from the same call unfinalized."""
+    o, l, m = flash_mqkv(*args, **kw)
+    if l is None:
+        _, l, m = flash_mqkv(*args, **{**kw, "finalize": False})
+    return o, l, m
+
+
 # ---------------------------------------------------------------------------
 # property sweeps: ring_flash single step == flash_mqkv, bitwise
 # ---------------------------------------------------------------------------
@@ -63,7 +73,7 @@ def test_ring_flash_matches_flash_mqkv(n_chunks, pad, causal, window):
         kw = dict(causal=causal, window=window, state=state,
                   finalize=c == n_chunks - 1, block_q=bq, block_k=bk,
                   interpret=True)
-        ref = flash_mqkv(*args, **kw)
+        ref = _flash_state(*args, **kw)
         (o, l, m), (kf, vf) = ring_flash_step(*args, **kw)
         np.testing.assert_array_equal(np.asarray(o), np.asarray(ref[0]))
         np.testing.assert_array_equal(np.asarray(l), np.asarray(ref[1]))
@@ -80,8 +90,8 @@ def test_ring_flash_gqa_groups(group, causal):
     q, _, _ = _mk_flat(11, bh_kv * group, 32, d)
     _, k, v = _mk_flat(12, bh_kv, 32, d)
     pos = jnp.arange(32, dtype=jnp.int32)
-    ref = flash_mqkv(q, k, v, pos, pos, group=group, causal=causal,
-                     block_q=16, block_k=16, interpret=True)
+    ref = _flash_state(q, k, v, pos, pos, group=group, causal=causal,
+                       block_q=16, block_k=16, interpret=True)
     (o, l, m), _ = ring_flash_step(q, k, v, pos, pos, group=group,
                                    causal=causal, block_q=16, block_k=16,
                                    interpret=True)
