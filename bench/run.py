@@ -8,12 +8,15 @@ and bucketer, the plan cache's jitted sampler step, ``dit_forward``,
 and traffic drawn from ``--seed``.  Set-up builds the weights on the
 device, loads or compiles every (rows, latent length) program the
 traffic can admit and runs two steps of each; then the window measures
-``--seconds`` of traffic; the requests still open at its close are
-drained; then the float32 reference replays a seeded sample of the
-finished requests and decides ``correct``.
+``--seconds`` of open-loop traffic, the requests still open at its close
+being drained, or serves a backlog mix's fixed set; then the float32
+reference of the config's form (``forms/<form>.py``) replays a seeded
+sample of the finished requests and decides ``correct``.
 
 ``--trace 0`` prints the cell's end-to-end metrics, ``--trace 1`` its
-per-layer metrics from a profiler trace of the window.  The last line
+per-layer metrics from a profiler trace of the window (of its first
+``trace_s`` seconds, where the mix bounds it), and on standard error the
+seconds each stage of reading the trace took.  The last line
 of standard output is one JSON object.  With no TPU, or fewer chips than
 the cell asks for, it exits 2 and prints no result.
 """
@@ -26,6 +29,7 @@ T_PROCESS = time.perf_counter()
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
@@ -34,16 +38,12 @@ import sys  # noqa: E402
 import tempfile  # noqa: E402
 from typing import Any  # noqa: E402
 
-from bench import flops, spec, traffic  # noqa: E402
+from bench import flops, readers, spec, traffic  # noqa: E402
 
 sys.path.insert(0, str(spec.ROOT / "src"))
 
 WARM_RID = 1 << 29  # warm-up request ids, apart from the window's
 RID_SPAN = 1 << 28  # window request ids start at a seeded offset below this
-# what the reference implements; the program's config must say the same
-MODEL_FORM = {"act": "gelu", "norm": "layernorm", "rope": "rope",
-              "rope_theta": 10000.0, "rope_pct": 1.0, "qkv_bias": False,
-              "causal": False}
 
 
 class _WarmedUp(Exception):
@@ -95,6 +95,22 @@ class Run:
     def num_steps(self) -> int:
         return int(self.cell.config["sampler"]["num_steps"])
 
+    @functools.cached_property
+    def part_mfu(self) -> dict[str, float]:
+        """``readers.part_mfu`` of the trace, read once for every part's
+        metric; empty without a trace of a chip."""
+        if self.trace is None or not self.trace.devices:
+            return {}
+        return readers.part_mfu(self.trace, self.cell.config,
+                                self.peak["bf16_flops_per_s"])
+
+
+def traced_batches(batches: list[Batch], trace_end: float) -> list[Batch]:
+    """The batches that a trace stopped at ``trace_end`` holds whole: the
+    trace stops only after a ``run_once`` returns, so every batch that
+    started before then ended inside it."""
+    return [b for b in batches if b.start < trace_end]
+
 
 def tpu_devices(chips: int):
     """The first ``chips`` TPU devices; exits 2 when there are not."""
@@ -113,14 +129,22 @@ def tpu_devices(chips: int):
 
 
 def model_config(config: dict):
+    """The program's config for ``config``; ValueError, naming the form,
+    where the program would not be what the form's reference computes."""
     from repro.configs import get_config
 
+    name = config["form"]
+    form = spec.form(name)
     m = dict(config["model"])
     cfg = dataclasses.replace(get_config(m.pop("base")), **m)
-    for k, want in MODEL_FORM.items():
+    for k, want in form.PROGRAM_KEYS.items():
         if getattr(cfg, k) != want:
-            raise ValueError(f"{k}={getattr(cfg, k)!r}: the reference "
-                             f"implements {want!r}")
+            raise ValueError(f"form {name}: {k}={getattr(cfg, k)!r} but "
+                             f"the form implements {want!r}")
+    for k, want in form.program_sizes(config).items():
+        if config[k] != want:
+            raise ValueError(f"form {name}: {k} {config[k]!r} but the "
+                             f"program takes {want!r}")
     return cfg
 
 
@@ -135,16 +159,13 @@ class Harness:
         from repro.compat import make_mesh
         from repro.core import SPConfig
         from repro.models import get_model
-        from repro.models.dit import COND_TOKENS
         from repro.serving import DiTServer, SamplerConfig
         from repro.serving.metrics import Tracker
 
         c = cell.config
-        if c["text_tokens"] != COND_TOKENS:
-            raise ValueError(f"text_tokens {c['text_tokens']} but the "
-                             f"program takes {COND_TOKENS}")
         self.cell, self.seed = cell, seed
         self.cfg = model_config(c)
+        init = spec.form(c["form"]).INIT
         mesh_shape = (c["mesh"]["data"], c["mesh"]["model"])
         self.mesh = make_mesh(mesh_shape, ("data", "model"),
                               devices=list(devices))
@@ -157,8 +178,8 @@ class Harness:
         rep = NamedSharding(self.mesh, P())
         make = jax.jit(lambda k: (
             weights.make_params(struct, k, self.cfg.n_layers,
-                                self.cfg.dtype),
-            weights.cond_pool(k, self.cfg.d_model, COND_TOKENS,
+                                self.cfg.dtype, init),
+            weights.cond_pool(k, c["text_tokens"], c["text_width"],
                               self.cfg.dtype)), out_shardings=rep)
         params, pool = make(key)
         self.conds = [pool[i] for i in range(weights.COND_POOL)]
@@ -254,25 +275,36 @@ class Harness:
             batches.append(Batch(start, end, len(results),
                                  self.by_rid[results[0].rid].length))
 
-    def serve(self, seconds: float, on_close, rate: float | None = None
-              ) -> tuple[list[Req], list[Batch]]:
-        """Offer the cell's open-loop traffic for ``seconds``, then drain
-        for at most the traffic's ``drain_s``; ``on_close`` runs once,
-        after the first ``run_once`` that ends past the window's close.
-        ``rate`` overrides the mix's rate (the knee sweep)."""
+    def serve(self, seconds: float, on_close, rate: float | None = None,
+              on_trace_end=lambda: None) -> tuple[list[Req], list[Batch]]:
+        """Offer the cell's traffic: an open loop's for ``seconds``, then
+        drain for at most the mix's ``drain_s``; a backlog's set until it
+        is served, for at most ``drain_s``.  ``on_close`` runs once,
+        after the first ``run_once`` that ends past the open window's
+        close (a backlog's: once its set is served), ``on_trace_end``
+        likewise past the mix's ``trace_s``, or with ``on_close`` where
+        that comes first.  ``rate`` overrides the mix's rate (the knee
+        sweep)."""
         import jax
 
         tr = self.cell.traffic
         rid0 = int(traffic.rng(self.seed).integers(RID_SPAN))
         batches: list[Batch] = []
-        closed = False
+        backlog = tr["loop"] == "backlog"
+        close_at = math.inf if backlog else seconds
+        trace_at = min(tr.get("trace_s", math.inf), close_at)
+        limit = tr["drain_s"] + (0 if backlog else seconds)
+        closed = trace_ended = False
         self._t0 = time.perf_counter()
-        sched = traffic.open_schedule(tr, self.seed, seconds, rate)
+        sched = traffic.schedule(tr, self.seed, seconds, rate)
         reqs = [Req(rid0 + i, a.length, a.due) for i, a in enumerate(sched)]
         nxt = 0
         while True:
             t = self.now()
-            if t >= seconds and not closed:
+            if t >= trace_at and not trace_ended:
+                on_trace_end()
+                trace_ended = True
+            if t >= close_at and not closed:
                 on_close()
                 closed = True
             while nxt < len(reqs) and reqs[nxt].due <= t:
@@ -280,13 +312,15 @@ class Harness:
                 nxt += 1
             if nxt == len(reqs) and not self.srv.pending:
                 break
-            if t >= seconds + tr["drain_s"]:
+            if t >= limit:
                 break
             if self.srv.pending:
                 self.run_once(batches)
             else:
                 with jax.profiler.TraceAnnotation("bench.wait_arrival"):
                     time.sleep(max(0.0, reqs[nxt].due - self.now()))
+        if not trace_ended:
+            on_trace_end()
         if not closed:
             on_close()
         return reqs, batches
@@ -322,19 +356,20 @@ def compare(cell: spec.Cell, seed: int, sample: list[Req], conds,
     from bench import reference, weights
 
     c = cell.config
-    n = reference.Dims.of(c)
+    form = spec.form(c["form"])
+    n = form.Dims.of(c)
     key = weights.base_key(seed)
     g = c["sampler"].get("guidance_scale", 1.0)
     steps = c["sampler"]["num_steps"]
     out = []
     for r in sample:
-        x0 = reference.initial_noise(r.rid, r.length, n.dtype)
+        x0 = reference.initial_noise(r.rid, r.length, c["latent_channels"],
+                                     n.dtype)
         cond = conds[r.rid % len(conds)]
-        want = reference.sample(key, n, x0, cond, steps, g,
-                                devices=devices)
+        want = form.sample(key, n, x0, cond, steps, g, devices=devices)
         got = (r.latents if mode == "program"
-               else reference.sample(key, n, x0, cond, steps, g, mode=mode,
-                                     devices=devices))
+               else form.sample(key, n, x0, cond, steps, g, mode=mode,
+                                devices=devices))
         out.append(reference.rel_err(np.asarray(got, np.float32),
                                      jax.device_get(want), x0))
     return out
@@ -401,31 +436,53 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         opts.python_tracer_level = 0
         jax.profiler.start_trace(log_dir, profiler_options=opts)
     setup_s = time.perf_counter() - T_PROCESS
+    stages: dict[str, float] = {}  # seconds of each stage of the trace
+
+    def timed(stage: str, fn, *args):
+        t0 = time.perf_counter()
+        out = fn(*args)
+        stages[stage] = time.perf_counter() - t0
+        return out
+
+    def on_trace_end() -> None:
+        traced["window_s"] = h.now()
+        if trace:
+            timed("stop_trace", jax.profiler.stop_trace)
 
     def on_close() -> None:
         traced["compiles"] = len(compiles)
-        traced["window_s"] = h.now()
-        if trace:
-            jax.profiler.stop_trace()
 
-    reqs, batches = h.serve(seconds, on_close)
+    reqs, batches = h.serve(seconds, on_close, on_trace_end=on_trace_end)
     gc.unfreeze()
     peak_mem = memory_peak_bytes(devices)
     dev = devices[0]
     tr = None
     if trace:
-        tr = trace_mod.load(trace_mod.find(log_dir))
+        tr = timed("parse", trace_mod.load, timed("find", trace_mod.find,
+                                                  log_dir))
         if trace_dir is None:
             shutil.rmtree(log_dir, ignore_errors=True)
     run = Run(cell, seconds, setup_s, reqs, batches,
-              [b for b in batches if b.start < traced["window_s"]], tr,
+              traced_batches(batches, traced["window_s"]), tr,
               dev.device_kind, len(devices), traced["compiles"])
     names = cell.per_layer if trace else cell.end_to_end
+    t_read = time.perf_counter()
     metrics = {}
     for name, entry in names.items():
         v = spec.metric_reader(name)(run)
         if v is not None:
             metrics[name] = {"value": v, "unit": entry["unit"]}
+    if trace:
+        busy = trace_mod.busy_s(tr)
+        breakdown = {"device_ops": trace_mod.top_ops(tr),
+                     "idle_gaps": trace_mod.idle_gaps(tr)}
+        stages["readers"] = time.perf_counter() - t_read
+        for scope, secs in readers.scope_seconds(tr).items():
+            print(f"scope {scope}: {secs!r} s", file=sys.stderr)
+        for name, secs in readers.stall_gaps(tr):
+            print(f"stall in {name}: {secs!r} s", file=sys.stderr)
+        for stage, secs in stages.items():
+            print(f"trace stage {stage}: {secs!r} s", file=sys.stderr)
     print(f"compiles in window: {run.compiles_in_window}", file=sys.stderr)
     late = [r.submitted - r.due for r in reqs if r.submitted is not None]
     if late:
@@ -456,10 +513,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
            "device": {"platform": dev.platform, "kind": dev.device_kind,
                       "count": len(devices), "memory_peak_bytes": peak_mem}}
     if trace:
-        out["device"]["busy_s"] = trace_mod.busy_s(tr)
+        out["device"]["busy_s"] = busy
         out["device"]["window_s"] = traced["window_s"]
-        out["breakdown"] = {"device_ops": trace_mod.top_ops(tr),
-                            "idle_gaps": trace_mod.idle_gaps(tr)}
+        out["breakdown"] = breakdown
     out["checks"] = checks
     for name, c in checks.items():
         print(f"check {name}: {c['value']!r} limit {c['limit']!r}",

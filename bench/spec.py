@@ -1,7 +1,8 @@
 """Finds a cell's parts by name: ``BENCHMARK.json`` at the checkout root,
-``configs/<config>.json`` (the entry's ``file``), ``traffic/<traffic>.json``
-and ``metrics/<metric>.py``.  Adding a cell, configuration, traffic mix
-or metric adds files and entries; nothing here changes."""
+``configs/<config>.json`` (the entry's ``file``), ``traffic/<traffic>.json``,
+``metrics/<metric>.py`` and ``forms/<form>.py`` (the config's ``form``).
+Adding a cell, configuration, traffic mix, metric or architecture adds
+files and entries; nothing here changes."""
 from __future__ import annotations
 
 import dataclasses
@@ -9,6 +10,8 @@ import importlib.util
 import json
 import pathlib
 import re
+import sys
+import zlib
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 HERE = pathlib.Path(__file__).resolve().parent
@@ -61,6 +64,27 @@ def metric_reader(name: str, root: pathlib.Path = ROOT):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def form(name: str, root: pathlib.Path = ROOT):
+    """The architecture module ``forms/<name>.py`` that a config names
+    under ``form``, executed once a process; ValueError, naming the
+    form, when there is none."""
+    path = root / "bench" / "forms" / f"{name}.py"
+    if not (NAME.match(name) and path.is_file()):
+        raise ValueError(f"form {name!r}: no bench/forms/{name}.py")
+    mod_name = "bench_form_{}_{:08x}".format(
+        re.sub(r"\W", "_", name), zlib.crc32(str(path.resolve()).encode()))
+    if mod_name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(mod_name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[mod_name] = mod  # the form's functions look it up
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[mod_name]
+            raise
+    return sys.modules[mod_name]
 
 
 def peak(device_kind: str) -> dict:

@@ -12,7 +12,8 @@ def tiny_cell(chips: int = 1, dtype: str = "float32",
         "model": {"base": "flux-12b", "d_model": 64, "n_heads": 2,
                   "n_kv_heads": 2, "head_dim": 32, "d_ff": 128,
                   "n_layers": 2, "dtype": dtype},
-        "text_tokens": 256, "mesh": {"data": 1, "model": chips},
+        "form": "dit_uniform", "text_tokens": 256, "text_width": 64,
+        "latent_channels": 64, "mesh": {"data": 1, "model": chips},
         "sp": sp or {"strategy": "full"},
         "sampler": {"num_steps": 3, "guidance_scale": guidance},
         "max_batch": 2,

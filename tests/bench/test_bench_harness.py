@@ -1,6 +1,7 @@
 """The harness end to end on the CPU at a tiny size: a sound run is
 correct and reports its metrics; without a TPU the command prints no
 result and exits non-zero."""
+import dataclasses
 import json
 import os
 import pathlib
@@ -11,7 +12,7 @@ import sys
 import jax
 import pytest
 
-from bench import run, spec
+from bench import readers, run, spec
 
 ROOT = spec.ROOT
 
@@ -65,3 +66,28 @@ def test_readers_find_nothing_without_a_trace(tiny):
     for name in ("step_mfu.image", "device_idle_share.image"):
         assert spec.metric_reader(name)(r) is None
     assert pathlib.Path(ROOT / "bench" / "metrics").is_dir()
+
+
+def test_a_backlog_is_served_whole_and_trace_s_ends_the_trace_early(tiny):
+    """A backlog mix's set is all submitted at the open and served to the
+    last request, whatever ``seconds`` says; its ``trace_s`` ends the
+    trace after the first batch, and only that batch counts as traced."""
+    cell = tiny()
+    cell = dataclasses.replace(cell, traffic={
+        "loop": "backlog", "requests": 6, "lengths": [64, 128],
+        "shares": [0.5, 0.5], "drain_s": 120, "trace_s": 1e-3})
+    h = run.Harness(cell, 5, jax.devices()[:1])
+    h.warm_up()
+    marks = []
+    reqs, batches = h.serve(
+        0.5, lambda: marks.append(("close", h.now())),
+        on_trace_end=lambda: marks.append(("trace", h.now())))
+    assert len(reqs) == 6 and all(r.done is not None for r in reqs)
+    assert all(r.due == 0.0 for r in reqs) and len(batches) >= 3
+    assert [m[0] for m in marks] == ["trace", "close"]
+    assert batches[0].end <= marks[0][1] < batches[1].start
+    assert marks[1][1] >= batches[-1].end
+    assert run.traced_batches(batches, marks[0][1]) == batches[:1]
+    lat = readers.latencies(run.Run(cell, 0.5, 0.0, reqs, batches, [], None,
+                                    "cpu", 1, 0))
+    assert sorted(lat) == sorted(r.done for r in reqs)

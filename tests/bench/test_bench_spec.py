@@ -27,8 +27,9 @@ def test_every_cell_is_found_by_name(w):
     assert cell.per_layer
     for name in list(cell.end_to_end) + list(cell.per_layer):
         assert callable(spec.metric_reader(name))
-    assert cell.traffic["loop"] == "open"
+    assert cell.traffic["loop"] in ("open", "backlog")
     assert math.isclose(sum(cell.traffic["shares"]), 1.0)
+    spec.form(cell.config["form"])
 
 
 def test_names_and_units_use_allowed_characters():
@@ -74,11 +75,19 @@ def test_config_states_source_cut_and_published_widths(path):
 
 
 def test_a_new_cell_needs_only_new_files(tmp_path):
-    (tmp_path / "bench" / "configs").mkdir(parents=True)
-    (tmp_path / "bench" / "traffic").mkdir()
-    (tmp_path / "bench" / "metrics").mkdir()
+    """A cell with a config of a new architecture, a backlog mix and a
+    metric of its own: each a new file, found by its name."""
+    for d in ("configs", "traffic", "metrics", "forms"):
+        (tmp_path / "bench" / d).mkdir(parents=True)
     (tmp_path / "bench" / "configs" / "new.json").write_text(
-        json.dumps({"model": {"n_layers": 1}}))
+        json.dumps({"form": "new_form", "model": {"n_layers": 1}}))
+    (tmp_path / "bench" / "forms" / "new_form.py").write_text(
+        "PROGRAM_KEYS = {'act': 'swiglu'}\n"
+        "def forward_flops(config, rows, latent):\n"
+        "    return 7.0 * rows * latent\n")
+    (tmp_path / "bench" / "traffic" / "video.json").write_text(json.dumps(
+        {"loop": "backlog", "requests": 6, "lengths": [17552],
+         "shares": [1.0], "drain_s": 300, "trace_s": 12}))
     (tmp_path / "bench" / "traffic" / "burst.json").write_text(json.dumps(
         {"loop": "open", "rate_per_s": 2.0, "lengths": [1024],
          "shares": [1.0]}))
@@ -89,15 +98,22 @@ def test_a_new_cell_needs_only_new_files(tmp_path):
         {"name": "new", "file": "bench/configs/new.json"}]
     bench["workloads"] = BENCH["workloads"] + [
         {"name": "new_cell", "config": "new", "traffic": "burst",
-         "chips": 1}]
+         "chips": 1},
+        {"name": "new_video", "config": "new", "traffic": "video",
+         "chips": 4}]
     bench["per_layer"] = BENCH["per_layer"] + [
         {"name": "new_metric", "unit": "%", "workloads": ["new_cell"]}]
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     cell = spec.cell("new_cell", root=tmp_path)
-    assert cell.config == {"model": {"n_layers": 1}}
+    assert cell.config == {"form": "new_form", "model": {"n_layers": 1}}
     assert cell.traffic["rate_per_s"] == 2.0
     assert "new_metric" in cell.per_layer
     assert spec.metric_reader("new_metric", root=tmp_path)(None) == 42.0
+    form = spec.form(cell.config["form"], root=tmp_path)
+    assert form.PROGRAM_KEYS == {"act": "swiglu"}
+    assert form.forward_flops(cell.config, 2, 3) == 42.0
+    video = spec.cell("new_video", root=tmp_path)
+    assert len(traffic.schedule(video.traffic, 1, 50.0)) == 6
     with pytest.raises(KeyError):
         spec.cell("no_such_cell", root=tmp_path)
 
@@ -150,8 +166,33 @@ def test_a_mix_with_a_schedule_seed_replays_one_trace():
 def test_every_traffic_and_metric_file_loads():
     for path in (spec.ROOT / "bench" / "traffic").glob("*.json"):
         mix = json.loads(path.read_text())
-        assert mix["loop"] == "open"
+        assert traffic.schedule(mix, 1, 10.0)
         assert len(mix["lengths"]) == len(mix["shares"])
     for path in (spec.ROOT / "bench" / "metrics").glob("*.py"):
         assert spec.NAME.match(path.stem)
         assert callable(spec.metric_reader(path.stem))
+
+
+@pytest.mark.parametrize("seed", [0, 2**31 + 5, 2**40 + 3])
+def test_backlog_is_a_fixed_set_all_due_at_the_open(seed):
+    tr = {"loop": "backlog", "requests": 7, "lengths": [4096, 17552],
+          "shares": [0.3, 0.7], "drain_s": 300}
+    a = traffic.schedule(tr, seed, 50.0)
+    assert len(a) == 7 and all(x.due == 0.0 for x in a)
+    lens = [x.length for x in a]
+    assert (lens.count(4096), lens.count(17552)) == (2, 5)
+    assert a == traffic.schedule(tr, seed, 5.0)  # the window does not size it
+    orders = {tuple(x.length for x in traffic.schedule(tr, s, 50.0))
+              for s in range(seed, seed + 8)}
+    assert len(orders) > 1  # the order is drawn from the run's seed
+    fixed = dict(tr, schedule_seed=9)
+    assert traffic.schedule(fixed, seed, 50.0) == traffic.schedule(
+        fixed, seed + 1, 50.0)
+
+
+def test_an_open_mix_keeps_its_schedule_and_an_unknown_loop_fails():
+    tr = spec.cell("flux_img_mix").traffic
+    assert traffic.schedule(tr, 3, 50.0) == traffic.open_schedule(
+        tr, 3, 50.0)
+    with pytest.raises(ValueError, match="closed"):
+        traffic.schedule(dict(tr, loop="closed"), 3, 50.0)
