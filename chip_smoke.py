@@ -109,11 +109,12 @@ def ref_inputs(cfg, tokens: int, seed: int, dtype: str):
     import jax
     import jax.numpy as jnp
 
-    from repro.models.dit import COND_TOKENS, LATENT_CHANNELS
+    from repro.models.dit import LATENT_CHANNELS
 
     kx, kc = jax.random.split(jax.random.PRNGKey(seed + 2))
     x = jax.random.normal(kx, (1, tokens, LATENT_CHANNELS), jnp.float32)
-    cond = jax.random.normal(kc, (1, COND_TOKENS, cfg.d_model), jnp.float32)
+    cond = jax.random.normal(kc, (1, cfg.text_tokens, cfg.cond_width),
+                             jnp.float32)
     return x.astype(dtype), cond.astype(dtype)
 
 

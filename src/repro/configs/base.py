@@ -48,7 +48,8 @@ class ModelConfig:
     vocab: int
     head_dim: int = 0  # 0 -> d_model // n_heads
     # --- positional / attention flavour ---
-    rope: Literal["rope", "mrope", "rope2d", "sinusoidal", "none"] = "rope"
+    rope: Literal["rope", "mrope", "rope2d", "rope3d", "sinusoidal",
+                  "none"] = "rope"
     rope_theta: float = 10000.0
     rope_pct: float = 1.0  # stablelm: partial rotary
     qkv_bias: bool = False
@@ -63,6 +64,20 @@ class ModelConfig:
     norm: Literal["rmsnorm", "layernorm"] = "rmsnorm"
     act: Literal["swiglu", "gelu", "geglu"] = "swiglu"
     tie_embeddings: bool = False
+    # --- DiT (family "dit"); the defaults are the repo's uniform adaLN block
+    # "cogvideox": CogVideoX's expert adaLN (text and latent tokens
+    # modulated apart), real timestep features, biased linears and the
+    # final AdaLayerNorm (models/dit.py)
+    block: Literal["uniform", "cogvideox"] = "uniform"
+    text_tokens: int = 256  # conditioning tokens ahead of the latents
+    text_width: int = 0  # width of the text embeddings; 0 = d_model
+    time_embed_dim: int = 0  # cogvideox: width of the timestep embedding
+    qk_norm: bool = False  # per-head affine LayerNorm on q and k
+    proj_bias: bool = False  # bias on the attention output and MLP linears
+    # rope3d: a latent frame's (rows, cols) of patches, and head_dim split
+    # over (frame, row, col); the text tokens ahead are not rotated
+    patch_grid: tuple[int, ...] = ()
+    rope_axes: tuple[int, ...] = ()
     # --- numerics / sharding ---
     dtype: str = "bfloat16"
     # logical-axis -> mesh-axes rules; see models/sharding.py
@@ -74,6 +89,11 @@ class ModelConfig:
         if self.head_dim:
             return self.head_dim
         return self.d_model // max(self.n_heads, 1)
+
+    @property
+    def cond_width(self) -> int:
+        """Width of the text embeddings a DiT takes."""
+        return self.text_width or self.d_model
 
     @property
     def attention_free(self) -> bool:

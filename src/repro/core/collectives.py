@@ -11,7 +11,10 @@ overlap NVSHMEM gives the paper.  Every schedule is therefore built from
 channel puts over a *flattened* SP axis, with the paper's logical
 (P_u × P_r) factorisation expressed as plain rank arithmetic.  This module
 owns the layout bookkeeping (GroupLayout) and the all-to-all entry points;
-the staged transfer programs themselves are ``repro.comm.stream``'s.
+the staged transfer programs themselves are ``repro.comm.stream``'s.  The
+entry points, like the ring and torus hops in ``ring.py`` and
+``torus.py``, run under the named scope ``sp_comm``: in a device trace
+the SP exchange's ops carry it in their ``tf_op`` path.
 
 Logical layout (see planner.py):
   flat rank p in [0, P_u * P_r) over the mesh SP axes (major axis first).
@@ -167,6 +170,7 @@ class GroupLayout:
 # entry point.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("sp_comm")
 def grouped_all_to_all(
     x: jax.Array,
     layout: GroupLayout,
@@ -200,6 +204,7 @@ def grouped_all_to_all(
                              backend=backend, interpret=interpret)
 
 
+@jax.named_scope("sp_comm")
 def monolithic_all_to_all(
     x: jax.Array, layout: GroupLayout, *, split_axis: int,
     backend: str = "xla", interpret: bool | None = None,
@@ -230,6 +235,7 @@ def monolithic_all_to_all(
                               backend=backend, interpret=interpret)
 
 
+@jax.named_scope("sp_comm")
 def ungroup_all_to_all(
     stacked: jax.Array, layout: GroupLayout, *, concat_axis: int,
     backend: str = "xla", interpret: bool | None = None,

@@ -104,8 +104,9 @@ def ring_attention(
     def body(s, carry):
         kc, vc, acc = carry
         # issue next-step transfer first (double buffer), compute current
-        nxt = ring_shift(layout, kc, vc, stream=stream,
-                         overlaps="ring attend")
+        with jax.named_scope("sp_comm"):
+            nxt = ring_shift(layout, kc, vc, stream=stream,
+                             overlaps="ring attend")
         owner = (my_r - s) % p_r  # ring rank whose shard I currently hold
         acc = merge(acc, _attend(q, kc, vc, mask_for(owner)))
         _profiler.mark_compute("ring attend", layout.axes, (kc, vc),
@@ -122,8 +123,9 @@ def ring_attention(
         for s in range(p_r - 1):
             # fence this step's attend inputs on the accumulator so only one
             # step's score matrix is live; the next put stays independent
-            nxt = ring_shift(layout, kc, vc, stream=stream,
-                             overlaps="ring attend")
+            with jax.named_scope("sp_comm"):
+                nxt = ring_shift(layout, kc, vc, stream=stream,
+                                 overlaps="ring attend")
             (kc_g, vc_g), accs = fence((kc, vc), tuple(acc))
             acc = Partial(*accs)
             owner = (my_r - s) % p_r

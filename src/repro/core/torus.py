@@ -146,8 +146,9 @@ def torus_attention(
     q_recv = [None] * p_u  # q_recv[j] = Q chunk from ulysses peer j
     for kstage in range(1, p_u):
         send = jnp.take(qc, (u + kstage) % p_u, axis=0)
-        recv = torus_hop(layout, kstage, send, stream=stream,
-                         overlaps="diag-KV attend").wait()
+        with jax.named_scope("sp_comm"):
+            recv = torus_hop(layout, kstage, send, stream=stream,
+                             overlaps="diag-KV attend").wait()
         src = (u - kstage) % p_u
         if not fused_pull_q:
             part = ring_attention(
@@ -182,11 +183,12 @@ def torus_attention(
     # ---- Pull-KV stages: KV chunks arrive; all Q attends each new chunk
     for kstage in range(1, p_u):
         src = (u - kstage) % p_u
-        k_recv, v_recv = torus_hop(
-            layout, kstage,
-            jnp.take(kc, (u + kstage) % p_u, axis=0),
-            jnp.take(vc, (u + kstage) % p_u, axis=0),
-            stream=stream, overlaps="gathered-Q attend").wait()
+        with jax.named_scope("sp_comm"):
+            k_recv, v_recv = torus_hop(
+                layout, kstage,
+                jnp.take(kc, (u + kstage) % p_u, axis=0),
+                jnp.take(vc, (u + kstage) % p_u, axis=0),
+                stream=stream, overlaps="gathered-Q attend").wait()
         (k_recv, v_recv), acc = _gate((k_recv, v_recv), acc)
         kpos_fn = lambda owner_r, s=src: _rank_of(layout, s, owner_r) * ls + jnp.arange(ls)
         part = ring_attention(

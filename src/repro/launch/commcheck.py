@@ -59,7 +59,7 @@ def main() -> int:
     from ..core import SPConfig, sp_attention
     from ..core.pipefusion import KVState, PipelineConfig
     from ..models import ParallelContext, get_model
-    from ..models.dit import COND_TOKENS, dit_forward_displaced
+    from ..models.dit import dit_forward_displaced
     from ..serving import SamplerConfig
     from ..serving.sampler import hybrid_state_shape
     from ..compat import make_mesh
@@ -102,7 +102,8 @@ def main() -> int:
     seq = 32
     lat = jax.random.normal(jax.random.PRNGKey(2), (1, seq, 64), jnp.float32)
     cond = jax.random.normal(jax.random.PRNGKey(3),
-                             (1, COND_TOKENS, cfg.d_model), jnp.float32)
+                             (1, cfg.text_tokens, cfg.cond_width),
+                             jnp.float32)
     state = hybrid_state_shape(cfg, 1, seq, sc)
     tt = jnp.full((1,), 0.5, jnp.float32)
 

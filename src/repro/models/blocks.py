@@ -133,10 +133,13 @@ def layer_norm(x: jax.Array, w: jax.Array, b: jax.Array, eps: float = 1e-5) -> j
     return ((x - mu) * jax.lax.rsqrt(var + eps) * w + b).astype(dt)
 
 
-def norm(x: jax.Array, p: Params, kind: str) -> jax.Array:
+QK_NORM_EPS = 1e-6  # CogVideoX's per-head q/k LayerNorm
+
+
+def norm(x: jax.Array, p: Params, kind: str, eps: float = 1e-5) -> jax.Array:
     if kind == "rmsnorm":
         return rms_norm(x, p["scale"])
-    return layer_norm(x, p["scale"], p["bias"])
+    return layer_norm(x, p["scale"], p["bias"], eps)
 
 
 def init_norm(b: ParamBuilder, name: str, d: int, kind: str) -> None:
@@ -222,6 +225,29 @@ def apply_rope(
     return rot_fn(q), rot_fn(k)
 
 
+def apply_rope3d(x: jax.Array, positions: jax.Array, cfg) -> jax.Array:
+    """CogVideoX's 3-D rotary embedding of x [B, L, H, D] at ``positions``
+    [B, L].  Position p >= ``cfg.text_tokens`` is latent token
+    p - text_tokens, at (frame, row, col) of frames of ``cfg.patch_grid``
+    (rows, cols) patches; the head dim splits into ``cfg.rope_axes``
+    slices rotated by frame, row and col, each in interleaved pairs
+    (x[2i], x[2i+1]) at angle coord * theta^(-2i / slice width).  The text
+    tokens ahead are not rotated."""
+    rows, cols = cfg.patch_grid
+    p = positions - cfg.text_tokens
+    coords = (p // (rows * cols), (p // cols) % rows, p % cols)
+    ang = jnp.concatenate(
+        [c[..., None].astype(jnp.float32)
+         * cfg.rope_theta ** (-jnp.arange(0, w, 2, dtype=jnp.float32) / w)
+         for c, w in zip(coords, cfg.rope_axes)], axis=-1)
+    sin, cos = jnp.sin(ang)[:, :, None, :], jnp.cos(ang)[:, :, None, :]
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    x0, x1 = pairs[..., 0], pairs[..., 1]
+    rot = jnp.stack([x0 * cos - x1 * sin, x1 * cos + x0 * sin],
+                    axis=-1).reshape(x.shape)
+    return jnp.where((p >= 0)[:, :, None, None], rot, x).astype(x.dtype)
+
+
 def sinusoidal_embedding(length: int, d: int) -> jax.Array:
     """Whisper-style sinusoidal positional table [length, d]."""
     half = d // 2
@@ -242,7 +268,11 @@ def init_attention(b: ParamBuilder, cfg, prefix: str = "attn",
     init_linear(b, f"{prefix}/wk", d, hkv * hd, ("embed", "kv_heads_flat"), bias=cfg.qkv_bias)
     init_linear(b, f"{prefix}/wv", d, hkv * hd, ("embed", "kv_heads_flat"), bias=cfg.qkv_bias)
     init_linear(b, f"{prefix}/wo", hq * hd, d, ("heads_flat", "embed"),
+                bias=cfg.proj_bias,
                 scale=(hq * hd) ** -0.5 / (2 * cfg.n_layers) ** 0.5)
+    if cfg.qk_norm:
+        for name in ("q_norm", "k_norm"):
+            init_norm(b, f"{prefix}/{name}", hd, "layernorm")
 
 
 def attention(
@@ -278,16 +308,23 @@ def attention(
     causal = cfg.causal if causal is None else causal
     src = x if xkv is None else xkv
 
-    # named scopes (qkv, attn, attn_out; mlp in ``mlp``) are metadata only:
+    # named scopes (qkv with qk_prep inside, attn, attn_out; mlp in
+    # ``mlp``; sp_comm in core/ around the SP exchanges) are metadata only:
     # they prefix the ops' names in the device trace, so a profile splits
     # a block's device time by part
     with jax.named_scope("qkv"):
         q = linear(x, p["wq"]).reshape(b_, l_, hq, hd)
         k = linear(src, p["wk"]).reshape(b_, src.shape[1], hkv, hd)
         v = linear(src, p["wv"]).reshape(b_, src.shape[1], hkv, hd)
-        if xkv is None:  # no rope on cross-attention
-            q, k = apply_rope(q, k, positions, variant=cfg.rope,
-                              theta=cfg.rope_theta, rope_pct=cfg.rope_pct)
+        with jax.named_scope("qk_prep"):
+            if cfg.qk_norm:
+                q = norm(q, p["q_norm"], "layernorm", QK_NORM_EPS)
+                k = norm(k, p["k_norm"], "layernorm", QK_NORM_EPS)
+            if xkv is None and cfg.rope == "rope3d":
+                q, k = (apply_rope3d(a, positions, cfg) for a in (q, k))
+            elif xkv is None:  # no rope on cross-attention
+                q, k = apply_rope(q, k, positions, variant=cfg.rope,
+                                  theta=cfg.rope_theta, rope_pct=cfg.rope_pct)
 
     with jax.named_scope("attn"):
         if extra_kv is not None:
@@ -339,12 +376,12 @@ def init_mlp(b: ParamBuilder, cfg, prefix: str = "mlp", d_ff: int | None = None,
              logical_ff: str = "mlp") -> None:
     d = cfg.d_model
     ff = d_ff or cfg.d_ff
+    bias = cfg.proj_bias
     if cfg.act in ("swiglu", "geglu"):
-        init_linear(b, f"{prefix}/wi_gate", d, ff, ("embed", logical_ff))
-        init_linear(b, f"{prefix}/wi_up", d, ff, ("embed", logical_ff))
-    else:
-        init_linear(b, f"{prefix}/wi_up", d, ff, ("embed", logical_ff))
-    init_linear(b, f"{prefix}/wo", ff, d, (logical_ff, "embed"),
+        init_linear(b, f"{prefix}/wi_gate", d, ff, ("embed", logical_ff),
+                    bias=bias)
+    init_linear(b, f"{prefix}/wi_up", d, ff, ("embed", logical_ff), bias=bias)
+    init_linear(b, f"{prefix}/wo", ff, d, (logical_ff, "embed"), bias=bias,
                 scale=ff ** -0.5 / (2 * cfg.n_layers) ** 0.5)
 
 

@@ -191,7 +191,7 @@ def _dit_inputs(cfg, shape, abstract=True, key=None, dtype=None):
     b, t = shape.global_batch, shape.seq_len
     batch = {
         "latents": _sds((b, t, dit_mod.LATENT_CHANNELS), dtype),
-        "cond": _sds((b, dit_mod.COND_TOKENS, cfg.d_model), dtype),
+        "cond": _sds((b, cfg.text_tokens, cfg.cond_width), dtype),
         "timesteps": _sds((b,), jnp.float32),
         "targets": _sds((b, t, dit_mod.LATENT_CHANNELS), dtype),
     }

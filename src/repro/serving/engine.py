@@ -33,7 +33,7 @@ from ..core import SPConfig, plan_hybrid
 from ..core.strategy import attention_lowering
 from ..core.comm_model import NetworkModel
 from ..models import ParallelContext, get_model, param_shardings
-from ..models.dit import COND_TOKENS, LATENT_CHANNELS
+from ..models.dit import LATENT_CHANNELS
 from .metrics import Tracker, profiler_annotation
 from .sampler import (
     SamplerConfig,
@@ -63,7 +63,8 @@ from .sched import (
 class DiTRequest:
     rid: int
     seq_len: int  # latent tokens (resolution / duration proxy)
-    cond: jax.Array | None = None  # [COND_TOKENS, d] text embedding (stub)
+    # [cfg.text_tokens, cfg.cond_width] text embedding (stub); None = zeros
+    cond: jax.Array | None = None
     submitted: float = 0.0
     # SLA: seconds from submission to deadline; None = best-effort.  The
     # admission policy scores deadline slack with the comm model's
@@ -260,11 +261,12 @@ class DiTServer:
         # pipelined bucket, else sp_attention's lowering of the joint
         # (text + latent) self-attention
         attn = ("displaced" if sc.pipelined else attention_lowering(
-            self.ctx.sp, self.ctx.mesh, COND_TOKENS + seq,
+            self.ctx.sp, self.ctx.mesh, self.cfg.text_tokens + seq,
             self.cfg.resolved_head_dim))
         return self.plan_cache.step_fn(batch, seq, build,
                                        variant=choice.num_patches,
-                                       build_tags={"attn": attn})
+                                       build_tags={"attn": attn,
+                                                   "block": self.cfg.block})
 
     def _dp_degree(self) -> int:
         ba = self.ctx.sp.batch_axes or ()
@@ -368,14 +370,14 @@ class DiTServer:
         n_real = len(batch)
         b = adm.batch_rows  # n_real + dp padding rows (dropped at the end)
         t = adm.seq_len
-        d = self.cfg.d_model
         # span tags stay low-cardinality: no request or admission ids
         span_tags = {"rows": n_real, "seq": t}
         with tr.span("engine.prepare", tags=span_tags):
             sc = self._bucket_sampler(adm.plan)
             cond = jnp.stack([
                 (batch[i].cond if i < n_real and batch[i].cond is not None
-                 else jnp.zeros((COND_TOKENS, d), self.cfg.dtype))
+                 else jnp.zeros((self.cfg.text_tokens, self.cfg.cond_width),
+                                self.cfg.dtype))
                 for i in range(b)
             ])
             x = self._noise(batch, b, t)
@@ -392,17 +394,20 @@ class DiTServer:
         drift_vals = []
         resyncs = 0
         t_enqueued = 0.0
+        # a step runs one forward a guidance branch of each request
+        dispatch_tags = {**span_tags, "branches": sc.branches}
 
         def dispatch(i: int, f, *args):
             """Enqueue sampler step ``i`` (``f(*args)``) as an
-            ``engine.dispatch`` span.  Measured, only its profiler
-            annotation is open here: ``tick`` publishes the record once
-            the step's clock has stopped."""
+            ``engine.dispatch`` span, tagged also with the guidance
+            ``branches``.  Measured, only its profiler annotation is open
+            here: ``tick`` publishes the record once the step's clock has
+            stopped."""
             nonlocal t_enqueued
             if not measure:
-                with tr.span("engine.dispatch", step=i, tags=span_tags):
+                with tr.span("engine.dispatch", step=i, tags=dispatch_tags):
                     return f(*args)
-            with profiler_annotation("engine.dispatch", span_tags):
+            with profiler_annotation("engine.dispatch", dispatch_tags):
                 out = f(*args)
             t_enqueued = time.perf_counter()
             return out
@@ -423,7 +428,8 @@ class DiTServer:
                 ep = tr.epoch
                 rec_tags = {**span_tags, "parent": "engine.run_once"}
                 tr.span_event("engine.dispatch", t0 - ep, t_enqueued - t0,
-                              step=i, tags=rec_tags)
+                              step=i, tags={**rec_tags,
+                                            "branches": sc.branches})
                 tr.span_event("engine.sync", t_sync - ep, t_ready - t_sync,
                               step=i, tags=rec_tags)
                 self.tracker.log("engine.t_step_s", t_step, step=i,

@@ -18,7 +18,7 @@ Beyond the paper, the sampler composes two extra parallel axes with SP
     whole step.  The classic pair is k = 2 with weights ``(g, 1-g)``;
     ``cfg_weights`` generalises to negative prompts and multi-conditioning
     stacks (k > 2), with per-branch conditioning passed as a stacked
-    ``[k, B, COND_TOKENS, d]`` tensor.
+    ``[k, B, text_tokens, cond_width]`` tensor.
   * **Displaced patch pipelining** (``SamplerConfig.pipeline``): after
     ``warmup_steps`` synchronous steps, each step runs the PipeFusion
     forward (models/dit.py: ``dit_forward_displaced``) reusing
@@ -36,12 +36,7 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..core.pipefusion import KVState, PipelineConfig, init_kv_state, kv_drift
 from ..models import ParallelContext
-from ..models.dit import (
-    COND_TOKENS,
-    LATENT_CHANNELS,
-    dit_forward,
-    dit_forward_displaced,
-)
+from ..models.dit import LATENT_CHANNELS, dit_forward, dit_forward_displaced
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,7 +46,7 @@ class SamplerConfig:
     # Per-branch guidance weights for degree-k CFG (ROADMAP: k > 2 stacks).
     # None = classic 2-way (guidance_scale, 1 - guidance_scale).  With k > 2
     # (or a negative prompt at k = 2) every branch's conditioning must be
-    # supplied explicitly as a stacked [k, B, COND_TOKENS, d] ``cond``
+    # supplied explicitly as a stacked [k, B, text_tokens, cond_width] cond
     # (zeros rows = unconditional branches).
     cfg_weights: tuple[float, ...] | None = None
     # hybrid parallelism (DESIGN.md §7); both compose with any SP strategy
@@ -71,6 +66,11 @@ class SamplerConfig:
     @property
     def cfg_degree(self) -> int:
         return len(self.branch_weights)
+
+    @property
+    def branches(self) -> int:
+        """Forwards a step takes of each request: its guidance branches."""
+        return self.cfg_degree if self.guided else 1
 
     @property
     def pipelined(self) -> bool:
@@ -162,7 +162,7 @@ def hybrid_state_shape(cfg: ModelConfig, batch: int, seq_len: int,
     """Zero KVState matching what the hybrid steps thread (all k guidance
     branches included when cfg-parallel)."""
     b = sc.cfg_degree * batch if (sc.guided and sc.cfg_parallel) else batch
-    return init_kv_state(cfg.n_layers, b, COND_TOKENS + seq_len,
+    return init_kv_state(cfg.n_layers, b, cfg.text_tokens + seq_len,
                          cfg.n_kv_heads, cfg.resolved_head_dim,
                          jnp.dtype(cfg.dtype))
 

@@ -23,7 +23,7 @@ from repro.core.collectives import (
 from repro.core.pipefusion import PipelineConfig
 from repro.launch.mesh import make_hybrid_mesh
 from repro.models import ParallelContext, get_model
-from repro.models.dit import COND_TOKENS, dit_forward_displaced
+from repro.models.dit import dit_forward_displaced
 from repro.serving import SamplerConfig
 from repro.serving.sampler import hybrid_state_shape
 
@@ -160,7 +160,7 @@ def test_displaced_pipe_handoff_overlaps_stage_compute(rng):
     seq = 32
     lat = jax.random.normal(rng, (1, seq, 64), jnp.float32)
     cond = jax.random.normal(jax.random.PRNGKey(1),
-                             (1, COND_TOKENS, cfg.d_model), jnp.float32)
+                             (1, cfg.text_tokens, cfg.cond_width), jnp.float32)
     state = hybrid_state_shape(cfg, 1, seq, sc)
     tt = jnp.full((1,), 0.5, jnp.float32)
 

@@ -12,7 +12,6 @@ from repro.configs import get_reduced
 from repro.core import PipelineConfig, SPConfig
 from repro.launch.mesh import make_host_mesh, make_hybrid_mesh
 from repro.models import ParallelContext, get_model
-from repro.models.dit import COND_TOKENS
 from repro.serving import DiTRequest, DiTServer, SamplerConfig, sample
 
 SEQ = 64
@@ -35,7 +34,7 @@ def setup():
               for l, k in zip(leaves, keys)]
     params = jax.tree.unflatten(treedef, leaves)
     cond = jax.random.normal(jax.random.PRNGKey(1),
-                             (1, COND_TOKENS, cfg.d_model), jnp.float32)
+                             (1, cfg.text_tokens, cfg.cond_width), jnp.float32)
     return cfg, params, axes, cond
 
 
@@ -107,7 +106,7 @@ def test_cfg_degree_4_on_4way_cfg_axis(setup):
     weights = (2.0, 1.0, 0.5, -2.5)
     conds = jnp.concatenate(
         [cond, 2.0 * cond, -1.0 * cond, jnp.zeros_like(cond)], axis=0)
-    conds = conds.reshape(4, 1, COND_TOKENS, cfg.d_model)
+    conds = conds.reshape(4, 1, cfg.text_tokens, cfg.cond_width)
     ref = _sample(cfg, params, conds,
                   make_host_mesh(),
                   SPConfig(strategy="full", sp_axes=("model",),

@@ -28,7 +28,7 @@ from repro.core import SPConfig
 from repro.kernels.flash_mqkv import flash_mqkv
 from repro.kernels.ring_flash import ring_flash_step
 from repro.models import ParallelContext, get_model
-from repro.models.dit import COND_TOKENS, LATENT_CHANNELS
+from repro.models.dit import LATENT_CHANNELS
 from repro.serving import SamplerConfig
 from repro.serving.sampler import sample_step
 from repro.train import AdamWConfig
@@ -36,7 +36,7 @@ from repro.train.optimizer import init_adamw
 from repro.train.trainer import make_train_step
 
 HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
-TOKENS = 4096 + COND_TOKENS  # a 1024x1024 image's latents plus text
+TOKENS = 4096 + get_config("flux-12b").text_tokens  # 1024^2 image + text
 
 
 @pytest.fixture(scope="module")
@@ -113,18 +113,18 @@ def test_remote_put_compiles(topo, mesh_shape, route):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _flux_step(mesh, sp, tokens, sharding):
-    """The served sampler step of flux-12b at published widths, depth cut
+def _dit_step(mesh, sp, tokens, sharding, arch="flux-12b", guidance=1.0):
+    """The served sampler step of ``arch`` at published widths, depth cut
     to 2 blocks, bf16; shapes only."""
-    cfg = dataclasses.replace(get_config("flux-12b"), n_layers=2)
+    cfg = dataclasses.replace(get_config(arch), n_layers=2)
     bundle = get_model(cfg)
     params = jax.eval_shape(
         lambda: bundle.init(cfg, jax.random.PRNGKey(0), 1)[0])
     params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, sharding), params)
     ctx = ParallelContext(mesh, sp, "prefill")
-    sc = SamplerConfig(num_steps=4)
+    sc = SamplerConfig(num_steps=4, guidance_scale=guidance)
     x = _sds((1, tokens, LATENT_CHANNELS), jnp.bfloat16, sharding)
-    cond = _sds((1, COND_TOKENS, cfg.d_model), jnp.bfloat16, sharding)
+    cond = _sds((1, cfg.text_tokens, cfg.cond_width), jnp.bfloat16, sharding)
     t = _sds((), jnp.float32, sharding)
 
     def step(params, x, cond, t):
@@ -150,7 +150,7 @@ def test_flux_step_one_chip(topo, one_chip, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
     sp = SPConfig(strategy="full", sp_axes=("model",), batch_axes=("data",))
-    compiled = _flux_step(mesh, sp, 4096, one_chip)
+    compiled = _dit_step(mesh, sp, 4096, one_chip)
     _assert_fits(compiled)
     assert "tpu_custom_call" in compiled.as_text()
     temp = compiled.memory_analysis().temp_size_in_bytes
@@ -190,7 +190,21 @@ def test_flux_step_sp4(topo, monkeypatch, strategy, backend):
     mesh = make_mesh((1, 4), ("data", "model"), devices=topo.devices)
     sp = SPConfig(strategy=strategy, sp_axes=("model",),
                   batch_axes=("data",), comm_backend=backend)
-    compiled = _flux_step(mesh, sp, 16384, NamedSharding(mesh, P()))
+    compiled = _dit_step(mesh, sp, 16384, NamedSharding(mesh, P()))
     _assert_fits(compiled)
     if backend == "pallas":
         assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_cogvideox_step_sp4(topo):
+    """CogVideoX-5B's block at published widths (2 blocks) on the
+    ``cogvideox_t2v_sp4`` cell's video, 13 x 30 x 45 = 17,550 latent
+    tokens and 226 of text, with guidance at SP=4 ``swift_torus``: it
+    compiles, fits a chip, and exchanges over the torus."""
+    mesh = make_mesh((1, 4), ("data", "model"), devices=topo.devices)
+    sp = SPConfig(strategy="swift_torus", sp_axes=("model",),
+                  batch_axes=("data",))
+    compiled = _dit_step(mesh, sp, 17550, NamedSharding(mesh, P()),
+                         arch="cogvideox-5b", guidance=6.0)
+    _assert_fits(compiled)
+    assert "collective-permute" in compiled.as_text()

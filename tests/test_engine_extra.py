@@ -10,7 +10,7 @@ import pytest
 from repro.configs import get_reduced
 from repro.core import SPConfig
 from repro.models import ParallelContext, get_model
-from repro.models.dit import COND_TOKENS, dit_forward
+from repro.models.dit import dit_forward
 from repro.serving import DiTRequest, DiTServer, SamplerConfig
 from repro.serving.sampler import sample_step
 
@@ -41,7 +41,7 @@ def test_euler_step_direction(dit, mesh1):
     # but adaLN gates are zero-init too; assert the identity holds)
     ctx = ParallelContext(mesh1, SP, "prefill")
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 64))
-    cond = jnp.zeros((1, COND_TOKENS, cfg.d_model))
+    cond = jnp.zeros((1, cfg.text_tokens, cfg.cond_width))
     v = dit_forward(params, cfg, ctx, latents=x, cond=cond,
                     timesteps=jnp.ones((1,)))
     x2 = sample_step(params, cfg, ctx, x, cond, jnp.float32(1.0),
@@ -55,7 +55,7 @@ def test_guidance_scale_path(dit, mesh1):
     ctx = ParallelContext(mesh1, SP, "prefill")
     x = jax.random.normal(jax.random.PRNGKey(2), (1, 32, 64))
     cond = jax.random.normal(jax.random.PRNGKey(3),
-                             (1, COND_TOKENS, cfg.d_model)) * 0.02
+                             (1, cfg.text_tokens, cfg.cond_width)) * 0.02
     out = sample_step(params, cfg, ctx, x, cond, jnp.float32(1.0),
                       jnp.float32(0.25), SamplerConfig(num_steps=4,
                                                        guidance_scale=3.0))

@@ -4,17 +4,24 @@
 * ``run_once`` is one ``engine.run_once`` span holding ``engine.admit``,
   ``engine.prepare``, one ``engine.dispatch`` a sampler step,
   ``engine.sync`` and ``engine.finish``, in that order, tagged with the
-  batch's rows and latent length and with no request or admission id;
+  batch's rows and latent length and with no request or admission id,
+  ``engine.dispatch`` also with the guidance ``branches`` a request runs;
 * the default tracker's aggregates do not grow with the requests served;
 * a slow sink's emission stays out of the measured step clock;
 * each bucket build's ``plan_cache.trace`` span names its attention
-  lowering (``attn``);
+  lowering (``attn``) and block kind (``block``);
 * the step's HLO carries the block's scopes (``qkv``, ``attn``,
-  ``attn_out``, ``mlp``) in its op names, which the device trace's
-  ``tf_op`` paths come from.
+  ``attn_out``, ``mlp``; ``qk_prep``, ``modulate`` and, at SP > 1,
+  ``sp_comm`` for CogVideoX's block) in its op names, which the device
+  trace's ``tf_op`` paths come from.
 """
 import dataclasses
+import json
+import os
+import pathlib
 import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -24,12 +31,12 @@ from repro.configs import get_reduced
 from repro.core import PipelineConfig, SPConfig
 from repro.core.strategy import attention_lowering
 from repro.models import ParallelContext, get_model
-from repro.models.dit import COND_TOKENS
 from repro.serving import DiTRequest, DiTServer, SamplerConfig
 from repro.serving.metrics import RecordingTracker, Tracker
 from repro.serving.sampler import sample_step
 from tests.test_sampler import SlowTracker
 
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 SP = SPConfig(strategy="full", sp_axes=("model",), batch_axes=("data",))
 SCOPES = ("qkv", "attn", "attn_out", "mlp")
 STEPS = 3
@@ -82,7 +89,10 @@ def test_run_once_spans_nest_in_order(dit, mesh1, measured):
         assert r.tags["parent"] == "engine.run_once"
         assert outer.t_start <= r.t_start
         assert r.t_start + r.value <= end + 1e-9
-        if r.name != "engine.admit":
+        if r.name == "engine.dispatch":  # one forward a request: unguided
+            assert r.tags == {"rows": 2, "seq": 32, "branches": 1,
+                              "parent": "engine.run_once"}
+        elif r.name != "engine.admit":
             assert r.tags == {"rows": 2, "seq": 32,
                               "parent": "engine.run_once"}
     for a, b in zip(inner, inner[1:]):  # one after another, no overlap
@@ -132,9 +142,28 @@ def test_plan_cache_trace_tags_the_attention_lowering(dit, mesh1, pipeline):
     assert sorted(r.tags["seq"] for r in builds) == [32, 64]
     for r in builds:
         want = "displaced" if pipeline else attention_lowering(
-            SP, mesh1, COND_TOKENS + r.tags["seq"], cfg.resolved_head_dim)
+            SP, mesh1, cfg.text_tokens + r.tags["seq"], cfg.resolved_head_dim)
         assert r.tags["attn"] == want
         assert want in ("reference", "displaced")
+        assert r.tags["block"] == "uniform"
+
+
+def test_cogvideox_spans_tag_the_block_and_the_guidance_branches(mesh1):
+    """A guided ``cogvideox`` bucket: its build's ``plan_cache.trace``
+    names the block kind, and each ``engine.dispatch`` says that a step
+    runs two forwards (branches) of each request."""
+    cfg = dataclasses.replace(get_reduced("cogvideox-5b"), dtype="float32")
+    params, _ = get_model(cfg).init(cfg, jax.random.PRNGKey(0), 1)
+    sink = RecordingTracker()
+    srv = DiTServer(params, cfg, mesh1, SP,
+                    sampler=SamplerConfig(num_steps=2, guidance_scale=6.0),
+                    max_batch=1, tracker=sink)
+    srv.submit(DiTRequest(rid=3, seq_len=32))
+    assert len(srv.serve()) == 1
+    (build,) = [r for r in sink.records if r.name == "plan_cache.trace"]
+    assert build.tags["block"] == "cogvideox"
+    disp = [r for r in sink.records if r.name == "engine.dispatch"]
+    assert len(disp) == 2 and all(r.tags["branches"] == 2 for r in disp)
 
 
 def served_series(dit, mesh, requests: int) -> dict:
@@ -186,3 +215,50 @@ def test_block_scopes_name_the_step_ops(dit, mesh1):
     attn = {n.split("/attn/", 1)[1] for n in op_names if "/attn/" in n}
     assert {"reduce_max", "exp"} <= {a.split("/")[-1] for a in attn}
     assert any(n.startswith("bhlk,bkhd->blhd") for n in attn)  # p @ v
+
+
+def test_cogvideox_scopes_name_the_sp4_step_ops():
+    """At SP=4 (``swift_torus`` on four CPU devices, a child process) the
+    compiled guided step of the ``cogvideox`` block carries ``qk_prep``
+    (inside ``qkv``), ``modulate`` and ``sp_comm`` (inside ``attn``: the
+    torus permutes and the Push-O all-to-all) in its ops' names, which
+    the device trace's ``tf_op`` paths come from."""
+    code = (
+        "import sys; sys.path[:0] = ['src']\n"
+        "import dataclasses, json, re\n"
+        "import jax, jax.numpy as jnp\n"
+        "from repro.compat import make_mesh\n"
+        "from repro.configs import get_reduced\n"
+        "from repro.core import SPConfig\n"
+        "from repro.models import ParallelContext, get_model\n"
+        "from repro.serving import SamplerConfig\n"
+        "from repro.serving.sampler import sample_step\n"
+        "cfg = dataclasses.replace(get_reduced('cogvideox-5b'),"
+        " dtype='float32')\n"
+        "p = jax.eval_shape(lambda: get_model(cfg).init(cfg,"
+        " jax.random.PRNGKey(0), 1)[0])\n"
+        "ctx = ParallelContext(make_mesh((1, 4), ('data', 'model')),"
+        " SPConfig(strategy='swift_torus', sp_axes=('model',),"
+        " batch_axes=('data',)), 'prefill')\n"
+        "sc = SamplerConfig(num_steps=2, guidance_scale=6.0)\n"
+        "f = jax.jit(lambda p, x, c: sample_step(p, cfg, ctx, x, c,"
+        " jnp.float32(1.0), 0.5, sc))\n"
+        "txt = f.lower(p, jax.ShapeDtypeStruct((1, 48, 64), jnp.float32),"
+        " jax.ShapeDtypeStruct((1, cfg.text_tokens, cfg.cond_width),"
+        " jnp.float32)).compile().as_text()\n"
+        "ops = re.findall(r' (collective-permute|all-to-all)\\(.*?"
+        "op_name=\"([^\"]*)\"', txt)\n"
+        "print(json.dumps({'names': sorted(set(re.findall("
+        "r'op_name=\"([^\"]*)\"', txt))), 'comm': ops}))\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    res = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stderr[-3000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    names = out["names"]
+    assert any("/qkv/qk_prep/" in n for n in names)
+    assert any("/modulate/" in n for n in names)
+    assert any("/attn/" in n and "/sp_comm/" in n for n in names)
+    in_scope = {kind for kind, name in out["comm"] if "/sp_comm/" in name}
+    assert in_scope == {"collective-permute", "all-to-all"}

@@ -15,7 +15,7 @@ from repro.configs import ALL_ARCHS, get_config, get_reduced
 from repro.core import PipelineConfig, SPConfig, plan_hybrid
 from repro.core.pipefusion import patch_slices, stage_layers
 from repro.models import ParallelContext, get_model
-from repro.models.dit import COND_TOKENS, dit_forward, dit_forward_displaced
+from repro.models.dit import dit_forward, dit_forward_displaced
 from repro.serving import DiTRequest, DiTServer, SamplerConfig, sample
 
 SP = SPConfig(strategy="full", sp_axes=("model",), batch_axes=("data",))
@@ -39,7 +39,7 @@ def dit_setup():
 @pytest.fixture(scope="module")
 def cond(dit_setup):
     cfg, _ = dit_setup
-    return jax.random.normal(jax.random.PRNGKey(1), (1, COND_TOKENS, cfg.d_model),
+    return jax.random.normal(jax.random.PRNGKey(1), (1, cfg.text_tokens, cfg.cond_width),
                              jnp.float32)
 
 
@@ -158,9 +158,9 @@ def test_cfg_degree_3_weighted_recombine(dit_setup, mesh1):
     ctx = ParallelContext(mesh1, SP, "prefill")
     weights = (3.0, 1.5, -3.5)  # sums to 1: Σ g_i cond_i + (1-Σ g_i) uncond
     c1 = jax.random.normal(jax.random.PRNGKey(21),
-                           (1, COND_TOKENS, cfg.d_model), jnp.float32)
+                           (1, cfg.text_tokens, cfg.cond_width), jnp.float32)
     c2 = jax.random.normal(jax.random.PRNGKey(22),
-                           (1, COND_TOKENS, cfg.d_model), jnp.float32)
+                           (1, cfg.text_tokens, cfg.cond_width), jnp.float32)
     conds = jnp.stack([c1, c2, jnp.zeros_like(c1)], axis=0)  # [3, B, C, d]
     x = jax.random.normal(jax.random.PRNGKey(23), (1, SEQ, 64), jnp.float32)
     tt = jnp.full((1,), 0.7, jnp.float32)
