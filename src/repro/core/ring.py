@@ -16,10 +16,18 @@ Masking is exact under arbitrary chunk layouts: the caller supplies a
 to the global positions of its elements, so causal/sliding-window masks are
 identical to the single-device computation no matter where a chunk
 currently sits.
+
+Each attend has one of two lowerings, the same for every stage of a
+schedule: ``ORACLE``, ``attend_partial`` on the operands as given, merged
+into the running partial; or a ``FlashLowering``, the ``flash_mqkv``
+kernel on operands moved once into its layout, with the running
+(O', l, m) carried through the kernel as its state (Algorithm 2's fused
+merge).  ``core.strategy.attention_lowering`` chooses.
 """
 from __future__ import annotations
 
-from typing import Callable
+from functools import partial
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -28,12 +36,144 @@ from jax import lax
 from ..comm import Stream, fence, ring_shift
 from ..comm import profiler as _profiler
 from ..comm import trace as _trace
+from ..kernels.flash_mqkv import flash_mqkv
+from ..kernels.ops import _flatten_heads, _pad_to, _round_up, block_sizes
 from .collectives import GroupLayout
 from .softmax import (MaskSpec, Partial, attend_partial,
-                      attend_partial_blockwise, empty_partial, merge)
+                      attend_partial_blockwise, empty_partial, finalize,
+                      merge)
 
 # maps the ring coordinate (traced int32) owning the chunk -> [Lk] positions
 KPosFn = Callable[[jax.Array], jax.Array]
+
+
+class OracleLowering:
+    """Attends by ``attend_partial``: operands as given ([B, L, H, D]),
+    the running state a ``Partial`` as ``core.softmax`` lays it out."""
+
+    seq_axes = Partial(o=1, l=2, m=2)  # each state field's row axis
+
+    @staticmethod
+    def q(x):
+        return x
+
+    kv = q_pos = k_pos = q
+
+    @staticmethod
+    def empty(q_shape) -> Partial:
+        return empty_partial(*q_shape)
+
+    @staticmethod
+    def finalize(acc: Partial, dtype) -> jax.Array:
+        return finalize(acc, dtype=dtype)
+
+
+ORACLE = OracleLowering()
+
+
+class FlashLowering(NamedTuple):
+    """Attends by the ``flash_mqkv`` kernel, for q chunks of ``q_len``
+    rows and KV chunks of ``k_len``: heads folded into the batch
+    ([B·H, L, D]), the state (O', l, m) as [B·H, L, D] and [B·H, L, 1]
+    (``flash_mqkv``'s ``keepdims``), q rows padded to whole ``block_q``
+    blocks (computed and dropped), K and V to whole ``block_k`` blocks
+    (positions -1, masked).  The blocks come from the chunks' shape
+    (``kernels.ops.block_sizes``)."""
+
+    batch: int
+    q_len: int
+    k_len: int
+    block_q: int
+    block_k: int
+
+    seq_axes = Partial(o=1, l=1, m=1)
+
+    @classmethod
+    def of(cls, batch, q_len, k_len, head_dim, dtype) -> "FlashLowering":
+        bq, bk = block_sizes(q_len, k_len, head_dim,
+                             jnp.dtype(dtype).itemsize)
+        return cls(batch, q_len, k_len, bq, bk)
+
+    def q(self, x):  # [B, Lq, H, D] -> [B·H, Lq padded, D]
+        return _pad_to(_flatten_heads(x), 1, self.block_q)
+
+    def kv(self, x):
+        return _pad_to(_flatten_heads(x), 1, self.block_k)
+
+    def q_pos(self, p):
+        return _pad_to(p.astype(jnp.int32), 0, self.block_q)
+
+    def k_pos(self, p):
+        return _pad_to(p.astype(jnp.int32), 0, self.block_k, value=-1)
+
+    def empty(self, q_shape) -> Partial:
+        bh, lq, d = q_shape
+        return Partial(o=jnp.zeros((bh, lq, d), jnp.float32),
+                       l=jnp.zeros((bh, lq, 1), jnp.float32),
+                       m=jnp.full((bh, lq, 1), -jnp.inf, jnp.float32))
+
+    def finalize(self, acc: Partial, dtype) -> jax.Array:
+        """O = O' / l over whole chunks of q rows, back to [B, L, H, D]
+        with each chunk's padding dropped: the one transpose out."""
+        bh, lq, d = acc.o.shape
+        h, rows = bh // self.batch, _round_up(self.q_len, self.block_q)
+        o = (acc.o / jnp.where(acc.l == 0.0, 1.0, acc.l)).astype(dtype)
+        o = o.reshape(self.batch, h, lq // rows, rows, d)[:, :, :, :self.q_len]
+        return o.transpose(0, 2, 3, 1, 4).reshape(self.batch, -1, h, d)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11, 12))
+def _flash_attend(q, k, v, q_pos, k_pos, state, scale, causal, window,
+                  block_q, block_k, masked, interpret) -> Partial:
+    """One attend through the flash kernel in ``FlashLowering``'s layout,
+    the running (O', l, m) carried in (``state``; None when fresh) and
+    out.  The gradient is the oracle's (``_oracle_attend``, as
+    ``strategy._one_chip_flash`` at SP=1): a Pallas call has no transpose
+    rule."""
+    return Partial(*flash_mqkv(
+        q, k, v, q_pos, k_pos, group=q.shape[0] // k.shape[0], scale=scale,
+        causal=causal, window=window, state=state, finalize=False,
+        block_q=block_q, block_k=block_k, masked=masked, keepdims=True,
+        interpret=interpret))
+
+
+def _oracle_attend(q, k, v, q_pos, k_pos, state, scale, causal, window
+                   ) -> Partial:
+    """``_flash_attend``'s values by ``attend_partial``, merged into the
+    carried state: the kernel's [B·H, L, D] layout viewed as a batch of
+    B·Hkv with the ``group`` q heads of each KV head (GQA), pad keys
+    (position -1) masked out."""
+    bhkv, lq = k.shape[0], q.shape[1]
+    group = q.shape[0] // bhkv
+    heads = lambda x: x.reshape(bhkv, group, lq, -1).transpose(0, 2, 1, 3)
+    rows = lambda x: x[..., 0].reshape(bhkv, group, lq)
+    mask = MaskSpec(causal=causal, window=window, q_pos=q_pos, k_pos=k_pos,
+                    valid_k=k_pos >= 0)
+    part = attend_partial(heads(q), k[:, :, None], v[:, :, None],
+                          scale=scale, mask=mask)
+    if state is not None:
+        part = merge(Partial(heads(state.o), rows(state.l), rows(state.m)),
+                     part)
+    flat = lambda x: x.reshape(bhkv * group, lq, 1)
+    return Partial(part.o.transpose(0, 2, 1, 3).reshape(q.shape[0], lq, -1),
+                   flat(part.l), flat(part.m))
+
+
+def _flash_attend_fwd(q, k, v, q_pos, k_pos, state, *static):
+    return (_flash_attend(q, k, v, q_pos, k_pos, state, *static),
+            (q, k, v, q_pos, k_pos, state))
+
+
+def _flash_attend_bwd(scale, causal, window, block_q, block_k, masked,
+                      interpret, res, g):
+    q, k, v, q_pos, k_pos, state = res
+    _, vjp = jax.vjp(lambda q, k, v, st: _oracle_attend(
+        q, k, v, q_pos, k_pos, st, scale, causal, window), q, k, v, state)
+    dq, dk, dv, dstate = vjp(g)
+    return dq, dk, dv, None, None, dstate
+
+
+_flash_attend.defvjp(_flash_attend_fwd, _flash_attend_bwd)
 
 
 def ring_attention(
@@ -52,10 +192,14 @@ def ring_attention(
     kv_block: int | None = None,
     backend: str = "xla",
     interpret: bool | None = None,
+    lowering: OracleLowering | FlashLowering = ORACLE,
 ) -> Partial:
     """Run P_r ring steps; returns the merged partial (not finalized).
 
-    ``kv_block`` caps the materialized score matrix per attend (see
+    ``lowering`` says how each step attends (the module docstring): q, k,
+    v, ``q_pos`` and ``accum`` come in its layout, and the result goes out
+    in it; ``k_pos_fn`` gives a chunk's positions unpadded.  ``kv_block``
+    caps the materialized score matrix per oracle attend (see
     softmax.attend_partial_blockwise).
 
     ``backend="pallas"`` runs the fused path (DESIGN.md §8.1): each ring
@@ -69,32 +213,40 @@ def ring_attention(
         return _ring_attention_pallas(
             q, k, v, layout, q_pos=q_pos, k_pos_fn=k_pos_fn, scale=scale,
             causal=causal, window=window, accum=accum, interpret=interpret)
-    def _attend(q_, k_, v_, mask):
-        if kv_block is not None:
-            return attend_partial_blockwise(q_, k_, v_, scale=scale,
-                                            mask=mask, kv_block=kv_block)
-        return attend_partial(q_, k_, v_, scale=scale, mask=mask)
     p_r = layout.p_ring
-    b, lq, hq, d = q.shape
-    acc = accum if accum is not None else empty_partial(b, lq, hq, d)
+    acc = accum
     masked = causal or window is not None
 
-    def mask_for(owner_r):
-        if not masked:
-            return None
-        return MaskSpec(
-            causal=causal,
-            window=window,
-            q_pos=q_pos,
-            k_pos=k_pos_fn(owner_r) if k_pos_fn is not None else None,
-        )
+    if isinstance(lowering, FlashLowering):
+        masked = masked or lowering.k_len % lowering.block_k != 0
+        qp = (q_pos if q_pos is not None
+              else jnp.zeros(q.shape[1], jnp.int32))
+
+        def attend(acc, kc, vc, owner_r):
+            kp = (k_pos_fn(owner_r) if k_pos_fn is not None
+                  else jnp.arange(lowering.k_len))
+            return _flash_attend(
+                q, kc, vc, qp, lowering.k_pos(kp), acc, scale, causal,
+                window, lowering.block_q, lowering.block_k, masked,
+                interpret)
+    else:
+        def attend(acc, kc, vc, owner_r):
+            mask = None if not masked else MaskSpec(
+                causal=causal, window=window, q_pos=q_pos,
+                k_pos=k_pos_fn(owner_r) if k_pos_fn is not None else None)
+            if kv_block is not None:
+                part = attend_partial_blockwise(q, kc, vc, scale=scale,
+                                                mask=mask, kv_block=kv_block)
+            else:
+                part = attend_partial(q, kc, vc, scale=scale, mask=mask)
+            return part if acc is None else merge(acc, part)
 
     _, my_r = layout.my_coords()
     if p_r == 1:
         # pure-Ulysses plan: no ring rotation, but this local attend is
         # still the compute the torus hops are scheduled to hide — mark it
         # so per-stage traces stay complete for overlap accounting
-        out = merge(acc, _attend(q, k, v, mask_for(my_r)))
+        out = attend(acc, k, v, my_r)
         _profiler.mark_compute("local attend", layout.axes, (k, v),
                                tuple(out), stream="ring")
         return out
@@ -108,7 +260,7 @@ def ring_attention(
             nxt = ring_shift(layout, kc, vc, stream=stream,
                              overlaps="ring attend")
         owner = (my_r - s) % p_r  # ring rank whose shard I currently hold
-        acc = merge(acc, _attend(q, kc, vc, mask_for(owner)))
+        acc = attend(acc, kc, vc, owner)
         _profiler.mark_compute("ring attend", layout.axes, (kc, vc),
                                tuple(acc), stream=stream.name)
         return (*nxt.payload, acc)
@@ -126,19 +278,22 @@ def ring_attention(
             with jax.named_scope("sp_comm"):
                 nxt = ring_shift(layout, kc, vc, stream=stream,
                                  overlaps="ring attend")
-            (kc_g, vc_g), accs = fence((kc, vc), tuple(acc))
-            acc = Partial(*accs)
+            if acc is not None:  # a fresh first step has nothing to wait on
+                (kc, vc), accs = fence((kc, vc), tuple(acc))
+                acc = Partial(*accs)
             owner = (my_r - s) % p_r
-            acc = merge(acc, _attend(q, kc_g, vc_g, mask_for(owner)))
+            acc = attend(acc, kc, vc, owner)
             _profiler.mark_compute("ring attend", layout.axes,
-                                   (kc_g, vc_g), tuple(acc),
+                                   (kc, vc), tuple(acc),
                                    stream=stream.name)
             kc, vc = nxt.payload
     else:
+        if acc is None:  # the loop carries a state from its first step
+            acc = lowering.empty(q.shape)
         kc, vc, acc = lax.fori_loop(0, p_r - 1, body, (k, v, acc))
     # last step: compute only, no further transfer (2(P-1)/P volume, §2.2)
     owner = (my_r - (p_r - 1)) % p_r
-    out = merge(acc, _attend(q, kc, vc, mask_for(owner)))
+    out = attend(acc, kc, vc, owner)
     _profiler.mark_compute("ring attend", layout.axes, (kc, vc),
                            tuple(out), stream=stream.name)
     return out

@@ -29,8 +29,8 @@ from ..compat import pallas_interpret
 from ..kernels.ops import flash_attention
 from . import planner
 from .collectives import GroupLayout
-from .ring import ring_attention
-from .softmax import finalize, reference_attention, MaskSpec
+from .ring import ORACLE, FlashLowering, ring_attention
+from .softmax import reference_attention, MaskSpec
 from .torus import torus_attention
 from .ulysses import gather_qkv, group_positions, scatter_o
 
@@ -62,8 +62,9 @@ class SPConfig:
     # Beyond-paper (§Perf): fuse all Pull-Q stage compute into one ring
     # circulation of the diagonal KV (Algorithm 1 re-circulates it P_u x).
     torus_fused_pull_q: bool = False
-    # Beyond-paper (§Perf): cap the materialized score matrix per attend at
-    # [B, H, Lq, attn_kv_block] (XLA-level flash blocking); None = off.
+    # Beyond-paper (§Perf): cap the materialized score matrix per oracle
+    # attend at [B, H, Lq, attn_kv_block] (XLA-level flash blocking; the
+    # flash kernel's stages block by themselves); None = off.
     attn_kv_block: int | None = None
     # Comm lowering (DESIGN.md §8.1): "xla" = ppermute + barrier, overlap
     # left to XLA's scheduler; "pallas" = in-kernel DMA + semaphores (the
@@ -142,25 +143,41 @@ def resolve_layout(
                        u_groups=u_groups(pl.p_ulysses, swift))
 
 
+def flash_attends(head_dim: int, window=None) -> bool:
+    """Whether attention can run the Pallas flash kernel: compiled on a
+    TPU (the platform test of ``compat.pallas_interpret``), for a
+    head_dim of 64 (a [block, 64] tile spans the last dimension of the
+    kernel's [B·H, L, D] operands) or of whole 128-lane tiles, and a
+    static window (the kernel's mask is static)."""
+    return (not pallas_interpret()
+            and (head_dim == 64 or head_dim % 128 == 0)
+            and (window is None or isinstance(window, int)))
+
+
 def attention_lowering(cfg: SPConfig, mesh: jax.sharding.Mesh, q_len: int,
                        head_dim: int, window=None) -> str:
     """What ``sp_attention`` runs for self-attention of this shape.
 
     Below SP=2: ``"flash"``, the Pallas flash kernel
-    (``kernels.ops.flash_attention``), on a one-device mesh of a TPU (the
-    platform test of ``compat.pallas_interpret``) for a real query length,
-    a head_dim of whole 128-lane tiles and a static window (the kernel's
-    mask is static); ``"reference"``, the materialised oracle, everywhere
-    else: the CPU, decode's one-token queries, a traced window, several
-    devices (GSPMD partitions the oracle, and does not partition a Pallas
-    call).  At SP > 1 the SP strategy's name."""
+    (``kernels.ops.flash_attention``), on a one-device mesh for a real
+    query length where ``flash_attends``; ``"reference"``, the
+    materialised oracle, everywhere else: the CPU, decode's one-token
+    queries, a head_dim or a traced window the kernel does not take,
+    several devices (GSPMD partitions the oracle, and does not partition
+    a Pallas call).  At SP > 1 the SP strategy's name and, after a
+    ``+``, the attend inside each of its stages: ``flash`` where
+    ``flash_attends`` (the kernel, the running (O', l, m) carried through
+    it), ``reference`` elsewhere (``attend_partial``'s materialised score
+    block), ``ring_flash`` under the Pallas comm backend (its fused
+    ring-step kernel)."""
+    flash = flash_attends(head_dim, window)
     if cfg.strategy != "full" and math.prod(mesh.shape[a]
                                             for a in cfg.sp_axes) > 1:
-        return cfg.strategy
-    flash = (math.prod(mesh.shape.values()) == 1 and not pallas_interpret()
-             and q_len > 1 and head_dim % 128 == 0
-             and (window is None or isinstance(window, int)))
-    return "flash" if flash else "reference"
+        stage = ("ring_flash" if cfg.comm_backend == "pallas"
+                 else "flash" if flash else "reference")
+        return f"{cfg.strategy}+{stage}"
+    one_chip = math.prod(mesh.shape.values()) == 1 and q_len > 1
+    return "flash" if one_chip and flash else "reference"
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -187,21 +204,27 @@ _one_chip_flash.defvjp(_one_chip_flash_fwd, _one_chip_flash_bwd)
 
 
 def _usp_like(q, k, v, layout: GroupLayout, *, scale, causal, window, unroll,
-              kv_block=None, backend="xla", interpret=None, wire_dtype=None):
+              kv_block=None, backend="xla", interpret=None, wire_dtype=None,
+              flash=False):
     """Shared body for usp/swift/ulysses/ring: monolithic Ulysses gather →
     Ring Attention → scatter.  The layout decides which boundary each
-    technique crosses (that single bit is the paper's §4.2 contribution)."""
+    technique crosses (that single bit is the paper's §4.2 contribution).
+    ``flash`` runs the ring's attends through the flash kernel."""
     ls = q.shape[1]
     g = gather_qkv(q, k, v, layout, backend=backend, interpret=interpret,
                    wire_dtype=wire_dtype)
+    b, lg, _, d = g.q.shape
+    low = (FlashLowering.of(b, lg, g.k.shape[1], d, q.dtype) if flash
+           else ORACLE)
     kpos_fn = lambda owner_r: group_positions(layout, ls, owner_r)
     part = ring_attention(
-        g.q, g.k, g.v, layout,
-        q_pos=g.q_pos, k_pos_fn=kpos_fn,
+        low.q(g.q), low.kv(g.k), low.kv(g.v), layout,
+        q_pos=low.q_pos(g.q_pos), k_pos_fn=kpos_fn,
         scale=scale, causal=causal, window=window, unroll=unroll,
         kv_block=kv_block, backend=backend, interpret=interpret,
+        lowering=low,
     )
-    return scatter_o(finalize(part, dtype=q.dtype), layout,
+    return scatter_o(low.finalize(part, q.dtype), layout,
                      backend=backend, interpret=interpret,
                      wire_dtype=wire_dtype)
 
@@ -239,21 +262,16 @@ def sp_attention(
     ba = cfg.effective_batch_axes(mesh)
     spec = P(ba, cfg.sp_axes, None, None)
 
+    common = dict(
+        layout=layout, scale=scale, causal=causal, window=window,
+        unroll=cfg.unroll_ring, kv_block=cfg.attn_kv_block,
+        backend=cfg.comm_backend, interpret=cfg.kernel_interpret,
+        wire_dtype=cfg.a2a_wire_dtype, flash=lowering.endswith("+flash"))
     if cfg.strategy == "swift_torus":
-        body = partial(
-            torus_attention, layout=layout, scale=scale, causal=causal,
-            window=window, unroll=cfg.unroll_ring,
-            fused_pull_q=cfg.torus_fused_pull_q, kv_block=cfg.attn_kv_block,
-            backend=cfg.comm_backend, interpret=cfg.kernel_interpret,
-            wire_dtype=cfg.a2a_wire_dtype,
-        )
+        body = partial(torus_attention,
+                       fused_pull_q=cfg.torus_fused_pull_q, **common)
     else:
-        body = partial(
-            _usp_like, layout=layout, scale=scale, causal=causal,
-            window=window, unroll=cfg.unroll_ring, kv_block=cfg.attn_kv_block,
-            backend=cfg.comm_backend, interpret=cfg.kernel_interpret,
-            wire_dtype=cfg.a2a_wire_dtype,
-        )
+        body = partial(_usp_like, **common)
 
     fn = jax.shard_map(
         lambda q, k, v: body(q, k, v),

@@ -27,14 +27,16 @@ same bytes move, on the same hops, in the same stage order.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..comm import Stream, fence, pin, torus_hop
 from .collectives import GroupLayout
-from .ring import ring_attention
-from .softmax import Partial, empty_partial, finalize, merge
+from .ring import ORACLE, FlashLowering, ring_attention
+from .softmax import Partial
 
 
 def _pin(acc: Partial) -> Partial:
@@ -49,7 +51,7 @@ def _gate(tensors: tuple, acc: Partial):
     hoisted/overlapped by the scheduler)."""
     vals, accs = fence(tensors, tuple(acc))
     return vals, Partial(*accs)
-from .ulysses import group_positions, scatter_o
+from .ulysses import scatter_o
 
 HEAD_AXIS = 2
 
@@ -65,15 +67,13 @@ def _rank_of(layout: GroupLayout, u, r):
     return r * layout.p_ulysses + u
 
 
-def _merge_slice(acc: Partial, upd: Partial, start: jax.Array, ls: int) -> Partial:
-    """Merge ``upd`` (covering q slice [start, start+ls)) into ``acc``."""
-    sl = lambda a, ax: lax.dynamic_slice_in_dim(a, start, ls, axis=ax)
-    cur = Partial(o=sl(acc.o, 1), l=sl(acc.l, 2), m=sl(acc.m, 2))
-    new = merge(cur, upd)
-    ins = lambda a, u, ax: lax.dynamic_update_slice_in_dim(a, u, start, axis=ax)
-    return Partial(
-        o=ins(acc.o, new.o, 1), l=ins(acc.l, new.l, 2), m=ins(acc.m, new.m, 2)
-    )
+def _put_slice(acc: Partial, upd: Partial, start: jax.Array,
+               axes: Partial) -> Partial:
+    """Write ``upd`` into ``acc`` from q row ``start`` (``axes``: each
+    field's row axis).  Those rows of ``acc`` are still empty, so this is
+    their merge."""
+    return Partial(*(lax.dynamic_update_slice_in_dim(a, u, start, axis=ax)
+                     for a, u, ax in zip(acc, upd, axes)))
 
 
 def torus_attention(
@@ -91,9 +91,16 @@ def torus_attention(
     backend: str = "xla",
     interpret: bool | None = None,
     wire_dtype: str | None = None,
+    flash: bool = False,
 ) -> jax.Array:
     """Full SwiftFusion attention with the Torus schedule; returns O in the
     original [B, Ls, Hq, D] sharding.
+
+    ``flash`` attends every stage through the flash kernel
+    (``ring.FlashLowering``): each chunk is moved into the kernel's layout
+    once, as it arrives, the gathered Q is assembled there, and the
+    gathered-Q accumulator is the kernel's carried state through the
+    Pull-KV stages; O leaves that layout once, at the end.
 
     ``wire_dtype`` compresses the inter-machine leg of the Push-O when the
     layout is hierarchical (``layout.u_groups > 1``, DESIGN.md §8.2); the
@@ -114,31 +121,34 @@ def torus_attention(
     permuted Q tensors are 2× smaller than KV and arrive early, so the
     exposed latency is small)."""
     p_u, p_r = layout.p_ulysses, layout.p_ring
-    b, ls, hq, d = q.shape
-    h = hq // p_u
+    b, ls, _, d = q.shape
     u, r = layout.my_coords()
 
     qc = _split_heads(q, p_u)  # [P_u, B, Ls, h, D]
     kc = _split_heads(k, p_u)
     vc = _split_heads(v, p_u)
-    k_diag, v_diag = jnp.take(kc, u, axis=0), jnp.take(vc, u, axis=0)
+    low = (FlashLowering.of(b, ls, ls, d, q.dtype) if flash else ORACLE)
+    ring = partial(ring_attention, layout=layout, scale=scale,
+                   causal=causal, window=window, unroll=unroll,
+                   kv_block=kv_block, backend=backend, interpret=interpret,
+                   lowering=low)
+    q_diag = low.q(jnp.take(qc, u, axis=0))
+    k_diag = low.kv(jnp.take(kc, u, axis=0))
+    v_diag = low.kv(jnp.take(vc, u, axis=0))
+    rows = q_diag.shape[1]  # one Q chunk's rows in the attend's layout
 
-    my_pos = lambda: _rank_of(layout, u, r) * ls + jnp.arange(ls)
-    chunk_pos = lambda src_u: _rank_of(layout, src_u, r) * ls + jnp.arange(ls)
+    chunk_pos = lambda src_u: low.q_pos(
+        _rank_of(layout, src_u, r) * ls + jnp.arange(ls))
     # position of the diagonal KV chunk as it circulates the Ring group
     diag_kpos_fn = lambda owner_r: _rank_of(layout, u, owner_r) * ls + jnp.arange(ls)
 
-    acc = empty_partial(b, p_u * ls, h, d)  # gathered-q accumulator, source-u order
-
+    acc = None  # gathered-q accumulator, source-u order
     if not fused_pull_q:
+        acc = low.empty((q_diag.shape[0], p_u * rows, *q_diag.shape[2:]))
         # ---- stage 0: stationary diagonal chunks, compute starts, no comm
-        part = ring_attention(
-            jnp.take(qc, u, axis=0), k_diag, v_diag, layout,
-            q_pos=my_pos(), k_pos_fn=diag_kpos_fn,
-            scale=scale, causal=causal, window=window, unroll=unroll,
-            kv_block=kv_block, backend=backend, interpret=interpret,
-        )
-        acc = _merge_slice(acc, part, u * ls, ls)
+        part = ring(q_diag, k_diag, v_diag, q_pos=chunk_pos(u),
+                    k_pos_fn=diag_kpos_fn)
+        acc = _put_slice(acc, part, u * rows, low.seq_axes)
 
     stream = Stream("torus", backend=backend, interpret=interpret)
 
@@ -150,35 +160,27 @@ def torus_attention(
             recv = torus_hop(layout, kstage, send, stream=stream,
                              overlaps="diag-KV attend").wait()
         src = (u - kstage) % p_u
+        recv = low.q(recv)
         if not fused_pull_q:
-            part = ring_attention(
-                recv, k_diag, v_diag, layout,
-                q_pos=chunk_pos(src), k_pos_fn=diag_kpos_fn,
-                scale=scale, causal=causal, window=window, unroll=unroll,
-                kv_block=kv_block, backend=backend, interpret=interpret,
-            )
-            acc = _pin(_merge_slice(acc, part, src * ls, ls))
+            part = ring(recv, k_diag, v_diag, q_pos=chunk_pos(src),
+                        k_pos_fn=diag_kpos_fn)
+            acc = _pin(_put_slice(acc, part, src * rows, low.seq_axes))
         q_recv[kstage] = (src, recv)
 
     # assemble the gathered Q (source-u order) for the Pull-KV stages
-    q_gather = jnp.zeros((p_u, b, ls, h, d), q.dtype)
-    q_gather = lax.dynamic_update_slice_in_dim(
-        q_gather, jnp.take(qc, u, axis=0)[None], u, axis=0
-    )
+    q_gather = jnp.zeros((p_u, *q_diag.shape), q_diag.dtype)
+    q_gather = lax.dynamic_update_slice_in_dim(q_gather, q_diag[None], u,
+                                               axis=0)
     for src, recv in filter(None, q_recv):
         q_gather = lax.dynamic_update_slice_in_dim(q_gather, recv[None], src, axis=0)
-    q_gather = jnp.moveaxis(q_gather, 0, 1).reshape(b, p_u * ls, h, d)
-    q_pos_all = group_positions(layout, ls, r)
+    q_gather = jnp.moveaxis(q_gather, 0, 1).reshape(
+        q_diag.shape[0], p_u * rows, *q_diag.shape[2:])
+    q_pos_all = jnp.concatenate([chunk_pos(j) for j in range(p_u)])
 
     if fused_pull_q:
         # single ring circulation of the diagonal KV over ALL gathered Q
-        part = ring_attention(
-            q_gather, k_diag, v_diag, layout,
-            q_pos=q_pos_all, k_pos_fn=diag_kpos_fn,
-            scale=scale, causal=causal, window=window, unroll=unroll,
-            kv_block=kv_block, backend=backend, interpret=interpret,
-        )
-        acc = merge(acc, part)
+        acc = ring(q_gather, k_diag, v_diag, q_pos=q_pos_all,
+                   k_pos_fn=diag_kpos_fn)
 
     # ---- Pull-KV stages: KV chunks arrive; all Q attends each new chunk
     for kstage in range(1, p_u):
@@ -191,15 +193,10 @@ def torus_attention(
                 stream=stream, overlaps="gathered-Q attend").wait()
         (k_recv, v_recv), acc = _gate((k_recv, v_recv), acc)
         kpos_fn = lambda owner_r, s=src: _rank_of(layout, s, owner_r) * ls + jnp.arange(ls)
-        part = ring_attention(
-            q_gather, k_recv, v_recv, layout,
-            q_pos=q_pos_all, k_pos_fn=kpos_fn,
-            scale=scale, causal=causal, window=window, unroll=unroll,
-            kv_block=kv_block, backend=backend, interpret=interpret,
-        )
-        acc = merge(acc, part)
+        acc = ring(q_gather, low.kv(k_recv), low.kv(v_recv),
+                   q_pos=q_pos_all, k_pos_fn=kpos_fn, accum=acc)
 
     # ---- Push-O: staged inverse all-to-all; diagonal O never moves
-    o = finalize(acc, dtype=q.dtype)  # [B, P_u * Ls, h, D]
+    o = low.finalize(acc, q.dtype)  # [B, P_u * Ls, h, D]
     return scatter_o(o, layout, backend=backend, interpret=interpret,
                      wire_dtype=wire_dtype)

@@ -83,13 +83,13 @@ def _kernel(
     m_prev = m_s[...]  # [bq, 1]
     l_prev = l_s[...]
     if masked:
-        qp = qp_ref[...].astype(jnp.int32)[0]  # [bq]
-        kp = kp_ref[...].astype(jnp.int32)[0]  # [bk]
-        ok = (kp >= 0)[None, :]
+        qp = qp_ref[...].astype(jnp.int32)  # [bq, 1]
+        kp = kp_ref[...].astype(jnp.int32)  # [1, bk]
+        ok = kp >= 0
         if causal:
-            ok = ok & (qp[:, None] >= kp[None, :])
+            ok = ok & (qp >= kp)
         if window is not None:
-            ok = ok & (kp[None, :] > qp[:, None] - window)
+            ok = ok & (kp > qp - window)
         s = jnp.where(ok, s, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         safe_m = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
@@ -154,6 +154,7 @@ def flash_mqkv(
     block_k: int = DEFAULT_BLOCK_K,
     masked: bool = True,
     heads: int | None = None,
+    keepdims: bool = False,
     interpret: bool | None = None,
 ):
     """Core pallas_call.  Lq % block_q == 0 and Lk % block_k == 0 required
@@ -166,7 +167,12 @@ def flash_mqkv(
     [B, Lq, heads·D], k and v [B, Lk, heads/group·D], D a multiple of 128,
     and each head's [block, D] tile is read and written in place, so the
     projections' [B, L, H·D] layout needs no transpose either way (fresh,
-    finalized calls only)."""
+    finalized calls only).
+
+    ``keepdims`` takes and returns the (l, m) state as [BH, Lq, 1], the
+    kernel's own layout: on a TPU a [BH, Lq] array is laid out apart from
+    it, so each conversion would be a copy of the 128-lane padded column
+    (a caller that chains calls keeps the state in this form)."""
     if heads is None:
         bh, lq, d = q.shape
         bhkv, lk, _ = k.shape
@@ -202,12 +208,17 @@ def flash_mqkv(
     lm_spec = pl.BlockSpec((None, block_q, 1), lambda h, qi, ki: (h, qi, 0))
     args, in_specs = [q, k, v], [q_spec, kv_spec, kv_spec]
     if masked:
-        args += [q_pos.reshape(1, lq), k_pos.reshape(1, lk)]
-        in_specs += [pl.BlockSpec((1, block_q), lambda h, qi, ki: (0, qi)),
+        # q positions as a column: a (block_q, 1) block tiles for any
+        # block_q of whole sublanes, where a (1, block_q) row needs whole
+        # 128-lane tiles
+        args += [q_pos.reshape(lq, 1), k_pos.reshape(1, lk)]
+        in_specs += [pl.BlockSpec((block_q, 1), lambda h, qi, ki: (qi, 0)),
                      pl.BlockSpec((1, block_k), lambda h, qi, ki: (0, ki))]
     if has_state:
         o_in, l_in, m_in = state
-        args += [o_in, l_in[..., None], m_in[..., None]]
+        if not keepdims:
+            l_in, m_in = l_in[..., None], m_in[..., None]
+        args += [o_in, l_in, m_in]
         in_specs += [q_spec, lm_spec, lm_spec]
 
     def kernel(*refs):
@@ -253,4 +264,4 @@ def flash_mqkv(
     if finalize:
         return outs[0], None, None
     o, l, m = outs
-    return o, l[..., 0], m[..., 0]
+    return (o, l, m) if keepdims else (o, l[..., 0], m[..., 0])

@@ -104,7 +104,7 @@ def ring_flash_step(
     n_q, n_k = lq // block_q, lk // block_k
     has_state = state is not None
 
-    qp2 = q_pos.reshape(1, lq)
+    qp2 = q_pos.reshape(lq, 1)  # a column, as flash_mqkv takes it
     kp2 = k_pos.reshape(1, lk)
     if state is None:
         o_in = jnp.zeros((bh, block_q, d), jnp.float32)
@@ -139,7 +139,7 @@ def ring_flash_step(
                          lambda h, qi, ki, g=group: (h // g, ki, 0)),
             pl.BlockSpec((None, block_k, d),
                          lambda h, qi, ki, g=group: (h // g, ki, 0)),
-            pl.BlockSpec((1, block_q), lambda h, qi, ki: (0, qi)),
+            pl.BlockSpec((block_q, 1), lambda h, qi, ki: (qi, 0)),
             pl.BlockSpec((1, block_k), lambda h, qi, ki: (0, ki)),
             oin_spec,
             lin_spec,

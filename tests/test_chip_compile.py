@@ -25,6 +25,7 @@ from repro.compat import make_mesh
 from repro.configs import get_config, get_reduced
 from repro.configs.shapes import InputShape
 from repro.core import SPConfig
+from repro.core.ring import FlashLowering
 from repro.kernels.flash_mqkv import flash_mqkv
 from repro.kernels.ring_flash import ring_flash_step
 from repro.models import ParallelContext, get_model
@@ -179,32 +180,92 @@ def test_train_step_one_chip(topo, one_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_train_step_sp4(topo, monkeypatch):
+    """A train step at SP=4 ``swift_torus`` where JAX reports a TPU: the
+    forward's torus stages attend through the flash kernel (causal, GQA,
+    two Ulysses pairs ringing the KV), and the gradient, the oracle's,
+    compiles beside it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_reduced("qwen2-1.5b"), head_dim=128,
+                              dtype="bfloat16", sharding_overrides=())
+    mesh = make_mesh((1, 4), ("data", "model"), devices=topo.devices)
+    sp = SPConfig(strategy="swift_torus", sp_axes=("model",),
+                  batch_axes=("data",))
+    bundle = get_model(cfg)
+    sds = lambda a: _sds(a.shape, a.dtype, NamedSharding(mesh, P()))
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: bundle.init(cfg, jax.random.PRNGKey(0), 1)[0]))
+    opt = jax.tree.map(sds, jax.eval_shape(init_adamw, params))
+    batch = jax.tree.map(sds, bundle.input_specs(
+        cfg, InputShape("train", 256, 2, "training"), abstract=True))
+    step = make_train_step(cfg, mesh, sp, AdamWConfig())
+    text = jax.jit(step).lower(params, opt, batch).compile().as_text()
+    assert "tpu_custom_call" in text and "collective-permute" in text
+
+
 @pytest.mark.parametrize("strategy,backend", [("swift_torus", "xla"),
                                               ("ring", "pallas")])
 def test_flux_step_sp4(topo, monkeypatch, strategy, backend):
     """SP=4 at 16384 tokens (a 2048x2048 image).  The Pallas ring takes
-    its TPU branches (compiled ring_flash, in-kernel remote put) only
-    where JAX reports a TPU backend, so the test reports one."""
-    if backend == "pallas":
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    its TPU branches (compiled ring_flash, in-kernel remote put), and the
+    XLA torus its flash stage attends, only where JAX reports a TPU
+    backend, so the test reports one."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = make_mesh((1, 4), ("data", "model"), devices=topo.devices)
     sp = SPConfig(strategy=strategy, sp_axes=("model",),
                   batch_axes=("data",), comm_backend=backend)
     compiled = _dit_step(mesh, sp, 16384, NamedSharding(mesh, P()))
     _assert_fits(compiled)
-    if backend == "pallas":
-        assert "tpu_custom_call" in compiled.as_text()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_cogvideox_step_sp4(topo):
+@pytest.mark.parametrize("masked", [False, True], ids=["open", "causal"])
+def test_stage_kernel_compiles(one_chip, masked):
+    """The SP stage attend at the video cell's Pull-KV shape: 12 heads x
+    64 a chip, the gathered 17,776 rows (four chunks padded to 4,480)
+    against a 4,444-row chunk, blocks from the chunk's shape, the running
+    state carried in and out.  Causal too: the kernel's q positions tile
+    at a block_q that is not a whole 128 lanes."""
+    low = FlashLowering.of(1, 4444, 4444, 64, jnp.bfloat16)
+    bh, lq, lk = 12, 4 * 4480, 4444
+    assert (low.block_q, low.block_k) == (448, 4444)
+    q = _sds((bh, lq, 64), jnp.bfloat16, one_chip)
+    kv = _sds((bh, lk, 64), jnp.bfloat16, one_chip)
+    state = (_sds((bh, lq, 64), jnp.float32, one_chip),
+             _sds((bh, lq, 1), jnp.float32, one_chip),
+             _sds((bh, lq, 1), jnp.float32, one_chip))
+    step = functools.partial(
+        flash_mqkv, causal=masked, finalize=False, block_q=low.block_q,
+        block_k=low.block_k, masked=masked, keepdims=True, interpret=False)
+    compiled = jax.jit(lambda q, k, v, qp, kp, st: step(
+        q, k, v, qp, kp, state=st)).lower(
+        q, kv, kv, _sds((lq,), jnp.int32, one_chip),
+        _sds((lk,), jnp.int32, one_chip), state).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# temp_size_in_bytes of the step below in the parent of the SP > 1 flash
+# lowering, compiled the same way: its torus stages attended through the
+# materialised score block
+MATERIALISED_SP4_TEMP_BYTES = 2_655_745_536
+
+
+def test_cogvideox_step_sp4(topo, monkeypatch):
     """CogVideoX-5B's block at published widths (2 blocks) on the
     ``cogvideox_t2v_sp4`` cell's video, 13 x 30 x 45 = 17,550 latent
-    tokens and 226 of text, with guidance at SP=4 ``swift_torus``: it
-    compiles, fits a chip, and exchanges over the torus."""
+    tokens and 226 of text, with guidance at SP=4 ``swift_torus``, where
+    JAX reports a TPU: it compiles, fits a chip, attends through the
+    flash kernel in its stages, still exchanges over the torus, and holds
+    less than the materialised score blocks took."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = make_mesh((1, 4), ("data", "model"), devices=topo.devices)
     sp = SPConfig(strategy="swift_torus", sp_axes=("model",),
                   batch_axes=("data",))
     compiled = _dit_step(mesh, sp, 17550, NamedSharding(mesh, P()),
                          arch="cogvideox-5b", guidance=6.0)
     _assert_fits(compiled)
-    assert "collective-permute" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < MATERIALISED_SP4_TEMP_BYTES, temp

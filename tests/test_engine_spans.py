@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_reduced
-from repro.core import PipelineConfig, SPConfig
+from repro.core import PipelineConfig, SPConfig, strategy
 from repro.core.strategy import attention_lowering
 from repro.models import ParallelContext, get_model
 from repro.serving import DiTRequest, DiTServer, SamplerConfig
@@ -146,6 +146,68 @@ def test_plan_cache_trace_tags_the_attention_lowering(dit, mesh1, pipeline):
         assert r.tags["attn"] == want
         assert want in ("reference", "displaced")
         assert r.tags["block"] == "uniform"
+
+
+def test_plan_cache_trace_tags_the_one_chip_flash_lowering(mesh1,
+                                                           monkeypatch):
+    """Where the lowering's platform test reports a TPU (the kernel still
+    runs interpreted here), a one-chip SP=1 bucket of 64-wide heads has
+    the ``attn`` tag ``flash``."""
+    monkeypatch.setattr(strategy, "pallas_interpret", lambda: False)
+    cfg = dataclasses.replace(get_reduced("flux-12b"), dtype="float32",
+                              n_heads=2, n_kv_heads=2, head_dim=64)
+    params, _ = get_model(cfg).init(cfg, jax.random.PRNGKey(0), 1)
+    sink = RecordingTracker()
+    srv = DiTServer(params, cfg, mesh1, SP,
+                    sampler=SamplerConfig(num_steps=1), max_batch=1,
+                    tracker=sink)
+    srv.submit(DiTRequest(rid=1, seq_len=32))
+    assert len(srv.serve()) == 1
+    (build,) = [r for r in sink.records if r.name == "plan_cache.trace"]
+    assert build.tags["attn"] == "flash"
+
+
+def test_sp4_bucket_tags_its_stage_lowering():
+    """An SP > 1 bucket's ``plan_cache.trace`` names the strategy and the
+    attend inside its stages: ``swift_torus+reference`` on the CPU, and
+    ``swift_torus+flash`` where the platform test reports a TPU (the
+    kernel then runs interpreted).  Four CPU devices, a child process;
+    two heads of 64 at SP=4 plan two Ulysses pairs that ring the KV."""
+    code = (
+        "import sys; sys.path[:0] = ['src']\n"
+        "import dataclasses, json\n"
+        "import jax\n"
+        "from repro.compat import make_mesh\n"
+        "from repro.configs import get_reduced\n"
+        "from repro.core import SPConfig, strategy\n"
+        "from repro.models import get_model\n"
+        "from repro.serving import DiTRequest, DiTServer, SamplerConfig\n"
+        "from repro.serving.metrics import RecordingTracker\n"
+        "cfg = dataclasses.replace(get_reduced('flux-12b'), dtype='float32',"
+        " n_heads=2, n_kv_heads=2, head_dim=64)\n"
+        "params, _ = get_model(cfg).init(cfg, jax.random.PRNGKey(0), 1)\n"
+        "mesh = make_mesh((1, 4), ('data', 'model'))\n"
+        "sp = SPConfig(strategy='swift_torus', sp_axes=('model',),"
+        " batch_axes=('data',))\n"
+        "tags = []\n"
+        "for tpu in (False, True):\n"
+        "    if tpu:\n"
+        "        strategy.pallas_interpret = lambda: False\n"
+        "    sink = RecordingTracker()\n"
+        "    srv = DiTServer(params, cfg, mesh, sp, max_batch=1,"
+        " sampler=SamplerConfig(num_steps=1), tracker=sink)\n"
+        "    srv.submit(DiTRequest(rid=1, seq_len=32))\n"
+        "    assert len(srv.serve()) == 1\n"
+        "    tags += [r.tags['attn'] for r in sink.records"
+        " if r.name == 'plan_cache.trace']\n"
+        "print(json.dumps(tags))\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    res = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stderr[-3000:]
+    assert json.loads(res.stdout.strip().splitlines()[-1]) == [
+        "swift_torus+reference", "swift_torus+flash"]
 
 
 def test_cogvideox_spans_tag_the_block_and_the_guidance_branches(mesh1):

@@ -150,7 +150,7 @@ def test_kernel_unnormalized_state_output():
     (1, 2560, 2, 2, 128),  # q blocks of 640 rows against one kv block
     (2, 512, 4, 2, 128),   # GQA, heads packed in the lanes
     (1, 512, 2, 2, 64),    # head_dim 64: heads flattened
-    (1, 300, 2, 2, 128),   # K padded to 384 columns: the masked path
+    (1, 300, 2, 2, 128),   # 300 keys: one block spanning them, unpadded
 ])
 def test_kernel_bf16_shape_derived_blocks(shape):
     """bf16 MXU operands, f32 softmax state, blocks from the shapes: the
@@ -191,10 +191,14 @@ def test_block_sizes_from_shape(length):
 
 
 def test_block_sizes_pad_without_a_divisor():
-    """A length with no fitting divisor pads q to whole blocks and K to
-    whole 128-lane tiles; K and V too large for VMEM split into blocks."""
+    """A length with no fitting divisor keeps K one unpadded block and
+    pads q to the fewest whole blocks within the score budget; K and V
+    too large for VMEM split into blocks."""
     bq, bk = block_sizes(4100, 4100, 128, 2)  # 4100 = 4 * 25 * 41
-    assert bk == 4224 and bq == SCORE_ELEMS // bk // 16 * 16
+    cap = SCORE_ELEMS // bk // 16 * 16
+    assert bk == 4100 and bq <= cap and bq % 16 == 0
+    assert -(-4100 // bq) == -(-4100 // cap)  # as few blocks as the cap
+    assert -(-4100 // bq) * bq - 4100 < 16 * -(-4100 // bq)
     long = 65536  # K and V of a head, double-buffered: 64 MiB
     bq, bk = block_sizes(long, long, 128, 2)
     assert bk < long and long % bk == 0 and 4 * bk * 128 * 2 <= KV_VMEM_BYTES
@@ -212,7 +216,8 @@ def _mesh(data, model):
     ("tpu", 1280, 128, None, "flash"),   # 1 x 1280 x 24 x 128 self-attention
     ("cpu", 1280, 128, None, "reference"),  # the CPU keeps the oracle
     ("tpu", 1, 128, None, "reference"),  # decode's one-token queries
-    ("tpu", 1280, 64, None, "reference"),  # a head_dim of part of a lane tile
+    ("tpu", 1280, 96, None, "reference"),  # a head_dim of part of a lane tile
+    ("tpu", 1280, 64, None, "flash"),    # 64 wide: spans the flat operand
     ("tpu", 1280, 128, 256, "flash"),    # a static window: the kernel's mask
     ("tpu", 1280, 128, jnp.int32(256), "reference"),  # a traced one is not
 ])
@@ -251,11 +256,26 @@ def test_one_chip_flash_differentiates_as_the_oracle(monkeypatch, mesh1,
 
 def test_attention_lowering_names_the_sp_strategy(monkeypatch):
     """The kernel on a one-device mesh; the oracle at SP=1 on several
-    (GSPMD does not partition a Pallas call); the strategy at SP > 1."""
+    (GSPMD does not partition a Pallas call); at SP > 1 the strategy and
+    the attend inside its stages: the kernel where the predicate holds,
+    the materialised oracle where it does not (the CPU, a head_dim the
+    kernel does not take), the fused ring kernel under the Pallas comm
+    backend."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     sp = SPConfig(strategy="swift_torus", sp_axes=("model",))
     assert attention_lowering(sp, _mesh(1, 1), 1280, 128) == "flash"
     assert attention_lowering(sp, _mesh(2, 1), 1280, 128) == "reference"
-    assert attention_lowering(sp, _mesh(1, 4), 1280, 128) == "swift_torus"
+    assert attention_lowering(sp, _mesh(1, 4), 1280, 128) == (
+        "swift_torus+flash")
+    assert attention_lowering(sp, _mesh(1, 4), 17776, 64) == (
+        "swift_torus+flash")
+    assert attention_lowering(sp, _mesh(1, 4), 1280, 96) == (
+        "swift_torus+reference")
+    pallas = dataclasses.replace(sp, strategy="ring", comm_backend="pallas")
+    assert attention_lowering(pallas, _mesh(1, 4), 1280, 128) == (
+        "ring+ring_flash")
     full = dataclasses.replace(sp, strategy="full")
     assert attention_lowering(full, _mesh(1, 4), 1280, 128) == "reference"
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert attention_lowering(sp, _mesh(1, 4), 1280, 128) == (
+        "swift_torus+reference")
