@@ -2,8 +2,8 @@
 
 Prints ``name,us_per_call,derived`` CSV.  See each module's docstring for
 the figure it regenerates and the derivation caveats (this container is
-CPU-only; multi-pod numbers come from the calibrated analytical model and
-the dry-run roofline, not wall clocks).
+CPU-only; multi-pod numbers come from the calibrated analytical model,
+not wall clocks).
 
 Besides the CSV, every module run also lands in a ``BENCH_<module>.json``
 trajectory record (``--out-dir``, default cwd): the module's rows (step
@@ -110,7 +110,6 @@ def main(argv: list[str] | None = None) -> None:
         hybrid_sweep,
         kernel_bench,
         layerwise,
-        roofline_table,
         sched_sweep,
     )
 
@@ -121,7 +120,6 @@ def main(argv: list[str] | None = None) -> None:
         "layerwise (Fig 9)": layerwise,
         "ablation (Fig 10)": ablation,
         "kernel_bench (Fig 12)": kernel_bench,
-        "roofline_table (assignment)": roofline_table,
         "hybrid_sweep (beyond-paper, DESIGN.md §7)": hybrid_sweep,
         "hier_a2a_sweep (beyond-paper, DESIGN.md §8.2)": hier_a2a_sweep,
         "sched_sweep (beyond-paper, DESIGN.md §9)": sched_sweep,

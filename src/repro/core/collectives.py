@@ -178,7 +178,6 @@ def grouped_all_to_all(
     split_axis: int,
     stack_axis: int = 0,
     backend: str = "xla",
-    interpret: bool | None = None,
     wire_dtype: str | None = None,
 ) -> jax.Array:
     """All-to-all restricted to Ulysses groups of ``layout``.
@@ -198,17 +197,15 @@ def grouped_all_to_all(
     """
     if layout.u_groups > 1:
         return hier_all_to_all(x, layout, split_axis=split_axis,
-                               backend=backend, interpret=interpret,
-                               wire_dtype=wire_dtype)
+                               backend=backend, wire_dtype=wire_dtype)
     return staged_all_to_all(x, layout, split_axis=split_axis,
-                             backend=backend, interpret=interpret)
+                             backend=backend)
 
 
 @jax.named_scope("sp_comm")
 def monolithic_all_to_all(
     x: jax.Array, layout: GroupLayout, *, split_axis: int,
-    backend: str = "xla", interpret: bool | None = None,
-    wire_dtype: str | None = None,
+    backend: str = "xla", wire_dtype: str | None = None,
 ) -> jax.Array:
     """Baseline atomic all-to-all (what Ulysses does before Torus).
 
@@ -221,8 +218,7 @@ def monolithic_all_to_all(
     """
     if layout.u_groups > 1:
         return hier_all_to_all(x, layout, split_axis=split_axis,
-                               backend=backend, interpret=interpret,
-                               wire_dtype=wire_dtype)
+                               backend=backend, wire_dtype=wire_dtype)
     if (layout.p_ring == 1 and layout.p_ulysses == layout.size
             and backend == "xla"):
         chunks = jnp.stack(jnp.split(x, layout.p_ulysses, axis=split_axis), axis=0)
@@ -232,14 +228,13 @@ def monolithic_all_to_all(
             chunks, layout.axes, split_axis=0, concat_axis=0, tiled=True
         )
     return grouped_all_to_all(x, layout, split_axis=split_axis,
-                              backend=backend, interpret=interpret)
+                              backend=backend)
 
 
 @jax.named_scope("sp_comm")
 def ungroup_all_to_all(
     stacked: jax.Array, layout: GroupLayout, *, concat_axis: int,
-    backend: str = "xla", interpret: bool | None = None,
-    wire_dtype: str | None = None,
+    backend: str = "xla", wire_dtype: str | None = None,
 ) -> jax.Array:
     """Inverse transform: send ``stacked[j]`` back to ulysses-peer j and
     concatenate the received chunks along ``concat_axis`` (the fourth
@@ -249,8 +244,7 @@ def ungroup_all_to_all(
         return jnp.squeeze(stacked, axis=0)
     if layout.u_groups > 1:
         return hier_ungroup(stacked, layout, concat_axis=concat_axis,
-                            backend=backend, interpret=interpret,
-                            wire_dtype=wire_dtype)
+                            backend=backend, wire_dtype=wire_dtype)
     if (layout.p_ring == 1 and layout.p_ulysses == layout.size
             and backend == "xla"):
         moved = lax.all_to_all(
@@ -258,4 +252,4 @@ def ungroup_all_to_all(
         )
         return jnp.concatenate(list(moved), axis=concat_axis)
     return staged_ungroup(stacked, layout, concat_axis=concat_axis,
-                          backend=backend, interpret=interpret)
+                          backend=backend)

@@ -31,17 +31,16 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from ..comm import Stream, fence, ring_shift
 from ..comm import profiler as _profiler
 from ..comm import trace as _trace
-from ..kernels.flash_mqkv import flash_mqkv
+from ..kernels.flash_mqkv import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_mqkv
 from ..kernels.ops import _flatten_heads, _pad_to, _round_up, block_sizes
+from ..kernels.ring_flash import ring_flash_step
 from .collectives import GroupLayout
-from .softmax import (MaskSpec, Partial, attend_partial,
-                      attend_partial_blockwise, empty_partial, finalize,
-                      merge)
+from .softmax import (MaskSpec, Partial, attend_partial, empty_partial,
+                      finalize, merge)
 
 # maps the ring coordinate (traced int32) owning the chunk -> [Lk] positions
 KPosFn = Callable[[jax.Array], jax.Array]
@@ -122,9 +121,9 @@ class FlashLowering(NamedTuple):
         return o.transpose(0, 2, 3, 1, 4).reshape(self.batch, -1, h, d)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11, 12))
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
 def _flash_attend(q, k, v, q_pos, k_pos, state, scale, causal, window,
-                  block_q, block_k, masked, interpret) -> Partial:
+                  block_q, block_k, masked) -> Partial:
     """One attend through the flash kernel in ``FlashLowering``'s layout,
     the running (O', l, m) carried in (``state``; None when fresh) and
     out.  The gradient is the oracle's (``_oracle_attend``, as
@@ -133,8 +132,7 @@ def _flash_attend(q, k, v, q_pos, k_pos, state, scale, causal, window,
     return Partial(*flash_mqkv(
         q, k, v, q_pos, k_pos, group=q.shape[0] // k.shape[0], scale=scale,
         causal=causal, window=window, state=state, finalize=False,
-        block_q=block_q, block_k=block_k, masked=masked, keepdims=True,
-        interpret=interpret))
+        block_q=block_q, block_k=block_k, masked=masked, keepdims=True))
 
 
 def _oracle_attend(q, k, v, q_pos, k_pos, state, scale, causal, window
@@ -165,7 +163,7 @@ def _flash_attend_fwd(q, k, v, q_pos, k_pos, state, *static):
 
 
 def _flash_attend_bwd(scale, causal, window, block_q, block_k, masked,
-                      interpret, res, g):
+                      res, g):
     q, k, v, q_pos, k_pos, state = res
     _, vjp = jax.vjp(lambda q, k, v, st: _oracle_attend(
         q, k, v, q_pos, k_pos, st, scale, causal, window), q, k, v, state)
@@ -188,31 +186,25 @@ def ring_attention(
     causal: bool = False,
     window: int | None = None,
     accum: Partial | None = None,
-    unroll: bool = False,
-    kv_block: int | None = None,
     backend: str = "xla",
-    interpret: bool | None = None,
     lowering: OracleLowering | FlashLowering = ORACLE,
 ) -> Partial:
-    """Run P_r ring steps; returns the merged partial (not finalized).
+    """Run P_r ring steps, unrolled; returns the merged partial (not
+    finalized).  Unrolled steps let XLA schedule each permute against the
+    next step's compute, and let HLO cost analysis count every step.
 
     ``lowering`` says how each step attends (the module docstring): q, k,
     v, ``q_pos`` and ``accum`` come in its layout, and the result goes out
-    in it; ``k_pos_fn`` gives a chunk's positions unpadded.  ``kv_block``
-    caps the materialized score matrix per oracle attend (see
-    softmax.attend_partial_blockwise).
+    in it; ``k_pos_fn`` gives a chunk's positions unpadded.
 
     ``backend="pallas"`` runs the fused path (DESIGN.md §8.1): each ring
     step is ONE ``kernels.ring_flash`` call that carries the (O', l, m)
     online-softmax state in VMEM *and* issues the next-step KV put from
-    inside the kernel, the paper's Algorithm-2 overlap.  The pallas path
-    is always step-unrolled (one kernel per step) and ignores
-    ``kv_block`` (the kernel has its own VMEM blocking); ``interpret``
-    selects the interpreter-mode lowering (None follows the platform)."""
+    inside the kernel, the paper's Algorithm-2 overlap."""
     if backend == "pallas":
         return _ring_attention_pallas(
             q, k, v, layout, q_pos=q_pos, k_pos_fn=k_pos_fn, scale=scale,
-            causal=causal, window=window, accum=accum, interpret=interpret)
+            causal=causal, window=window, accum=accum)
     p_r = layout.p_ring
     acc = accum
     masked = causal or window is not None
@@ -227,18 +219,13 @@ def ring_attention(
                   else jnp.arange(lowering.k_len))
             return _flash_attend(
                 q, kc, vc, qp, lowering.k_pos(kp), acc, scale, causal,
-                window, lowering.block_q, lowering.block_k, masked,
-                interpret)
+                window, lowering.block_q, lowering.block_k, masked)
     else:
         def attend(acc, kc, vc, owner_r):
             mask = None if not masked else MaskSpec(
                 causal=causal, window=window, q_pos=q_pos,
                 k_pos=k_pos_fn(owner_r) if k_pos_fn is not None else None)
-            if kv_block is not None:
-                part = attend_partial_blockwise(q, kc, vc, scale=scale,
-                                                mask=mask, kv_block=kv_block)
-            else:
-                part = attend_partial(q, kc, vc, scale=scale, mask=mask)
+            part = attend_partial(q, kc, vc, scale=scale, mask=mask)
             return part if acc is None else merge(acc, part)
 
     _, my_r = layout.my_coords()
@@ -252,45 +239,23 @@ def ring_attention(
         return out
 
     stream = Stream("ring")
-
-    def body(s, carry):
-        kc, vc, acc = carry
-        # issue next-step transfer first (double buffer), compute current
+    kc, vc = k, v
+    for s in range(p_r - 1):
+        # issue the next step's transfer first (double buffer), then fence
+        # this step's attend inputs on the accumulator, so that only one
+        # step's score matrix is live; the put does not pass through the
+        # fence and still overlaps the attend
         with jax.named_scope("sp_comm"):
             nxt = ring_shift(layout, kc, vc, stream=stream,
                              overlaps="ring attend")
+        if acc is not None:  # a fresh first step has nothing to wait on
+            (kc, vc), accs = fence((kc, vc), tuple(acc))
+            acc = Partial(*accs)
         owner = (my_r - s) % p_r  # ring rank whose shard I currently hold
         acc = attend(acc, kc, vc, owner)
         _profiler.mark_compute("ring attend", layout.axes, (kc, vc),
                                tuple(acc), stream=stream.name)
-        return (*nxt.payload, acc)
-
-    if unroll:
-        # unrolling lets XLA schedule permutes across step boundaries at the
-        # cost of HLO size; fori_loop keeps HLO O(1) in P_r.  The fence on
-        # acc stops the scheduler from materializing every step's score
-        # matrix at once (puts don't pass through the fence, so they still
-        # overlap with compute).
-        kc, vc = k, v
-        for s in range(p_r - 1):
-            # fence this step's attend inputs on the accumulator so only one
-            # step's score matrix is live; the next put stays independent
-            with jax.named_scope("sp_comm"):
-                nxt = ring_shift(layout, kc, vc, stream=stream,
-                                 overlaps="ring attend")
-            if acc is not None:  # a fresh first step has nothing to wait on
-                (kc, vc), accs = fence((kc, vc), tuple(acc))
-                acc = Partial(*accs)
-            owner = (my_r - s) % p_r
-            acc = attend(acc, kc, vc, owner)
-            _profiler.mark_compute("ring attend", layout.axes,
-                                   (kc, vc), tuple(acc),
-                                   stream=stream.name)
-            kc, vc = nxt.payload
-    else:
-        if acc is None:  # the loop carries a state from its first step
-            acc = lowering.empty(q.shape)
-        kc, vc, acc = lax.fori_loop(0, p_r - 1, body, (k, v, acc))
+        kc, vc = nxt.payload
     # last step: compute only, no further transfer (2(P-1)/P volume, §2.2)
     owner = (my_r - (p_r - 1)) % p_r
     out = attend(acc, kc, vc, owner)
@@ -315,7 +280,6 @@ def _ring_attention_pallas(
     causal: bool,
     window: int | None,
     accum: Partial | None,
-    interpret: bool,
 ) -> Partial:
     """P_r fused ring steps: kernel-carried (O', l, m) + in-kernel puts.
 
@@ -323,11 +287,6 @@ def _ring_attention_pallas(
     D], padding masked via k_pos = -1), so the kernel's forward buffers
     can be handed to the channel unmodified at every step.
     """
-    from ..kernels.flash_mqkv import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q,
-                                      flash_mqkv)
-    from ..kernels.ops import _flatten_heads, _pad_to
-    from ..kernels.ring_flash import ring_flash_step
-
     def _flatten_pad(x, block):  # [B, L, H, D] -> [B*H, L_pad, D]
         return _pad_to(_flatten_heads(x), 1, block)
 
@@ -352,7 +311,7 @@ def _ring_attention_pallas(
                 else jnp.arange(lk, dtype=jnp.int32))
         return _pad_pos(base, bk, -1)
 
-    stream = Stream("ring", backend="pallas", interpret=interpret)
+    stream = Stream("ring", backend="pallas")
     state = None
     fut = None
     for s in range(p_r):
@@ -368,7 +327,7 @@ def _ring_attention_pallas(
             (o, l, m), (kfwd, vfwd) = ring_flash_step(
                 qf, kc, vc, qpp, kpos_for(owner), group=group, scale=scale,
                 causal=causal, window=window, state=state, finalize=False,
-                block_q=bq, block_k=bk, interpret=interpret)
+                block_q=bq, block_k=bk)
             fut = ch.put_fused(kfwd, vfwd, overlaps="ring attend")
             _trace.mark_compute("ring attend", stream=stream.name)
         else:
@@ -376,7 +335,7 @@ def _ring_attention_pallas(
             o, l, m = flash_mqkv(
                 qf, kc, vc, qpp, kpos_for(owner), group=group, scale=scale,
                 causal=causal, window=window, state=state, finalize=False,
-                block_q=bq, block_k=bk, interpret=interpret)
+                block_q=bq, block_k=bk)
         _profiler.mark_compute("ring attend", layout.axes, (kc, vc),
                                (o, l, m), stream=stream.name)
         state = (o, l, m)

@@ -139,41 +139,6 @@ def attend_partial(
     return Partial(o=o.astype(jnp.float32), l=l, m=m)
 
 
-def attend_partial_blockwise(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    *,
-    scale: float | None = None,
-    mask: MaskSpec | None = None,
-    kv_block: int = 1024,
-) -> Partial:
-    """attend_partial with the KV dim processed in blocks + online merge —
-    caps the materialized score matrix at [B, H, Lq, kv_block] (the
-    XLA-level analogue of the Pallas kernel's VMEM tiling; beyond-paper
-    §Perf fix for long-gathered-KV memory blowups)."""
-    b, lq, hq, d = q.shape
-    lk = k.shape[1]
-    if lk <= kv_block:
-        return attend_partial(q, k, v, scale=scale, mask=mask)
-    acc = empty_partial(b, lq, hq, d)
-    for i in range(0, lk, kv_block):
-        j = min(i + kv_block, lk)
-        if mask is not None:
-            kp = (mask.k_pos[i:j] if mask.k_pos is not None
-                  else jnp.arange(i, j) + mask.k_offset)
-            vk = mask.valid_k[i:j] if mask.valid_k is not None else None
-            m = dataclasses.replace(mask, k_pos=kp, k_offset=0, valid_k=vk)
-        else:
-            m = None
-        acc = merge(acc, attend_partial(q, k[:, i:j], v[:, i:j],
-                                        scale=scale, mask=m))
-        # pin the schedule: without this XLA is free to materialize every
-        # block's score matrix before any merge, defeating the blocking
-        acc = Partial(*jax.lax.optimization_barrier(tuple(acc)))
-    return acc
-
-
 def merge(a: Partial, b: Partial) -> Partial:
     """Associative, commutative merge of two partials (Appendix C eq. 2-3)."""
     m = jnp.maximum(a.m, b.m)

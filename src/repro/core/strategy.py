@@ -35,6 +35,7 @@ from .torus import torus_attention
 from .ulysses import gather_qkv, group_positions, scatter_o
 
 STRATEGIES = ("full", "ring", "ulysses", "usp", "swift", "swift_torus")
+MACHINE_AXIS = "pod"  # the slow-boundary mesh axis (paper's N)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,7 +45,6 @@ class SPConfig:
     strategy: str = "swift_torus"
     sp_axes: tuple[str, ...] = ("model",)  # sequence-parallel mesh axes
     batch_axes: tuple[str, ...] | None = ("data",)  # batch (DP) mesh axes
-    machine_axis: str = "pod"  # the slow-boundary axis (paper's N)
     replicate_kv: bool = False  # allow P_u up to gcd(SP, Hq) by replicating KV
     # Hybrid-parallel axes (DESIGN.md §7).  cfg_axis: the 2-way classifier-
     # free-guidance axis — the sampler stacks the cond/uncond branches on
@@ -55,23 +55,10 @@ class SPConfig:
     # named here so planners/engines can find it.
     cfg_axis: str | None = None
     pp_axis: str | None = None
-    # Unrolled ring steps let XLA schedule each permute against the next
-    # step's compute AND make HLO cost_analysis see every trip (lax loops
-    # are counted once); fori_loop is available for very large P_r.
-    unroll_ring: bool = True
-    # Beyond-paper (§Perf): fuse all Pull-Q stage compute into one ring
-    # circulation of the diagonal KV (Algorithm 1 re-circulates it P_u x).
-    torus_fused_pull_q: bool = False
-    # Beyond-paper (§Perf): cap the materialized score matrix per oracle
-    # attend at [B, H, Lq, attn_kv_block] (XLA-level flash blocking; the
-    # flash kernel's stages block by themselves); None = off.
-    attn_kv_block: int | None = None
     # Comm lowering (DESIGN.md §8.1): "xla" = ppermute + barrier, overlap
     # left to XLA's scheduler; "pallas" = in-kernel DMA + semaphores (the
-    # fused ring_flash path).  kernel_interpret: None follows the platform
-    # (compat.pallas_interpret — interpreted on CPU, compiled on a TPU).
+    # fused ring_flash path).
     comm_backend: str = "xla"
-    kernel_interpret: bool | None = None
     # Hierarchical a2a (DESIGN.md §8.2): decompose every Ulysses
     # all-to-all into an intra-machine exchange plus staged inter-machine
     # hops whenever the Ulysses groups span machines (engages only when
@@ -112,7 +99,7 @@ def resolve_layout(
 ) -> GroupLayout:
     """Instantiate the paper's (P_u × P_r) plan for this mesh + head count."""
     sp = math.prod(mesh.shape[a] for a in cfg.sp_axes)
-    n = mesh.shape[cfg.machine_axis] if cfg.machine_axis in cfg.sp_axes else 1
+    n = mesh.shape[MACHINE_AXIS] if MACHINE_AXIS in cfg.sp_axes else 1
     m = sp // n
 
     def u_groups(p_u: int, outer: bool) -> int:
@@ -203,16 +190,14 @@ def _one_chip_flash_bwd(scale, causal, window, qkv, g):
 _one_chip_flash.defvjp(_one_chip_flash_fwd, _one_chip_flash_bwd)
 
 
-def _usp_like(q, k, v, layout: GroupLayout, *, scale, causal, window, unroll,
-              kv_block=None, backend="xla", interpret=None, wire_dtype=None,
-              flash=False):
+def _usp_like(q, k, v, layout: GroupLayout, *, scale, causal, window,
+              backend="xla", wire_dtype=None, flash=False):
     """Shared body for usp/swift/ulysses/ring: monolithic Ulysses gather →
     Ring Attention → scatter.  The layout decides which boundary each
     technique crosses (that single bit is the paper's §4.2 contribution).
     ``flash`` runs the ring's attends through the flash kernel."""
     ls = q.shape[1]
-    g = gather_qkv(q, k, v, layout, backend=backend, interpret=interpret,
-                   wire_dtype=wire_dtype)
+    g = gather_qkv(q, k, v, layout, backend=backend, wire_dtype=wire_dtype)
     b, lg, _, d = g.q.shape
     low = (FlashLowering.of(b, lg, g.k.shape[1], d, q.dtype) if flash
            else ORACLE)
@@ -220,13 +205,11 @@ def _usp_like(q, k, v, layout: GroupLayout, *, scale, causal, window, unroll,
     part = ring_attention(
         low.q(g.q), low.kv(g.k), low.kv(g.v), layout,
         q_pos=low.q_pos(g.q_pos), k_pos_fn=kpos_fn,
-        scale=scale, causal=causal, window=window, unroll=unroll,
-        kv_block=kv_block, backend=backend, interpret=interpret,
+        scale=scale, causal=causal, window=window, backend=backend,
         lowering=low,
     )
     return scatter_o(low.finalize(part, q.dtype), layout,
-                     backend=backend, interpret=interpret,
-                     wire_dtype=wire_dtype)
+                     backend=backend, wire_dtype=wire_dtype)
 
 
 def sp_attention(
@@ -262,16 +245,11 @@ def sp_attention(
     ba = cfg.effective_batch_axes(mesh)
     spec = P(ba, cfg.sp_axes, None, None)
 
-    common = dict(
+    body = partial(
+        torus_attention if cfg.strategy == "swift_torus" else _usp_like,
         layout=layout, scale=scale, causal=causal, window=window,
-        unroll=cfg.unroll_ring, kv_block=cfg.attn_kv_block,
-        backend=cfg.comm_backend, interpret=cfg.kernel_interpret,
-        wire_dtype=cfg.a2a_wire_dtype, flash=lowering.endswith("+flash"))
-    if cfg.strategy == "swift_torus":
-        body = partial(torus_attention,
-                       fused_pull_q=cfg.torus_fused_pull_q, **common)
-    else:
-        body = partial(_usp_like, **common)
+        backend=cfg.comm_backend, wire_dtype=cfg.a2a_wire_dtype,
+        flash=lowering.endswith("+flash"))
 
     fn = jax.shard_map(
         lambda q, k, v: body(q, k, v),

@@ -37,6 +37,7 @@ from ..comm import Stream, fence, pin, torus_hop
 from .collectives import GroupLayout
 from .ring import ORACLE, FlashLowering, ring_attention
 from .softmax import Partial
+from .ulysses import HEAD_AXIS, scatter_o
 
 
 def _pin(acc: Partial) -> Partial:
@@ -51,9 +52,6 @@ def _gate(tensors: tuple, acc: Partial):
     hoisted/overlapped by the scheduler)."""
     vals, accs = fence(tensors, tuple(acc))
     return vals, Partial(*accs)
-from .ulysses import scatter_o
-
-HEAD_AXIS = 2
 
 
 def _split_heads(x: jax.Array, p_u: int) -> jax.Array:
@@ -85,11 +83,7 @@ def torus_attention(
     scale: float | None = None,
     causal: bool = False,
     window: int | None = None,
-    unroll: bool = True,
-    fused_pull_q: bool = False,
-    kv_block: int | None = None,
     backend: str = "xla",
-    interpret: bool | None = None,
     wire_dtype: str | None = None,
     flash: bool = False,
 ) -> jax.Array:
@@ -108,18 +102,7 @@ def torus_attention(
 
     ``backend="pallas"`` lowers every transfer through the Pallas channel
     backend (semaphore-tracked puts, DESIGN.md §8.1) and runs each
-    per-stage RINGATTN through the fused ring_flash kernel;
-    ``interpret`` selects interpreter mode (None follows the platform).
-
-    ``fused_pull_q`` is a beyond-paper optimization (EXPERIMENTS.md §Perf):
-    Algorithm 1 invokes RINGATTN once per Pull-Q stage, re-circulating the
-    *same* diagonal KV chunk through the Ring group P_u times.  The fused
-    variant keeps the staged (distance-k) Q permutes — identical inter-pod
-    wire schedule — but runs ONE ring circulation over the assembled
-    gathered Q, cutting Pull-Q intra-pod ring traffic by P_u×.  Trade-off:
-    diagonal-KV compute can no longer start before Q chunks arrive (the
-    permuted Q tensors are 2× smaller than KV and arrive early, so the
-    exposed latency is small)."""
+    per-stage RINGATTN through the fused ring_flash kernel."""
     p_u, p_r = layout.p_ulysses, layout.p_ring
     b, ls, _, d = q.shape
     u, r = layout.my_coords()
@@ -129,8 +112,7 @@ def torus_attention(
     vc = _split_heads(v, p_u)
     low = (FlashLowering.of(b, ls, ls, d, q.dtype) if flash else ORACLE)
     ring = partial(ring_attention, layout=layout, scale=scale,
-                   causal=causal, window=window, unroll=unroll,
-                   kv_block=kv_block, backend=backend, interpret=interpret,
+                   causal=causal, window=window, backend=backend,
                    lowering=low)
     q_diag = low.q(jnp.take(qc, u, axis=0))
     k_diag = low.kv(jnp.take(kc, u, axis=0))
@@ -142,15 +124,14 @@ def torus_attention(
     # position of the diagonal KV chunk as it circulates the Ring group
     diag_kpos_fn = lambda owner_r: _rank_of(layout, u, owner_r) * ls + jnp.arange(ls)
 
-    acc = None  # gathered-q accumulator, source-u order
-    if not fused_pull_q:
-        acc = low.empty((q_diag.shape[0], p_u * rows, *q_diag.shape[2:]))
-        # ---- stage 0: stationary diagonal chunks, compute starts, no comm
-        part = ring(q_diag, k_diag, v_diag, q_pos=chunk_pos(u),
-                    k_pos_fn=diag_kpos_fn)
-        acc = _put_slice(acc, part, u * rows, low.seq_axes)
+    # gathered-q accumulator, source-u order
+    acc = low.empty((q_diag.shape[0], p_u * rows, *q_diag.shape[2:]))
+    # ---- stage 0: stationary diagonal chunks, compute starts, no comm
+    part = ring(q_diag, k_diag, v_diag, q_pos=chunk_pos(u),
+                k_pos_fn=diag_kpos_fn)
+    acc = _put_slice(acc, part, u * rows, low.seq_axes)
 
-    stream = Stream("torus", backend=backend, interpret=interpret)
+    stream = Stream("torus", backend=backend)
 
     # ---- Pull-Q stages: Q chunks arrive one hop-distance k at a time
     q_recv = [None] * p_u  # q_recv[j] = Q chunk from ulysses peer j
@@ -161,10 +142,9 @@ def torus_attention(
                              overlaps="diag-KV attend").wait()
         src = (u - kstage) % p_u
         recv = low.q(recv)
-        if not fused_pull_q:
-            part = ring(recv, k_diag, v_diag, q_pos=chunk_pos(src),
-                        k_pos_fn=diag_kpos_fn)
-            acc = _pin(_put_slice(acc, part, src * rows, low.seq_axes))
+        part = ring(recv, k_diag, v_diag, q_pos=chunk_pos(src),
+                    k_pos_fn=diag_kpos_fn)
+        acc = _pin(_put_slice(acc, part, src * rows, low.seq_axes))
         q_recv[kstage] = (src, recv)
 
     # assemble the gathered Q (source-u order) for the Pull-KV stages
@@ -176,11 +156,6 @@ def torus_attention(
     q_gather = jnp.moveaxis(q_gather, 0, 1).reshape(
         q_diag.shape[0], p_u * rows, *q_diag.shape[2:])
     q_pos_all = jnp.concatenate([chunk_pos(j) for j in range(p_u)])
-
-    if fused_pull_q:
-        # single ring circulation of the diagonal KV over ALL gathered Q
-        acc = ring(q_gather, k_diag, v_diag, q_pos=q_pos_all,
-                   k_pos_fn=diag_kpos_fn)
 
     # ---- Pull-KV stages: KV chunks arrive; all Q attends each new chunk
     for kstage in range(1, p_u):
@@ -198,5 +173,4 @@ def torus_attention(
 
     # ---- Push-O: staged inverse all-to-all; diagonal O never moves
     o = low.finalize(acc, q.dtype)  # [B, P_u * Ls, h, D]
-    return scatter_o(o, layout, backend=backend, interpret=interpret,
-                     wire_dtype=wire_dtype)
+    return scatter_o(o, layout, backend=backend, wire_dtype=wire_dtype)

@@ -43,8 +43,7 @@ def group_positions(layout: GroupLayout, shard_len: int, ring_r) -> jax.Array:
 
 def gather_qkv(
     q: jax.Array, k: jax.Array, v: jax.Array, layout: GroupLayout,
-    *, backend: str = "xla", interpret: bool | None = None,
-    wire_dtype: str | None = None,
+    *, backend: str = "xla", wire_dtype: str | None = None,
 ) -> Gathered:
     """The first three all-to-alls of Ulysses Attention.  ``wire_dtype``
     compresses the inter-machine leg when the layout is hierarchical
@@ -53,8 +52,7 @@ def gather_qkv(
 
     def fwd(x):
         stacked = monolithic_all_to_all(x, layout, split_axis=HEAD_AXIS,
-                                        backend=backend, interpret=interpret,
-                                        wire_dtype=wire_dtype)
+                                        backend=backend, wire_dtype=wire_dtype)
         # [P_u, B, Ls, h, D] -> [B, P_u * Ls, h, D], source-u order
         p_u, b, ls, h, d = stacked.shape
         return jnp.moveaxis(stacked, 0, 1).reshape(b, p_u * ls, h, d)
@@ -66,7 +64,6 @@ def gather_qkv(
 
 
 def scatter_o(o: jax.Array, layout: GroupLayout, *, backend: str = "xla",
-              interpret: bool | None = None,
               wire_dtype: str | None = None) -> jax.Array:
     """The fourth all-to-all: restore O from [B, P_u*Ls, H/P_u, D] to the
     original [B, Ls, H, D] sequence sharding."""
@@ -74,5 +71,4 @@ def scatter_o(o: jax.Array, layout: GroupLayout, *, backend: str = "xla",
     b, lg, h, d = o.shape
     stacked = o.reshape(b, p_u, lg // p_u, h, d).transpose(1, 0, 2, 3, 4)
     return ungroup_all_to_all(stacked, layout, concat_axis=HEAD_AXIS,
-                              backend=backend, interpret=interpret,
-                              wire_dtype=wire_dtype)
+                              backend=backend, wire_dtype=wire_dtype)

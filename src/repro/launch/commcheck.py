@@ -152,8 +152,7 @@ def main() -> int:
 
     # same program through the Pallas channel backend (interpret mode):
     # routes still present in HLO, semaphore protocol clean
-    hier_pl = dataclasses.replace(hier_cfg, comm_backend="pallas",
-                                  kernel_interpret=True)
+    hier_pl = dataclasses.replace(hier_cfg, comm_backend="pallas")
     with comm.record("hier_a2a_pallas") as tr:
         lowered = jax.jit(
             lambda q, k, v: sp_attention(q, k, v, mesh=mesh, cfg=hier_pl)
@@ -171,7 +170,7 @@ def main() -> int:
 
     # --- 3. Pallas backend (DESIGN.md §8.1): same swift_torus program,
     # semaphore-tracked channels + fused ring kernel, interpret mode -----
-    psp = dataclasses.replace(sp, comm_backend="pallas", kernel_interpret=True)
+    psp = dataclasses.replace(sp, comm_backend="pallas")
     with comm.record("swift_torus_pallas") as tr:
         lowered = jax.jit(
             lambda q, k, v: sp_attention(q, k, v, mesh=mesh, cfg=psp)

@@ -1,4 +1,4 @@
-"""Roofline analysis from the compiled dry-run artifact (assignment §Roofline).
+"""Roofline analysis of a compiled program (assignment §Roofline).
 
 Three terms per (arch × shape × mesh), all derived WITHOUT hardware:
 

@@ -5,7 +5,7 @@ Conventions:
     axis names* is built at init time (see ParamBuilder) and mapped to mesh
     axes by models/sharding.py.
   * layer stacks are ``lax.scan`` over stacked weights (leading "layers"
-    dim) — keeps HLO size O(1) in depth for the 40-pair dry-run.
+    dim) — keeps HLO size O(1) in depth.
   * attention dispatches to core.sp_attention (train/prefill) or
     core.decode_attention (decode) based on the ParallelContext.
 """

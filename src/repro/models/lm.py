@@ -382,7 +382,7 @@ def lm_forward(
     # recomputes the whole layer (incl. the SP attention schedule) in the
     # backward instead of saving ring-step internals.
     body = ctx.remat_wrap(body)
-    # depth<=2 unrolls so dry-run cost probes see true per-layer cost
+    # depth<=2 unrolls so cost probes see the true per-layer cost
     # (XLA cost_analysis counts while-loop bodies once regardless of trips)
     (x, aux), new_caches = lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs,
                                     unroll=cfg.n_layers <= 2)

@@ -309,7 +309,7 @@ def test_hier_a2a_bit_compatible_with_monolithic(backend, mesh8, rng):
     def hier_fn(xs):
         # dispatch happens inside monolithic_all_to_all on u_groups > 1
         return monolithic_all_to_all(xs, hier, split_axis=2,
-                                     backend=backend, interpret=True)
+                                     backend=backend)
 
     def flat_fn(xs):
         return monolithic_all_to_all(xs, flat, split_axis=2)
@@ -331,9 +331,9 @@ def test_hier_roundtrip_and_ungroup(backend, mesh8, rng):
 
     def roundtrip(xs):
         stacked = monolithic_all_to_all(xs, layout, split_axis=2,
-                                        backend=backend, interpret=True)
+                                        backend=backend)
         return ungroup_all_to_all(stacked, layout, concat_axis=2,
-                                  backend=backend, interpret=True)
+                                  backend=backend)
 
     f = _smap(roundtrip, mesh8, spec)
     np.testing.assert_allclose(np.asarray(jax.jit(f)(x)), np.asarray(x),
@@ -422,7 +422,7 @@ def test_hier_a2a_profiler_measures_inter_hops(backend, mesh8, rng):
 
     def fn(xs):
         return monolithic_all_to_all(xs, layout, split_axis=2,
-                                     backend=backend, interpret=True)
+                                     backend=backend)
 
     f = jax.shard_map(fn, mesh=mesh8, in_specs=(spec,), out_specs=out_spec,
                       check_vma=False)

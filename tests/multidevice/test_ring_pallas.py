@@ -55,8 +55,7 @@ def _run_ring(mesh, layout, q, k, v, *, backend, causal=False, window=None,
                               k_pos=e_off + jnp.arange(ek.shape[1])))
         part = ring_attention(
             q, k, v, layout, q_pos=qp, k_pos_fn=kpfn, causal=causal,
-            window=window, accum=accum, unroll=True, backend=backend,
-            interpret=True)
+            window=window, accum=accum, backend=backend)
         return finalize(part, dtype=q.dtype)
 
     spec = P(("data",), ("sp",), None, None)
@@ -131,8 +130,7 @@ def test_ring_backend_parity():
 @pytest.mark.parametrize("causal", [False, True])
 def test_sp_attention_pallas_matches_reference(mesh8, strategy, causal):
     sp = SPConfig(strategy=strategy, sp_axes=("pod", "model"),
-                  batch_axes=("data",), comm_backend="pallas",
-                  kernel_interpret=True)
+                  batch_axes=("data",), comm_backend="pallas")
     q, k, v = _mk(4, 2, 32, 2, 2, 16)
     out = jax.jit(
         lambda q, k, v: sp_attention(q, k, v, mesh=mesh8, cfg=sp,
@@ -143,8 +141,7 @@ def test_sp_attention_pallas_matches_reference(mesh8, strategy, causal):
 
 def test_sp_attention_gqa_pallas(mesh8):
     sp = SPConfig(strategy="swift_torus", sp_axes=("pod", "model"),
-                  batch_axes=("data",), comm_backend="pallas",
-                  kernel_interpret=True)
+                  batch_axes=("data",), comm_backend="pallas")
     q, k, v = _mk(5, 2, 32, 4, 2, 16)
     out = jax.jit(
         lambda q, k, v: sp_attention(q, k, v, mesh=mesh8, cfg=sp))(q, k, v)

@@ -50,20 +50,6 @@ def test_sp_over_all_three_axes(strategy, qkv, mesh8):
     np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("causal,window", [(False, None), (True, None),
-                                           (True, 12)])
-def test_torus_fused_pull_q_matches_oracle(causal, window, qkv, mesh8):
-    """Beyond-paper fused Pull-Q schedule is numerically identical."""
-    q, k, v = qkv
-    cfg = SPConfig(strategy="swift_torus", sp_axes=("pod", "model"),
-                   batch_axes=("data",), torus_fused_pull_q=True)
-    ref = reference_attention(q, k, v,
-                              mask=MaskSpec(causal=causal, window=window))
-    out = jax.jit(lambda q, k, v: sp_attention(
-        q, k, v, mesh=mesh8, cfg=cfg, causal=causal, window=window))(q, k, v)
-    np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
-
-
 def test_gqa_limits_ulysses_degree(qkv, mesh8):
     """kv=1 head ⇒ planner must fall back to pure ring; still correct."""
     q, k, v = qkv

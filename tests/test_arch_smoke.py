@@ -3,7 +3,7 @@
 Each assigned arch instantiates a REDUCED variant of the same family
 (≤2 layers, d_model ≤ 512, ≤4 experts) and runs one forward + one train
 step on CPU, asserting output shapes and finiteness.  Full configs are
-exercised only via the dry-run.
+not instantiated here.
 """
 import dataclasses
 

@@ -83,7 +83,7 @@ def test_stage_attend_matches_attend_partial(d, length, blocks, mask, group,
               or low.k_len % low.block_k != 0)
     run = lambda k_, v_, kp, st: _flash_attend(
         low.q(q), low.kv(k_), low.kv(v_), low.q_pos(q_pos), low.k_pos(kp),
-        st, None, causal, window, low.block_q, low.block_k, masked, True)
+        st, None, causal, window, low.block_q, low.block_k, masked)
     state = run(k0, v0, k0_pos, None) if carried else None
     _check(_natural(run(k, v, k_pos, state), length), want)
 
@@ -108,8 +108,7 @@ def test_stage_attend_differentiates_as_the_oracle(blocks):
         for k_, v_ in ((k0, v0), (k, v)):
             st = _flash_attend(low.q(q), low.kv(k_), low.kv(v_),
                                low.q_pos(pos), low.k_pos(pos), st, None,
-                               True, None, low.block_q, low.block_k, True,
-                               True)
+                               True, None, low.block_q, low.block_k, True)
         return low.finalize(st, q.dtype)
 
     def oracle(q, k0, v0, k, v):
