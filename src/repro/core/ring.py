@@ -22,7 +22,10 @@ schedule: ``ORACLE``, ``attend_partial`` on the operands as given, merged
 into the running partial; or a ``FlashLowering``, the ``flash_mqkv``
 kernel on operands moved once into its layout, with the running
 (O', l, m) carried through the kernel as its state (Algorithm 2's fused
-merge).  ``core.strategy.attention_lowering`` chooses.
+merge).  ``core.strategy.attention_lowering`` chooses.  The lowering also
+says how a finished partial enters a larger accumulator (the torus's
+gathered-Q rows) and how O = O' / l is taken: by XLA ops for the oracle,
+inside the kernel calls for the flash lowering.
 """
 from __future__ import annotations
 
@@ -31,6 +34,7 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..comm import Stream, fence, ring_shift
 from ..comm import profiler as _profiler
@@ -40,15 +44,26 @@ from ..kernels.ops import _flatten_heads, _pad_to, _round_up, block_sizes
 from ..kernels.ring_flash import ring_flash_step
 from .collectives import GroupLayout
 from .softmax import (MaskSpec, Partial, attend_partial, empty_partial,
-                      finalize, merge)
+                      merge)
+from .softmax import finalize as finalize_partial
 
 # maps the ring coordinate (traced int32) owning the chunk -> [Lk] positions
 KPosFn = Callable[[jax.Array], jax.Array]
 
 
+def _put_slice(acc: Partial, upd: Partial, start: jax.Array,
+               axes: Partial) -> Partial:
+    """Write ``upd`` into ``acc`` from q row ``start`` (``axes``: each
+    field's row axis).  Those rows of ``acc`` are still empty, so this is
+    their merge."""
+    return Partial(*(lax.dynamic_update_slice_in_dim(a, u, start, axis=ax)
+                     for a, u, ax in zip(acc, upd, axes)))
+
+
 class OracleLowering:
     """Attends by ``attend_partial``: operands as given ([B, L, H, D]),
-    the running state a ``Partial`` as ``core.softmax`` lays it out."""
+    the running state a ``Partial`` as ``core.softmax`` lays it out, a
+    larger accumulator zero-filled and written by ``_put_slice``."""
 
     seq_axes = Partial(o=1, l=2, m=2)  # each state field's row axis
 
@@ -62,9 +77,26 @@ class OracleLowering:
     def empty(q_shape) -> Partial:
         return empty_partial(*q_shape)
 
+    def attend(self, q, k, v, q_pos, k_pos, acc, *, scale, causal, window,
+               into=None, finalize=False):
+        """One attend merged into ``acc`` (None when fresh); ``into`` and
+        ``finalize`` as ``ring_attention``'s."""
+        mask = None if not (causal or window is not None) else MaskSpec(
+            causal=causal, window=window, q_pos=q_pos, k_pos=k_pos)
+        part = attend_partial(q, k, v, scale=scale, mask=mask)
+        return self.settle(part if acc is None else merge(acc, part), into,
+                           finalize, q.dtype)
+
+    def settle(self, part: Partial, into, finalize: bool, dtype):
+        """A finished partial into ``into``'s accumulator, or finalized."""
+        if into is not None:
+            acc, row = into
+            part = _put_slice(acc, part, row, self.seq_axes)
+        return finalize_partial(part, dtype=dtype) if finalize else part
+
     @staticmethod
-    def finalize(acc: Partial, dtype) -> jax.Array:
-        return finalize(acc, dtype=dtype)
+    def finalize(o: jax.Array) -> jax.Array:
+        return o
 
 
 ORACLE = OracleLowering()
@@ -77,7 +109,13 @@ class FlashLowering(NamedTuple):
     (``flash_mqkv``'s ``keepdims``), q rows padded to whole ``block_q``
     blocks (computed and dropped), K and V to whole ``block_k`` blocks
     (positions -1, masked).  The blocks come from the chunks' shape
-    (``kernels.ops.block_sizes``)."""
+    (``kernels.ops.block_sizes``).
+
+    A larger accumulator (``empty``, ``ring_attention``'s ``into``) lives
+    in kernel outputs alone: the first attend into it creates it, each
+    later one writes its rows in place through the kernel's ``at`` and
+    ``acc``, and the chain's last attend divides by l inside the kernel
+    (``finalize``).  No XLA op fills, patches or copies it."""
 
     batch: int
     q_len: int
@@ -105,70 +143,108 @@ class FlashLowering(NamedTuple):
     def k_pos(self, p):
         return _pad_to(p.astype(jnp.int32), 0, self.block_k, value=-1)
 
-    def empty(self, q_shape) -> Partial:
+    @staticmethod
+    def empty(q_shape) -> Partial:
+        """The accumulator's shapes, and no array: the first attend into
+        it creates it."""
         bh, lq, d = q_shape
-        return Partial(o=jnp.zeros((bh, lq, d), jnp.float32),
-                       l=jnp.zeros((bh, lq, 1), jnp.float32),
-                       m=jnp.full((bh, lq, 1), -jnp.inf, jnp.float32))
+        f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+        return Partial(f32(bh, lq, d), f32(bh, lq, 1), f32(bh, lq, 1))
 
-    def finalize(self, acc: Partial, dtype) -> jax.Array:
-        """O = O' / l over whole chunks of q rows, back to [B, L, H, D]
-        with each chunk's padding dropped: the one transpose out."""
-        bh, lq, d = acc.o.shape
+    def attend(self, q, k, v, q_pos, k_pos, acc, *, scale, causal, window,
+               into=None, finalize=False):
+        """One kernel call, ``acc`` (None when fresh) carried through it;
+        ``q_pos`` in this layout, ``k_pos`` the chunk's own (None: no
+        positions needed but the pad keys').  ``into`` and ``finalize``
+        as ``ring_attention``'s."""
+        masked = (causal or window is not None
+                  or self.k_len % self.block_k != 0)
+        qp = q_pos if q_pos is not None else jnp.zeros(q.shape[1], jnp.int32)
+        kp = self.k_pos(k_pos if k_pos is not None
+                        else jnp.arange(self.k_len))
+        dest, row, rows = None, None, None
+        if into is not None:
+            dest, row = into
+            if isinstance(dest.o, jax.ShapeDtypeStruct):  # created here
+                dest, rows = None, dest.o.shape[1]
+        return _flash_attend(q, k, v, qp, kp, acc, dest, row, scale, causal,
+                             window, self.block_q, self.block_k, masked,
+                             rows, finalize)
+
+    def finalize(self, o: jax.Array) -> jax.Array:
+        """The finalized O ([B·H, L, D], divided in the kernel) back to
+        [B, L, H, D] with each chunk's padding dropped: the one transpose
+        out."""
+        bh, lq, d = o.shape
         h, rows = bh // self.batch, _round_up(self.q_len, self.block_q)
-        o = (acc.o / jnp.where(acc.l == 0.0, 1.0, acc.l)).astype(dtype)
         o = o.reshape(self.batch, h, lq // rows, rows, d)[:, :, :, :self.q_len]
         return o.transpose(0, 2, 3, 1, 4).reshape(self.batch, -1, h, d)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
-def _flash_attend(q, k, v, q_pos, k_pos, state, scale, causal, window,
-                  block_q, block_k, masked) -> Partial:
+@partial(jax.custom_vjp, nondiff_argnums=tuple(range(8, 16)))
+def _flash_attend(q, k, v, q_pos, k_pos, state, dest, row, scale, causal,
+                  window, block_q, block_k, masked, rows, finalize):
     """One attend through the flash kernel in ``FlashLowering``'s layout,
-    the running (O', l, m) carried in (``state``; None when fresh) and
-    out.  The gradient is the oracle's (``_oracle_attend``, as
-    ``strategy._one_chip_flash`` at SP=1): a Pallas call has no transpose
-    rule."""
-    return Partial(*flash_mqkv(
+    the running (O', l, m) carried in (``state``; None when fresh).  Out:
+    the new state; with ``row`` (q's first row in a larger accumulator,
+    traced) that state written from there into ``dest`` in place, or,
+    with ``dest`` None, into a new accumulator of ``rows`` rows; with
+    ``finalize`` O = O' / l in q's dtype instead.  The gradient is the
+    oracle's (``_oracle_attend``, as ``strategy._one_chip_flash`` at
+    SP=1): a Pallas call has no transpose rule."""
+    o, l, m = flash_mqkv(
         q, k, v, q_pos, k_pos, group=q.shape[0] // k.shape[0], scale=scale,
-        causal=causal, window=window, state=state, finalize=False,
-        block_q=block_q, block_k=block_k, masked=masked, keepdims=True))
+        causal=causal, window=window, state=state, finalize=finalize,
+        block_q=block_q, block_k=block_k, masked=masked, keepdims=True,
+        at=None if row is None else row // block_q, acc=dest, rows=rows)
+    return o if finalize else Partial(o, l, m)
 
 
-def _oracle_attend(q, k, v, q_pos, k_pos, state, scale, causal, window
-                   ) -> Partial:
+def _oracle_attend(q, k, v, q_pos, k_pos, state, dest, row, scale, causal,
+                   window, rows, finalize):
     """``_flash_attend``'s values by ``attend_partial``, merged into the
-    carried state: the kernel's [B·H, L, D] layout viewed as a batch of
-    B·Hkv with the ``group`` q heads of each KV head (GQA), pad keys
-    (position -1) masked out."""
+    carried state, then placed or finalized as the kernel does: the
+    kernel's [B·H, L, D] layout viewed as a batch of B·Hkv with the
+    ``group`` q heads of each KV head (GQA), pad keys (position -1)
+    masked out.  A new accumulator's other rows, unwritten by the kernel,
+    are zeros here: nothing reads them."""
     bhkv, lq = k.shape[0], q.shape[1]
     group = q.shape[0] // bhkv
     heads = lambda x: x.reshape(bhkv, group, lq, -1).transpose(0, 2, 1, 3)
-    rows = lambda x: x[..., 0].reshape(bhkv, group, lq)
+    rows_of = lambda x: x[..., 0].reshape(bhkv, group, lq)
     mask = MaskSpec(causal=causal, window=window, q_pos=q_pos, k_pos=k_pos,
                     valid_k=k_pos >= 0)
     part = attend_partial(heads(q), k[:, :, None], v[:, :, None],
                           scale=scale, mask=mask)
     if state is not None:
-        part = merge(Partial(heads(state.o), rows(state.l), rows(state.m)),
-                     part)
+        part = merge(Partial(heads(state.o), rows_of(state.l),
+                             rows_of(state.m)), part)
     flat = lambda x: x.reshape(bhkv * group, lq, 1)
-    return Partial(part.o.transpose(0, 2, 1, 3).reshape(q.shape[0], lq, -1),
+    part = Partial(part.o.transpose(0, 2, 1, 3).reshape(q.shape[0], lq, -1),
                    flat(part.l), flat(part.m))
+    if finalize:
+        return (part.o / jnp.where(part.l == 0.0, 1.0, part.l)).astype(q.dtype)
+    if row is None:
+        return part
+    if dest is None:
+        dest = jax.tree.map(lambda x: jnp.zeros((x.shape[0], rows,
+                                                 x.shape[2]), x.dtype), part)
+    return _put_slice(dest, part, row, FlashLowering.seq_axes)
 
 
-def _flash_attend_fwd(q, k, v, q_pos, k_pos, state, *static):
-    return (_flash_attend(q, k, v, q_pos, k_pos, state, *static),
-            (q, k, v, q_pos, k_pos, state))
+def _flash_attend_fwd(q, k, v, q_pos, k_pos, state, dest, row, *static):
+    return (_flash_attend(q, k, v, q_pos, k_pos, state, dest, row, *static),
+            (q, k, v, q_pos, k_pos, state, dest, row))
 
 
-def _flash_attend_bwd(scale, causal, window, block_q, block_k, masked,
-                      res, g):
-    q, k, v, q_pos, k_pos, state = res
-    _, vjp = jax.vjp(lambda q, k, v, st: _oracle_attend(
-        q, k, v, q_pos, k_pos, st, scale, causal, window), q, k, v, state)
-    dq, dk, dv, dstate = vjp(g)
-    return dq, dk, dv, None, None, dstate
+def _flash_attend_bwd(scale, causal, window, block_q, block_k, masked, rows,
+                      finalize, res, g):
+    q, k, v, q_pos, k_pos, state, dest, row = res
+    _, vjp = jax.vjp(lambda q, k, v, st, dst: _oracle_attend(
+        q, k, v, q_pos, k_pos, st, dst, row, scale, causal, window, rows,
+        finalize), q, k, v, state, dest)
+    dq, dk, dv, dstate, ddest = vjp(g)
+    return dq, dk, dv, None, None, dstate, ddest, None
 
 
 _flash_attend.defvjp(_flash_attend_fwd, _flash_attend_bwd)
@@ -186,56 +262,50 @@ def ring_attention(
     causal: bool = False,
     window: int | None = None,
     accum: Partial | None = None,
+    into: tuple[Partial, jax.Array] | None = None,
+    finalize: bool = False,
     backend: str = "xla",
     lowering: OracleLowering | FlashLowering = ORACLE,
-) -> Partial:
-    """Run P_r ring steps, unrolled; returns the merged partial (not
-    finalized).  Unrolled steps let XLA schedule each permute against the
-    next step's compute, and let HLO cost analysis count every step.
+) -> Partial | jax.Array:
+    """Run P_r ring steps, unrolled; returns the merged partial.  Unrolled
+    steps let XLA schedule each permute against the next step's compute,
+    and let HLO cost analysis count every step.
 
     ``lowering`` says how each step attends (the module docstring): q, k,
     v, ``q_pos`` and ``accum`` come in its layout, and the result goes out
-    in it; ``k_pos_fn`` gives a chunk's positions unpadded.
+    in it; ``k_pos_fn`` gives a chunk's positions unpadded.  The last
+    step's attend also takes ``into`` = (acc, row): the result is then
+    ``acc`` (from ``lowering.empty``) with q's rows, from ``row``, set to
+    the partial (those rows of ``acc`` are still empty: this is their
+    merge); or ``finalize``: the result is O = O' / l in q's dtype.
 
     ``backend="pallas"`` runs the fused path (DESIGN.md §8.1): each ring
     step is ONE ``kernels.ring_flash`` call that carries the (O', l, m)
     online-softmax state in VMEM *and* issues the next-step KV put from
-    inside the kernel, the paper's Algorithm-2 overlap."""
+    inside the kernel, the paper's Algorithm-2 overlap; the result is
+    placed or finalized as the oracle does."""
     if backend == "pallas":
-        return _ring_attention_pallas(
+        part = _ring_attention_pallas(
             q, k, v, layout, q_pos=q_pos, k_pos_fn=k_pos_fn, scale=scale,
             causal=causal, window=window, accum=accum)
+        return ORACLE.settle(part, into, finalize, q.dtype)
     p_r = layout.p_ring
     acc = accum
-    masked = causal or window is not None
 
-    if isinstance(lowering, FlashLowering):
-        masked = masked or lowering.k_len % lowering.block_k != 0
-        qp = (q_pos if q_pos is not None
-              else jnp.zeros(q.shape[1], jnp.int32))
-
-        def attend(acc, kc, vc, owner_r):
-            kp = (k_pos_fn(owner_r) if k_pos_fn is not None
-                  else jnp.arange(lowering.k_len))
-            return _flash_attend(
-                q, kc, vc, qp, lowering.k_pos(kp), acc, scale, causal,
-                window, lowering.block_q, lowering.block_k, masked)
-    else:
-        def attend(acc, kc, vc, owner_r):
-            mask = None if not masked else MaskSpec(
-                causal=causal, window=window, q_pos=q_pos,
-                k_pos=k_pos_fn(owner_r) if k_pos_fn is not None else None)
-            part = attend_partial(q, kc, vc, scale=scale, mask=mask)
-            return part if acc is None else merge(acc, part)
+    def attend(acc, kc, vc, owner_r, last=False):
+        return lowering.attend(
+            q, kc, vc, q_pos, None if k_pos_fn is None else k_pos_fn(owner_r),
+            acc, scale=scale, causal=causal, window=window,
+            into=into if last else None, finalize=last and finalize)
 
     _, my_r = layout.my_coords()
     if p_r == 1:
         # pure-Ulysses plan: no ring rotation, but this local attend is
         # still the compute the torus hops are scheduled to hide — mark it
         # so per-stage traces stay complete for overlap accounting
-        out = attend(acc, k, v, my_r)
+        out = attend(acc, k, v, my_r, last=True)
         _profiler.mark_compute("local attend", layout.axes, (k, v),
-                               tuple(out), stream="ring")
+                               jax.tree.leaves(out), stream="ring")
         return out
 
     stream = Stream("ring")
@@ -258,9 +328,9 @@ def ring_attention(
         kc, vc = nxt.payload
     # last step: compute only, no further transfer (2(P-1)/P volume, §2.2)
     owner = (my_r - (p_r - 1)) % p_r
-    out = attend(acc, kc, vc, owner)
+    out = attend(acc, kc, vc, owner, last=True)
     _profiler.mark_compute("ring attend", layout.axes, (kc, vc),
-                           tuple(out), stream=stream.name)
+                           jax.tree.leaves(out), stream=stream.name)
     return out
 
 

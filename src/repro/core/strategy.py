@@ -202,13 +202,13 @@ def _usp_like(q, k, v, layout: GroupLayout, *, scale, causal, window,
     low = (FlashLowering.of(b, lg, g.k.shape[1], d, q.dtype) if flash
            else ORACLE)
     kpos_fn = lambda owner_r: group_positions(layout, ls, owner_r)
-    part = ring_attention(
+    o = ring_attention(
         low.q(g.q), low.kv(g.k), low.kv(g.v), layout,
         q_pos=low.q_pos(g.q_pos), k_pos_fn=kpos_fn,
-        scale=scale, causal=causal, window=window, backend=backend,
-        lowering=low,
+        scale=scale, causal=causal, window=window, finalize=True,
+        backend=backend, lowering=low,
     )
-    return scatter_o(low.finalize(part, q.dtype), layout,
+    return scatter_o(low.finalize(o), layout,
                      backend=backend, wire_dtype=wire_dtype)
 
 

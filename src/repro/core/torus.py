@@ -24,6 +24,14 @@ pushes overlap it at runtime.  XLA schedules statically, so we fold that
 compute into the Pull-KV stages and rely on the latency-hiding scheduler to
 overlap the staged Push-O permutes with *subsequent layer* compute — the
 same bytes move, on the same hops, in the same stage order.
+
+Where the partials live: one gathered-Q accumulator holds every Q chunk's
+rows in source-u order; stage 0 and each Pull-Q stage write their chunk's
+partial into its source's rows, the Pull-KV stages carry it whole, and the
+last one finalizes.  Under the oracle lowering it is a filled ``Partial``
+patched by ``dynamic_update_slice``; under the flash lowering it lives in
+the kernel's outputs alone (``ring.FlashLowering``), and no XLA op fills,
+patches or copies it.
 """
 from __future__ import annotations
 
@@ -65,15 +73,6 @@ def _rank_of(layout: GroupLayout, u, r):
     return r * layout.p_ulysses + u
 
 
-def _put_slice(acc: Partial, upd: Partial, start: jax.Array,
-               axes: Partial) -> Partial:
-    """Write ``upd`` into ``acc`` from q row ``start`` (``axes``: each
-    field's row axis).  Those rows of ``acc`` are still empty, so this is
-    their merge."""
-    return Partial(*(lax.dynamic_update_slice_in_dim(a, u, start, axis=ax)
-                     for a, u, ax in zip(acc, upd, axes)))
-
-
 def torus_attention(
     q: jax.Array,  # [B, Ls, Hq, D] natural (seq-sharded) layout
     k: jax.Array,  # [B, Ls, Hkv, D]
@@ -92,9 +91,12 @@ def torus_attention(
 
     ``flash`` attends every stage through the flash kernel
     (``ring.FlashLowering``): each chunk is moved into the kernel's layout
-    once, as it arrives, the gathered Q is assembled there, and the
-    gathered-Q accumulator is the kernel's carried state through the
-    Pull-KV stages; O leaves that layout once, at the end.
+    once, as it arrives, and the gathered Q is assembled there in one op.
+    The gathered-Q accumulator then lives in kernel outputs alone: stage 0's call
+    creates it, each Pull-Q call writes its rows in place (aliased), the
+    Pull-KV calls carry it as their state, and the last divides by l and
+    writes O in q's dtype.  No XLA op fills, patches or copies it; O
+    leaves the kernel's layout once, at the end.
 
     ``wire_dtype`` compresses the inter-machine leg of the Push-O when the
     layout is hierarchical (``layout.u_groups > 1``, DESIGN.md §8.2); the
@@ -124,17 +126,22 @@ def torus_attention(
     # position of the diagonal KV chunk as it circulates the Ring group
     diag_kpos_fn = lambda owner_r: _rank_of(layout, u, owner_r) * ls + jnp.arange(ls)
 
+    if p_u == 1:  # no Ulysses peer: the ring alone
+        o = ring(q_diag, k_diag, v_diag, q_pos=chunk_pos(u),
+                 k_pos_fn=diag_kpos_fn, finalize=True)
+        return scatter_o(low.finalize(o), layout, backend=backend,
+                         wire_dtype=wire_dtype)
+
     # gathered-q accumulator, source-u order
     acc = low.empty((q_diag.shape[0], p_u * rows, *q_diag.shape[2:]))
     # ---- stage 0: stationary diagonal chunks, compute starts, no comm
-    part = ring(q_diag, k_diag, v_diag, q_pos=chunk_pos(u),
-                k_pos_fn=diag_kpos_fn)
-    acc = _put_slice(acc, part, u * rows, low.seq_axes)
+    acc = ring(q_diag, k_diag, v_diag, q_pos=chunk_pos(u),
+               k_pos_fn=diag_kpos_fn, into=(acc, u * rows))
 
     stream = Stream("torus", backend=backend)
 
     # ---- Pull-Q stages: Q chunks arrive one hop-distance k at a time
-    q_recv = [None] * p_u  # q_recv[j] = Q chunk from ulysses peer j
+    q_chunks = [q_diag]  # stage order: stage k's came from u - k
     for kstage in range(1, p_u):
         send = jnp.take(qc, (u + kstage) % p_u, axis=0)
         with jax.named_scope("sp_comm"):
@@ -142,22 +149,22 @@ def torus_attention(
                              overlaps="diag-KV attend").wait()
         src = (u - kstage) % p_u
         recv = low.q(recv)
-        part = ring(recv, k_diag, v_diag, q_pos=chunk_pos(src),
-                    k_pos_fn=diag_kpos_fn)
-        acc = _pin(_put_slice(acc, part, src * rows, low.seq_axes))
-        q_recv[kstage] = (src, recv)
+        acc = _pin(ring(recv, k_diag, v_diag, q_pos=chunk_pos(src),
+                        k_pos_fn=diag_kpos_fn, into=(acc, src * rows)))
+        q_chunks.append(recv)
 
-    # assemble the gathered Q (source-u order) for the Pull-KV stages
-    q_gather = jnp.zeros((p_u, *q_diag.shape), q_diag.dtype)
-    q_gather = lax.dynamic_update_slice_in_dim(q_gather, q_diag[None], u,
-                                               axis=0)
-    for src, recv in filter(None, q_recv):
-        q_gather = lax.dynamic_update_slice_in_dim(q_gather, recv[None], src, axis=0)
-    q_gather = jnp.moveaxis(q_gather, 0, 1).reshape(
-        q_diag.shape[0], p_u * rows, *q_diag.shape[2:])
+    # the gathered Q (source-u order) for the Pull-KV stages, in one op:
+    # source j's chunk came in stage (u - j) % P_u, so each Ulysses rank
+    # has its own static concatenation, and the device runs its own
+    def gathered(rank):
+        return lambda *c: jnp.concatenate(
+            [c[(rank - j) % p_u] for j in range(p_u)], axis=1)
+    q_gather = lax.switch(u, [gathered(rank) for rank in range(p_u)],
+                          *q_chunks)
     q_pos_all = jnp.concatenate([chunk_pos(j) for j in range(p_u)])
 
-    # ---- Pull-KV stages: KV chunks arrive; all Q attends each new chunk
+    # ---- Pull-KV stages: KV chunks arrive; all Q attends each new chunk;
+    # the last finalizes
     for kstage in range(1, p_u):
         src = (u - kstage) % p_u
         with jax.named_scope("sp_comm"):
@@ -169,8 +176,9 @@ def torus_attention(
         (k_recv, v_recv), acc = _gate((k_recv, v_recv), acc)
         kpos_fn = lambda owner_r, s=src: _rank_of(layout, s, owner_r) * ls + jnp.arange(ls)
         acc = ring(q_gather, low.kv(k_recv), low.kv(v_recv),
-                   q_pos=q_pos_all, k_pos_fn=kpos_fn, accum=acc)
+                   q_pos=q_pos_all, k_pos_fn=kpos_fn, accum=acc,
+                   finalize=kstage == p_u - 1)
 
     # ---- Push-O: staged inverse all-to-all; diagonal O never moves
-    o = low.finalize(acc, q.dtype)  # [B, P_u * Ls, h, D]
+    o = low.finalize(acc)  # [B, P_u * Ls, h, D]
     return scatter_o(o, layout, backend=backend, wire_dtype=wire_dtype)

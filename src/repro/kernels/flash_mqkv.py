@@ -155,6 +155,9 @@ def flash_mqkv(
     masked: bool = True,
     heads: int | None = None,
     keepdims: bool = False,
+    at: jax.Array | None = None,
+    acc: tuple[jax.Array, jax.Array, jax.Array] | None = None,
+    rows: int | None = None,
     interpret: bool | None = None,
 ):
     """Core pallas_call.  Lq % block_q == 0 and Lk % block_k == 0 required
@@ -172,7 +175,15 @@ def flash_mqkv(
     ``keepdims`` takes and returns the (l, m) state as [BH, Lq, 1], the
     kernel's own layout: on a TPU a [BH, Lq] array is laid out apart from
     it, so each conversion would be a copy of the 128-lane padded column
-    (a caller that chains calls keeps the state in this form)."""
+    (a caller that chains calls keeps the state in this form).
+
+    ``at`` writes the state into a larger accumulator, q's rows from q
+    block ``at`` (an int32 scalar, traced: prefetched for the output
+    index maps; unfinalized ``keepdims`` calls only): into ``acc``, which
+    the outputs alias, so its other rows pass through and no XLA op
+    copies it (the kernel never reads it); without ``acc``, into fresh
+    outputs of ``rows`` rows whose other rows stay unwritten.  ``state``
+    is still read at q's own rows."""
     if heads is None:
         bh, lq, d = q.shape
         bhkv, lk, _ = k.shape
@@ -194,40 +205,54 @@ def flash_mqkv(
         scale = d ** -0.5
     n_q, n_k = lq // block_q, lk // block_k
     has_state = state is not None
+    placed = at is not None
+    assert placed or (acc is None and rows is None), "acc and rows need at"
+    assert not placed or (keepdims and not finalize), "at: unfinalized state"
 
-    def q_map(h, qi, ki):
+    # with ``at`` every index map also takes the prefetched offset (``*_``)
+    def q_map(h, qi, ki, *_):
         i, j = q_at(h)
         return i, qi, j
 
-    def kv_map(h, qi, ki):
+    def kv_map(h, qi, ki, *_):
         i, j = kv_at(h)
         return i, ki, j
 
     q_spec = pl.BlockSpec((None, block_q, d), q_map)
     kv_spec = pl.BlockSpec((None, block_k, d), kv_map)
-    lm_spec = pl.BlockSpec((None, block_q, 1), lambda h, qi, ki: (h, qi, 0))
+    lm_spec = pl.BlockSpec((None, block_q, 1),
+                           lambda h, qi, ki, *_: (h, qi, 0))
     args, in_specs = [q, k, v], [q_spec, kv_spec, kv_spec]
     if masked:
         # q positions as a column: a (block_q, 1) block tiles for any
         # block_q of whole sublanes, where a (1, block_q) row needs whole
         # 128-lane tiles
         args += [q_pos.reshape(lq, 1), k_pos.reshape(1, lk)]
-        in_specs += [pl.BlockSpec((block_q, 1), lambda h, qi, ki: (qi, 0)),
-                     pl.BlockSpec((1, block_k), lambda h, qi, ki: (0, ki))]
+        in_specs += [pl.BlockSpec((block_q, 1), lambda h, qi, ki, *_: (qi, 0)),
+                     pl.BlockSpec((1, block_k), lambda h, qi, ki, *_: (0, ki))]
     if has_state:
         o_in, l_in, m_in = state
         if not keepdims:
             l_in, m_in = l_in[..., None], m_in[..., None]
         args += [o_in, l_in, m_in]
         in_specs += [q_spec, lm_spec, lm_spec]
+    # the aliased accumulator stays in HBM, unread (pl.ANY: no block DMA);
+    # +1: the prefetched offset is the call's first operand
+    aliases = {}
+    if acc is not None:
+        aliases = {len(args) + 1 + i: i for i in range(3)}
+        args += list(acc)
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 3
 
     def kernel(*refs):
         # absent inputs (positions when unmasked, state when fresh) are
         # not passed at all, so no dummy array is made or copied in
-        refs = list(refs)
+        refs = list(refs[1:] if placed else refs)  # the offset: maps only
         qkv, rest = refs[:3], refs[3:]
         pos = [rest.pop(0), rest.pop(0)] if masked else [None, None]
         st = [rest.pop(0) for _ in range(3)] if has_state else [None] * 3
+        if acc is not None:
+            del rest[:3]  # aliased to the outputs
         outs = [rest.pop(0)] + ([None, None] if finalize
                                 else [rest.pop(0), rest.pop(0)])
         _kernel(*qkv, *pos, *st, *outs, *rest, scale=scale, causal=causal,
@@ -238,22 +263,34 @@ def flash_mqkv(
         out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
         out_specs = [q_spec]
     else:
-        out_shape = [jax.ShapeDtypeStruct((bh, lq, d), jnp.float32),
-                     jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32),
-                     jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32)]
+        out_len = acc[0].shape[1] if acc is not None else rows or lq
+        out_shape = [jax.ShapeDtypeStruct((bh, out_len, d), jnp.float32),
+                     jax.ShapeDtypeStruct((bh, out_len, 1), jnp.float32),
+                     jax.ShapeDtypeStruct((bh, out_len, 1), jnp.float32)]
         out_specs = [q_spec, lm_spec, lm_spec]
-    outs = pl.pallas_call(
-        kernel,
-        name="flash_mqkv",
+    grid = dict(
         grid=(bh, n_q, n_k),
         in_specs=in_specs,
         out_specs=out_specs,
-        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
+    )
+    if placed:
+        out_at = lambda h, qi, ki, at_ref: (h, qi + at_ref[0], 0)
+        grid["out_specs"] = [pl.BlockSpec(s.block_shape, out_at)
+                             for s in out_specs]
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **grid))
+        args = [jnp.reshape(at, (1,)).astype(jnp.int32)] + args
+    outs = pl.pallas_call(
+        kernel,
+        name="flash_mqkv",
+        **grid,
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit_bytes(block_q, block_k, d,

@@ -14,6 +14,7 @@ compile for a described chip is written to it but cannot be read back).
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -248,6 +249,23 @@ def test_stage_kernel_compiles(one_chip, masked):
 # lowering, compiled the same way: its torus stages attended through the
 # materialised score block
 MATERIALISED_SP4_TEMP_BYTES = 2_655_745_536
+# the same in the parent of the in-place torus accumulator: its stages'
+# partials went into a filled accumulator by dynamic-update-slice
+FILLED_ACC_SP4_TEMP_BYTES = 1_925_607_936
+# the gathered-Q accumulator of the step below, 12 heads a chip x 4
+# chunks of 4,480 rows: O' and the (l, m) columns
+ACC_SHAPES = ("f32[12,17920,64]", "f32[12,17920,1]")
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][\w-]*)\((.*)$",
+                     re.M)
+
+
+def _ops(text):
+    """(name, result shape without its layout, opcode, op_name) of every
+    instruction of an optimized HLO text, fusion bodies included."""
+    for name, shape, op, rest in _HLO_OP.findall(text):
+        scope = re.search(r'op_name="([^"]*)"', rest)
+        yield (name, re.sub(r"\{.*", "", shape), op,
+               scope.group(1) if scope else "")
 
 
 def test_cogvideox_step_sp4(topo, monkeypatch):
@@ -256,7 +274,11 @@ def test_cogvideox_step_sp4(topo, monkeypatch):
     tokens and 226 of text, with guidance at SP=4 ``swift_torus``, where
     JAX reports a TPU: it compiles, fits a chip, attends through the
     flash kernel in its stages, still exchanges over the torus, and holds
-    less than the materialised score blocks took."""
+    less than the materialised score blocks took.  The gathered-Q
+    accumulator lives in kernel outputs alone: seven kernel calls a
+    block-forward (stage 0, three Pull-Q, three Pull-KV), and no XLA op
+    under ``attn`` fills (broadcast) or patches (dynamic-update-slice)
+    it, nor copies it anywhere."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = make_mesh((1, 4), ("data", "model"), devices=topo.devices)
     sp = SPConfig(strategy="swift_torus", sp_axes=("model",),
@@ -269,3 +291,15 @@ def test_cogvideox_step_sp4(topo, monkeypatch):
     assert "collective-permute" in text
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < MATERIALISED_SP4_TEMP_BYTES, temp
+    assert temp <= FILLED_ACC_SP4_TEMP_BYTES, temp
+    ops = list(_ops(text))
+    glue = [(name, shape) for name, shape, op, scope in ops
+            if "attn/" in scope and (op == "dynamic-update-slice" or (
+                op == "broadcast" and shape in ACC_SHAPES))]
+    assert not glue, glue[:4]
+    copies = [name for name, shape, op, _ in ops
+              if op in ("copy", "copy-start") and shape in ACC_SHAPES]
+    assert not copies, copies[:4]
+    kernels = [name for name, _, op, _ in ops
+               if op == "custom-call" and name.startswith("flash_mqkv")]
+    assert len(kernels) == 7 * 2 * 2, len(kernels)  # 2 blocks x 2 forwards
